@@ -132,12 +132,9 @@ def run(iters: int = 3, quick: bool = False) -> dict:
             times[name] = _time(fn, qg, iters=iters)
             outs[name] = np.asarray(fn(qg))
             # pool-part launch count (the O(slots) -> O(1) claim), counted
-            # at runtime on a pool-only closure
-            pfn = jax.jit(lambda q, pf=pool_fn: pf(
-                q, A.attn_init(b, c, kvh, g, d))[1])
-            with ops.count_launches() as lc:
-                pfn(qg).block_until_ready()
-            launches[name] = lc["count"]
+            # from the traced program of a pool-only closure
+            pfn = lambda q, pf=pool_fn: pf(q, A.attn_init(b, c, kvh, g, d))[1]
+            launches[name] = ops.count_launches(pfn, qg)["count"]
         parity = float(np.max(np.abs(outs["jnp"] - outs["pool_batched"])))
         parity_scan = float(np.max(np.abs(outs["pallas_scan"]
                                           - outs["pool_batched"])))

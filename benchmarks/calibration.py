@@ -66,7 +66,7 @@ NOISE_FRAC = 0.01
 
 def synth_measured(sm: cm.StageModel, chunks, mplan,
                    seed: int) -> np.ndarray:
-    """A ``[N, T]`` measured-span array as MeasuredProfile lays it out:
+    """A ``[N, T]`` span array laid out like the telemetry profiles:
     chunk ``ph``'s true cost lands at every valid (stage, tick = stage+ph),
     times seeded multiplicative clock noise; fill/drain cells stay 0."""
     feats = cm.chunk_cost_features(sm, chunks, cm.WSC_PAPER,

@@ -154,9 +154,7 @@ def device_validation():
 
 def pipeline_leg(quick: bool = False) -> dict:
     """Real-pipeline leg: jit the chunked pipeline with a TP-SHARDED paged
-    pool (GSPMD kv-head sharding on new jaxlib; the manual TP lowering with
-    local kv heads on old jaxlib — ``compat.resolve_tp_lowering``) and
-    measure the pool's actual device bytes + prefill wall time per
+    pool (GSPMD kv-head sharding) and measure the pool's actual device bytes + prefill wall time per
     kv_dtype. Appends to artifacts/bench/kvstore.json."""
     import time
 
@@ -169,7 +167,7 @@ def pipeline_leg(quick: bool = False) -> dict:
     from repro.models.api import build_model
 
     cfg = replace(get_smoke_config("qwen3-8b"), dtype="float32")
-    stages, tp = 4, 2  # old jaxlib: build_plan resolves the manual TP lowering
+    stages, tp = 4, 2
     topo = make_test_topology(stages, tp)
     seq, m = 256, 8
     model = build_model(cfg)

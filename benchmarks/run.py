@@ -3,9 +3,8 @@
   PYTHONPATH=src python -m benchmarks.run [--quick]
 
 Jobs whose backend prerequisites are unavailable are SKIPPED, not crashed,
-and the SKIP line names the gating predicate (e.g.
-``compat.supports_partial_auto_spmd``) so a matrix-leg log says exactly WHY
-a job was gated. Job modules are imported lazily, exactly once, and only
+and the SKIP line names the gating predicate so a log says exactly WHY a
+job was gated. Job modules are imported lazily, exactly once, and only
 for the jobs actually selected — ``--only kvstore`` no longer pays the
 import cost (jax tracing setup included) of every other benchmark module.
 """
@@ -26,8 +25,6 @@ JOBS = {
     "kvstore": ("kvstore", "run",
                 "KV page store: max seq len vs kv_dtype + tier headroom",
                 None),
-    # was gated on compat.supports_partial_auto_spmd; the manual TP
-    # lowering (DESIGN.md §3.6) made tp=2 lower on old jaxlib too
     "kvstore_pipeline": ("kvstore", "pipeline_leg",
                          "Real-pipeline paged-pool bytes + wall time "
                          "(TP-sharded pool)", None),

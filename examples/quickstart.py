@@ -37,7 +37,7 @@ def main():
 
     cfg = replace(get_smoke_config(args.arch), dtype="float32")
     model = build_model(cfg)
-    tp = 2  # old jaxlib takes the manual TP lowering (compat.resolve_tp_lowering)
+    tp = 2
     topo = make_test_topology(num_stages=8 // tp, tp=tp)
     print(f"arch={args.arch} mesh={dict(topo.mesh.shape)} "
           f"stages={topo.num_stages} tp={topo.tp_size}")

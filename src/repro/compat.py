@@ -1,67 +1,32 @@
-"""JAX API-drift shims so the repo runs on both current and older jax.
+"""Thin JAX helpers shared across the repo.
 
-- ``shard_map``: jax >= 0.6 exposes ``jax.shard_map(..., axis_names=...,
-  check_vma=...)``; older releases have ``jax.experimental.shard_map`` with
-  the complementary ``auto``/``check_rep`` spelling. One entry point maps
-  between them (axis_names -> auto = mesh axes minus manual; check_vma ->
-  check_rep).
-- ``AxisType``: jax >= 0.5 types mesh axes explicitly
-  (``jax.sharding.AxisType.{Auto,Explicit,Manual}``); older meshes are
-  implicitly Auto. The shim exposes the real enum when present and a
-  placeholder otherwise so call sites can always say ``AxisType.Auto``.
-- ``make_mesh``: forwards ``axis_types`` only when the installed jax
-  understands it.
-- ``set_mesh``: jax >= 0.6 ``jax.set_mesh`` context manager; older jax uses
-  the mesh object itself as the context (``with mesh:``).
+- ``AxisType`` / ``make_mesh`` / ``set_mesh`` / ``shard_map``: the jax entry
+  points, re-exported so call sites import one module.
+- ``axis_types_kw``: ``{"axis_types": (Auto,) * n}`` for ``jax.make_mesh``.
+- ``ensure_host_devices``: fake host-platform devices for CPU runs.
+- ``resolve_tp_lowering``: validates ``RunConfig.tp_lowering``.
 """
 from __future__ import annotations
 
-import enum
-import inspect
-from typing import Optional
-
 import jax
+from jax.sharding import AxisType  # noqa: F401  (re-export)
 
-try:  # jax >= 0.5
-    from jax.sharding import AxisType  # noqa: F401  (re-export)
-except ImportError:  # older jax: every axis is implicitly Auto
-    class AxisType(enum.Enum):  # type: ignore[no-redef]
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-
-_MAKE_MESH_TAKES_AXIS_TYPES = (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters)
+make_mesh = jax.make_mesh
+set_mesh = jax.set_mesh
+shard_map = jax.shard_map
 
 
 def axis_types_kw(n: int) -> dict:
-    """``{"axis_types": (Auto,)*n}`` when the installed jax supports it."""
-    if _MAKE_MESH_TAKES_AXIS_TYPES:
-        return {"axis_types": (AxisType.Auto,) * n}
-    return {}
-
-
-def make_mesh(axis_shapes, axis_names, *, axis_types=None, **kw):
-    """``jax.make_mesh`` that drops ``axis_types`` on jax builds predating it."""
-    if axis_types is not None and _MAKE_MESH_TAKES_AXIS_TYPES:
-        kw["axis_types"] = axis_types
-    return jax.make_mesh(axis_shapes, axis_names, **kw)
-
-
-def set_mesh(mesh):
-    """Context manager activating ``mesh`` (jax.set_mesh or ``with mesh:``)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh  # old jax: Mesh is its own context manager
+    """``{"axis_types": (Auto,)*n}``: every mesh axis GSPMD-auto."""
+    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def ensure_host_devices(n: int = 8) -> None:
-    """Give a bare CPU host ``n`` fake host-platform devices (the chunked
-    pipeline needs >= 2). No-op when the flag is already set or real
-    accelerators exist — the flag only affects the host platform. Must run
-    before the first jax backend use (device queries, array ops); importing
-    jax is fine."""
+    """Give the CPU platform ``n`` fake host devices (the multi-stage
+    pipeline path on a CPU host). No-op when the flag is already set. Must
+    run before the first jax backend use (device queries, array ops);
+    importing jax is fine. Callers invoke it only when the platform is the
+    CPU — the flag never touches an accelerator."""
     import os
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
@@ -70,62 +35,11 @@ def ensure_host_devices(n: int = 8) -> None:
 
 
 def resolve_tp_lowering(requested: str = "auto") -> str:
-    """Resolve ``RunConfig.tp_lowering`` against the installed jaxlib.
-
-    "manual" is always honored. "auto" means GSPMD partial-auto SPMD when
-    the jaxlib can partition it inside shard_map, falling back to the
-    fully-manual lowering (explicit psums in the stage programs, all mesh
-    axes manual) on old jaxlib — which is what restores TP > 1 coverage
-    there (the old ``max_auto_tp`` tp=1 fallback is gone). The
-    ``REPRO_TP_LOWERING`` env var overrides the "auto" resolution (the CI
-    matrix uses it to pin the manual path on the old-jaxlib leg).
-    """
-    if requested == "manual":
-        return "manual"
-    if requested not in ("auto", "", None):
+    """Validate ``RunConfig.tp_lowering``: "auto" (GSPMD partial-auto
+    shard_map over the TP axis) or "manual" (all mesh axes manual, explicit
+    transport psums in the stage programs — DESIGN.md §3.6)."""
+    requested = requested or "auto"
+    if requested not in ("auto", "manual"):
         raise ValueError(f"unknown tp_lowering {requested!r}; "
                          "choose 'auto' or 'manual'")
-    import os
-    env = os.environ.get("REPRO_TP_LOWERING")
-    if env in ("auto", "manual"):
-        return env
-    return "auto" if supports_partial_auto_spmd() else "manual"
-
-
-def supports_partial_auto_spmd() -> bool:
-    """True when shard_map over a SUBSET of mesh axes (manual stage axis,
-    GSPMD-auto TP axis of size > 1) can be partitioned by the installed
-    jaxlib. Old jaxlib rejects the lowering with "UNIMPLEMENTED: PartitionId
-    instruction is not supported for SPMD partitioning", so pipeline runs
-    there must keep every non-manual axis at size 1 (tp = 1). The
-    ``jax.shard_map`` attribute doubles as the capability marker: it appeared
-    alongside the partitioner fix.
-    """
-    return hasattr(jax, "shard_map")
-
-
-def shard_map(f, *, mesh, in_specs, out_specs,
-              axis_names: Optional[frozenset] = None,
-              check_vma: Optional[bool] = None):
-    if hasattr(jax, "shard_map"):
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    kw = {}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    mapped = _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-    def with_mesh_ctx(*args):
-        # old jax resolves PartitionSpec-based with_sharding_constraint
-        # inside the body against the ambient mesh context
-        with mesh:
-            return mapped(*args)
-    return with_mesh_ctx
+    return requested

@@ -217,7 +217,9 @@ class RunConfig:
     num_chunks: int = 16
     num_stages: int = 16
     mbkr: bool = True
-    mbkr_spill_chunks: int = 0  # 0 -> auto (N//4)
+    # chunks per request that spill to the pair stage (M - p2); 0 -> the
+    # p2 with the fewest slots (mbkr.best_p2)
+    mbkr_spill_chunks: int = 0
     kv_spill_dtype: str = "bfloat16"  # beyond-paper: int8 spill compression
     remote_attn: str = "qship"  # fetch (paper-faithful) | qship (beyond-paper)
     # attention inner-loop implementation (core.attention registry):
@@ -253,10 +255,8 @@ class RunConfig:
     # counts don't divide). "auto": plain 16-way model axis.
     attn_sharding: str = "auto"
     # TP lowering strategy (core.transport / DESIGN.md §3.6): "auto" =
-    # GSPMD partial-auto shard_map (falls back to "manual" on old jaxlib,
-    # which cannot partition it — see compat.resolve_tp_lowering);
-    # "manual" = all mesh axes manual, explicit transport psums in the
-    # stage programs. Restores TP > 1 on the old-jaxlib CI leg.
+    # GSPMD partial-auto shard_map; "manual" = all mesh axes manual,
+    # explicit transport psums in the stage programs.
     tp_lowering: str = "auto"
     # transport registry entry (core.transport): how cross-stage/cross-rank
     # collectives lower. "jax" = jax.lax collectives; future TPU-native

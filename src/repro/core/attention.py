@@ -297,10 +297,9 @@ class PagedPallasBackend(PallasBackend):
     def pool_block(self, qg, kq, vq, ks, vs, valid, scale,
                    st: State) -> State:
         """Stacked-interface entry (the batched-fetch landing path): view
-        the landed chunk stack [S, B, C, K, D] as a page store with identity
-        handles — [S*ppc, B, pt, K, D] pages — and reuse the paged kernel.
-        With ppc == 1 (passthrough codec) the view is a free reshape; per-
-        page quantized stacks pay one small staging-buffer transpose (the
+        the landed chunk stack [S, B, C, K, D] as a head-major page store
+        with identity handles — [S*ppc, B, K, pt, D] pages — and reuse the
+        paged kernel. That costs one small staging-buffer transpose (the
         staging buffer is n_remote chunks, not the pool)."""
         from repro.kernels import ops
         s, b_, ck, kvh_, d_ = kq.shape
@@ -309,13 +308,13 @@ class PagedPallasBackend(PallasBackend):
 
         def pageize(x):
             x = x.reshape(s, b_, ppc, pt, kvh_, d_)
-            return x.transpose(0, 2, 1, 3, 4, 5).reshape(
-                s * ppc, b_, pt, kvh_, d_)
+            return x.transpose(0, 2, 1, 4, 3, 5).reshape(
+                s * ppc, b_, kvh_, pt, d_)
 
         ksc = vsc = None
-        if ks is not None:  # [S, ppc, B, 1, K, 1] -> [S*ppc, B, 1, K, 1]
-            ksc = ks.reshape(s * ppc, *ks.shape[2:])
-            vsc = vs.reshape(s * ppc, *vs.shape[2:])
+        if ks is not None:  # [S, ppc, B, 1, K, 1] -> [S*ppc, B, K, 1, 1]
+            ksc = jnp.swapaxes(ks, -3, -2).reshape(s * ppc, b_, kvh_, 1, 1)
+            vsc = jnp.swapaxes(vs, -3, -2).reshape(s * ppc, b_, kvh_, 1, 1)
         handles = jnp.arange(s * ppc, dtype=jnp.int32)
         b, c, kvh, g, d = qg.shape
         q = qg.reshape(b, c, kvh * g, d)
@@ -355,8 +354,8 @@ def pool_scan(backend: AttentionBackend, qg, pool_l, slot_pages, slot_chunk,
     """Accumulate attention over pool slots whose stored chunk < ``limit``.
 
     ``pool_l`` = (k_l, v_l, ks_l, vs_l): THIS layer's slices of the paged
-    pool — payloads [P, B, page_tokens, K, D] plus per-head scales (None for
-    a passthrough codec). ``slot_pages`` [slots+1, ppc] is the page table;
+    pool — head-major payloads [P, B, K, page_tokens, D] plus per-head
+    scales [P, B, K, 1, 1] (None for a passthrough codec). ``slot_pages`` [slots+1, ppc] is the page table;
     each visited slot's pages are gathered, and the ENCODED chunk goes to
     ``chunk_block_q`` (dequant-on-read is the backend's business).
     ``slots``: optional static subset of slot indices to visit (the creditor

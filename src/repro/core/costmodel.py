@@ -41,6 +41,25 @@ TPU_V5E = HardwareProfile("tpu-v5e", 197e12, 819e9, 16e9, 50e9, (16, 16))
 
 PROFILES = {p.name: p for p in (WSC_PAPER, GPU_HGX, TPU_V5E)}
 
+# jax.devices()[0].device_kind -> the profile the jax executor prices with.
+# A TPU kind missing here is an error, never a silent v5e default.
+DEVICE_PROFILES = {"TPU v5 lite": TPU_V5E}
+
+
+def device_profile(device) -> "HardwareProfile":
+    """The ``HardwareProfile`` of a JAX device. CPU runs (tests, Pallas
+    interpret mode) are priced with the v5e datasheet; an accelerator kind
+    without a profile raises."""
+    if device.platform == "cpu":
+        return TPU_V5E
+    try:
+        return DEVICE_PROFILES[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no HardwareProfile for {device.platform} device_kind "
+            f"{device.device_kind!r}; add it to costmodel.DEVICE_PROFILES"
+        ) from None
+
 ProfileSpec = Union[HardwareProfile, str]
 
 

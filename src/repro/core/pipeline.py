@@ -39,7 +39,8 @@ from repro.core.attention import NEG_INF  # noqa: F401  (re-export)
 from repro.core.gpipe import gpipe_prefill
 from repro.core.plan import PipelinePlan, build_plan  # noqa: F401
 from repro.core.staging import (Params, alloc_kv_pool,  # noqa: F401
-                                batch_specs, kv_split_axes, manual_only,
+                                auto_tp, batch_specs, init_stage_params,
+                                kv_split_axes, manual_only,
                                 manual_tp_plan, manual_tree, pad_experts,
                                 pad_q_heads, stage_param_specs, stage_params)
 from repro.kvstore.pages import PagedPool
@@ -68,7 +69,7 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
                      prefix_chunks: int = 0,
                      prefix_pool: Optional[PagedPool] = None,
                      return_kv: bool = False,
-                     tick_hook=None, health=None) -> jax.Array:
+                     health=None) -> jax.Array:
     """Chunked-pipeline prefill of ``tokens`` [B, S]; returns next-token
     logits [B, Vpad] (prefill-only: ONE output token, KV is discarded).
 
@@ -88,13 +89,6 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
     short-circuits, so the compiled program is identical. Return order is
     ``logits[, ledger][, telemetry]``.
 
-    ``tick_hook``: ZERO-ARG host callback fired (via ``jax.debug.callback``)
-    at the END of every tick on every shard — the measured-span beacon
-    (``obs.profile.TickSpanCollector.note``). It takes no operands because
-    this jaxlib's SPMD partitioner rejects operand-carrying callbacks inside
-    the manual shard_map region; tick identity is recovered host-side from
-    arrival order (the scan runs ticks in order).
-
     ``health``: an ``obs.health.HealthMonitor``; arms the non-finite
     activation sentinel. Per-(stage, tick) finite-counts of the stage
     output (gated to ACTIVE phases so bubble garbage never pages anyone)
@@ -104,7 +98,7 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
     end-of-run delivery gather of one tiny int32 array — O(1), not
     O(ticks).
 
-    Both default to None, in which case NOTHING extra is traced — the
+    It defaults to None, in which case NOTHING extra is traced — the
     compiled program is bit-identical (proven in tests/test_calibration.py,
     same style as the telemetry-off proof).
 
@@ -124,8 +118,8 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
     """
     if plan.mode == "gpipe":
         assert not return_ledger, "gpipe has no MBKR transport ledger"
-        assert tick_hook is None and health is None, \
-            "tick_hook/health probe only the chunked-pipeline driver"
+        assert health is None, \
+            "health probes only the chunked-pipeline driver"
         assert prefix_chunks == 0 and prefix_pool is None and not return_kv, \
             "prefix KV cache rides the chunked-pipeline paged pool only"
         return gpipe_prefill(cfg, staged, tokens, plan, topo,
@@ -223,7 +217,7 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
 
         # sequence-parallel residual is a GSPMD-auto-only optimization: the
         # manual lowering keeps the residual stream replicated across TP
-        seq_sharded = (mtp is None and isinstance(topo.tp_axis, tuple)
+        seq_sharded = (auto_tp(topo, mtp) and isinstance(topo.tp_axis, tuple)
                        and c % topo.tp_size == 0 and not is_ssm)
         x_spec = P(None, topo.tp_axis, None) if seq_sharded \
             else P(None, None, None)
@@ -259,7 +253,7 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
             if cfg.embedding_multiplier != 1.0:
                 x_emb = x_emb * cfg.embedding_multiplier
             x = jnp.where(stage == 0, x_emb.astype(dt), x_prev)
-            if mtp is None:
+            if auto_tp(topo, mtp):
                 x = jax.lax.with_sharding_constraint(x, x_spec)
             # ---- stage compute
             if is_ssm:
@@ -284,18 +278,14 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
             ring_active = (phase >= 0) & (phase < m) & (stage < n - 1)
             x_next, led = transport.ring_shift(x_out, st_ax, ring_perm, led,
                                                active=ring_active)
-            # ---- sentinels / probes: traced ONLY when armed (None = the
-            # compiled program is bit-identical, zero extra collectives).
-            # Non-finite counts ride the scan ys OUT of the manual region —
-            # operand-carrying debug callbacks inside manual shard_map are
-            # unsupported by this jaxlib's SPMD partitioner, so the only
-            # in-region callback is the zero-arg tick beacon.
+            # ---- sentinels: traced ONLY when armed (None = the compiled
+            # program is bit-identical, zero extra collectives). Non-finite
+            # counts ride the scan ys OUT of the manual region: no host
+            # callback may sit inside manual shard_map (Shardy rejects it)
             bad = None
             if health is not None:
                 nbad = jnp.sum(~jnp.isfinite(x_out.astype(jnp.float32)))
                 bad = jnp.where(ctx.active, nbad, 0).astype(jnp.int32)
-            if tick_hook is not None:
-                jax.debug.callback(tick_hook)
             return (x_next, pool, state, x_last, led, tel), (tel_ys, bad)
 
         tel0 = obs_t.telemetry_init() if return_telemetry else None
@@ -330,12 +320,12 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
     if prefix_pool is not None:
         extra["prefix_pool"] = prefix_pool
 
-    # one spec covers every pool leaf: [n, P, lps, B, pt|1, kvh, hd|1] —
+    # one spec covers every pool leaf: [n, P, lps, B, kvh, pt|1, hd|1] —
     # stage axis leads, batch is pod-sharded, kv heads carry the manual TP
     # axes (kv_div == tp is asserted above); under GSPMD-auto the kv-split
     # sharding flows from the argument's actual sharding instead
-    kv_leaf_spec = P(st_ax, None, None, pod_axes if pod_axes else None, None,
-                     mtp.axes if mtp is not None else None, None)
+    kv_leaf_spec = P(st_ax, None, None, pod_axes if pod_axes else None,
+                     mtp.axes if mtp is not None else None, None, None)
 
     specs = stage_param_specs(cfg, plan, topo)
     sl_specs = manual_tree(specs["stage_layers"], manual)

@@ -39,9 +39,8 @@ class PipelinePlan:
     remote_attn: str = "qship"   # fetch | qship
     attn_backend: str = "jnp"    # jnp | pallas (core.attention registry)
     transport: str = "jax"       # core.transport registry entry
-    tp_lowering: str = "auto"    # RESOLVED: auto (GSPMD) | manual (explicit
-                                 # transport psums, all mesh axes manual) —
-                                 # compat.resolve_tp_lowering decides "auto"
+    tp_lowering: str = "auto"    # auto (GSPMD) | manual (explicit
+                                 # transport psums, all mesh axes manual)
     fetch_batch: str = "auto"    # auto | on | off: land fetched chunk-layers
                                  # in a staging buffer and run ONE
                                  # pool_attention launch ("auto" follows the
@@ -127,7 +126,11 @@ def build_plan(cfg: ModelConfig, num_stages: int, seq_len: int,
     assert seq_len % m == 0, f"seq_len {seq_len} must divide into {m} chunks"
     c = seq_len // m
     use_mbkr = mode == "mocap" and not cfg.attn_free and num_stages >= 2 and m >= 2
-    mp = mbkr.plan(m, num_stages, mbkr=use_mbkr)
+    spill = run.mbkr_spill_chunks
+    if not 0 <= spill < m:
+        raise ValueError(f"mbkr_spill_chunks {spill} must lie in [0, {m})")
+    mp = mbkr.plan(m, num_stages, p2=m - spill if spill else None,
+                   mbkr=use_mbkr)
     codec = kvquant.get_codec(run.kv_dtype, cfg.dtype)
     geom = kvpages.page_geometry(c, mp.num_slots, run.kv_page_tokens)
     slot_pages = kvpages.build_slot_pages(geom)

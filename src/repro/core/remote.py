@@ -103,8 +103,8 @@ def host_table(ctx) -> jax.Array:
 
 
 def _pool_layer(pool: kvpages.PagedPool, l_idx: jax.Array):
-    """Slice one layer out of the paged pool: payloads [P, B, pt, K, D] +
-    scales [P, B, 1, K, 1] (None when passthrough)."""
+    """Slice one layer out of the paged pool: payloads [P, B, K, pt, D] +
+    scales [P, B, K, 1, 1] (None when passthrough)."""
     sl = lambda a: jax.lax.dynamic_index_in_dim(a, l_idx, axis=1,
                                                 keepdims=False)
     ks = sl(pool.k_scale) if pool.k_scale is not None else None

@@ -35,7 +35,7 @@ from repro.core import remote
 from repro.core.attention import (attn_finish, attn_init, get_backend,
                                   group_queries, pool_scan)
 from repro.core.plan import PipelinePlan
-from repro.core.staging import ManualTP, _hyb_scfg
+from repro.core.staging import ManualTP, _hyb_scfg, auto_tp
 from repro.core import transport as tx
 from repro.core.transport import Ledger, Transport
 from repro.obs import telemetry as obs_t
@@ -73,6 +73,11 @@ class StageCtx:
         """My phase is a real chunk this tick (not fill/drain garbage)."""
         return (self.phase >= 0) & (self.phase < self.plan.num_chunks)
 
+    @property
+    def auto_tp(self) -> bool:
+        """Sharding constraints may name the TP axes."""
+        return auto_tp(self.topo, self.mtp)
+
 
 def _tp_apply(ctx: StageCtx) -> Optional[T.ManualTPApply]:
     """Build the model-layer manual-TP hooks (psum closures) from the plan.
@@ -105,7 +110,7 @@ def attend_chunk(ctx: StageCtx, l_idx: jax.Array, q: jax.Array,
     ``(att, ledger, telemetry)``.
 
     q [B,C,H,D]; k_new/v_new [B,C,K,D]; ``pool`` is the stage's paged KV
-    store (``kvstore.pages.PagedPool``: payloads [P, lps, B, pt, K, D] +
+    store (``kvstore.pages.PagedPool``: payloads [P, lps, B, K, pt, D] +
     per-head scales when quantized). Under manual TP the shapes are the
     LOCAL shards (heads grouped per local kv head).
 
@@ -200,7 +205,7 @@ def tfm_stage_step(ctx: StageCtx, layers: Params, x: jax.Array,
             k = L.rms_norm(k, lp["k_norm"], cfg.norm_eps)
         q = L.apply_rope(q, cos, sin)
         k = L.apply_rope(k, cos, sin)
-        if mtp is None:
+        if ctx.auto_tp:
             q = jax.lax.with_sharding_constraint(
                 q, P(None, None, ctx.topo.tp_axis, None))
             if isinstance(ctx.topo.tp_axis, tuple):
@@ -235,7 +240,7 @@ def tfm_stage_step(ctx: StageCtx, layers: Params, x: jax.Array,
             xc = xc + updx
             tel = obs_t.charge(tel, "launches", 1.0, None, _rep(ctx))
         ep_axis = ctx.topo.tp_axis if (cfg.moe is not None and isinstance(
-            ctx.topo.tp_axis, tuple) and mtp is None) else None
+            ctx.topo.tp_axis, tuple) and ctx.auto_tp) else None
         if ep_axis is not None:
             # EP dispatch gathers tokens arbitrarily: replicate x first
             xc = jax.lax.with_sharding_constraint(xc, P(None, None, None))
@@ -246,7 +251,7 @@ def tfm_stage_step(ctx: StageCtx, layers: Params, x: jax.Array,
         # kv_split: keep the residual stream SEQUENCE-SHARDED between layers
         # (Megatron-SP): psums become reduce-scatters and the stage-boundary
         # ring permute moves C/tp tokens per chip instead of C
-        if mtp is None:
+        if ctx.auto_tp:
             xc = jax.lax.with_sharding_constraint(xc, ctx.x_spec)
         return (xc, li + 1, led, tel), (k, v)
 

@@ -137,7 +137,7 @@ def alloc_kv_pool(cfg: ModelConfig, plan: PipelinePlan, b: int,
                   mtp: Optional[ManualTP] = None) -> kvpages.PagedPool:
     """One stage's paged KV pool, zero-initialized in the plan's storage
     codec; kv_split meshes get the pool sharded by kv head (payloads AND
-    scales carry kvh on axis 4). Under the MANUAL lowering the body is
+    scales carry kvh on axis 3). Under the MANUAL lowering the body is
     mapped over the TP axes too, so the pool is allocated with the LOCAL
     kv-head count and no sharding hint."""
     kvh = cfg.num_kv_heads
@@ -146,8 +146,9 @@ def alloc_kv_pool(cfg: ModelConfig, plan: PipelinePlan, b: int,
         kvh //= mtp.kv_div
     pool = kvpages.alloc_pool(plan.page_geometry, plan.codec,
                               plan.layers_per_stage, b, kvh, hd)
-    if mtp is None and topo is not None and isinstance(topo.tp_axis, tuple):
-        spec = P(None, None, None, None, topo.tp_axis[0], None)
+    if (topo is not None and isinstance(topo.tp_axis, tuple)
+            and auto_tp(topo, mtp)):
+        spec = P(None, None, None, topo.tp_axis[0], None, None)
         shard = lambda a: (jax.lax.with_sharding_constraint(a, spec)
                            if a is not None else None)
         pool = kvpages.PagedPool(shard(pool.k), shard(pool.v),
@@ -203,6 +204,23 @@ def stage_params(cfg: ModelConfig, params: Params, plan: PipelinePlan) -> Params
     if "lm_head" in params:
         out["lm_head"] = params["lm_head"]
     return out
+
+
+def init_stage_params(cfg: ModelConfig, plan: PipelinePlan,
+                      topo: Topology, key: jax.Array) -> Params:
+    """Random parameters built directly in the ``stage_params`` layout: ONE
+    jitted init + restack whose outputs carry the ``stage_param_specs``
+    shardings, so each device only ever holds its stage's layer slice plus
+    the replicated embedding / head — no full flat copy on the first device
+    and no second copy from a host-side restack."""
+    from jax.sharding import NamedSharding
+    from repro.models.api import build_model
+    model = build_model(cfg)
+    shardings = jax.tree.map(lambda s: NamedSharding(topo.mesh, s),
+                             stage_param_specs(cfg, plan, topo),
+                             is_leaf=lambda x: isinstance(x, P))
+    return jax.jit(lambda k: stage_params(cfg, model.init(k), plan),
+                   out_shardings=shardings)(key)
 
 
 def stage_param_specs(cfg: ModelConfig, plan: PipelinePlan, topo: Topology) -> Params:
@@ -262,13 +280,21 @@ def _stage_param_specs(cfg: ModelConfig, plan: PipelinePlan, topo: Topology) -> 
 
 def batch_specs(topo: Topology, mtp: Optional[ManualTP] = None):
     """(manual shard_map axis_names, batch axes outside the stage axis).
-    The manual TP lowering adds the TP axes to the manual set — the whole
-    mesh is then manual, which is what old jaxlib can partition."""
+    The manual TP lowering adds the TP axes to the manual set, and so does
+    a size-1 TP axis: the whole mesh is then manual, which is where Mosaic
+    (Pallas TPU) kernels lower — they cannot be auto-partitioned."""
     pod_axes = tuple(a for a in topo.batch_axes if a != topo.stage_axis)
     manual = set(pod_axes) | {topo.stage_axis}
-    if mtp is not None:
-        manual |= set(mtp.axes)
+    if mtp is not None or topo.tp_size == 1:
+        md = topo.tp_axis
+        manual |= set(md if isinstance(md, tuple) else (md,))
     return manual, pod_axes
+
+
+def auto_tp(topo: Topology, mtp: Optional[ManualTP]) -> bool:
+    """GSPMD partitions the TP axes inside the pipeline region (the only
+    case in which sharding constraints may name them there)."""
+    return mtp is None and topo.tp_size > 1
 
 
 def manual_only(spec: P, manual) -> P:

@@ -14,8 +14,7 @@ files; now the pipeline path goes through a ``Transport``:
 - ``tp_psum`` / ``tp_reduce_scatter`` / ``tp_all_gather``
                       tensor-parallel collectives for the MANUAL TP lowering
                       (``RunConfig.tp_lowering="manual"``: explicit psums in
-                      the stage programs instead of GSPMD partial-auto, which
-                      old jaxlib cannot partition inside shard_map).
+                      the stage programs instead of GSPMD partial-auto).
 
 Transports are registered like attention backends (``register_transport``),
 so future comm optimizations — TPU-native qship DMA, in-pipeline cold

@@ -111,11 +111,9 @@ class FleetFabric:
                              router_rejections=self.router.rejections)
 
     def configure_obs(self, *, telemetry: Optional[bool] = None,
-                      measured: Optional[bool] = None,
                       health: Any = None) -> None:
         for cell in self.cells.values():
-            cell.configure_obs(telemetry=telemetry, measured=measured,
-                               health=health)
+            cell.configure_obs(telemetry=telemetry, health=health)
 
     def recalibrate(self, name: str, hw: Any) -> Any:
         """Swap ONE cell onto a calibrated profile (per-cell calibration is

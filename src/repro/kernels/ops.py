@@ -1,14 +1,14 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Handles: head-dim padding to the 128-lane width, KV padding to block
-multiples, CPU fallback to ``interpret=True`` (the container has no TPU;
-kernels are validated in interpret mode and TARGET TPU — see DESIGN.md).
+multiples, and the token-major <-> head-major transposes at the kernel
+boundary (callers pass ``[B, C, H, D]``; the kernels tile ``[B, H, C, D]``,
+see ``chunk_attn``). On a TPU backend every kernel compiles through Mosaic;
+off-TPU (CPU tests) they run in Pallas interpret mode.
 """
 from __future__ import annotations
 
-import contextlib
 import math
-import time
 from functools import partial
 from typing import Optional
 
@@ -26,77 +26,47 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-# --------------------------------------------------- launch-counting test hook
-# Counts RUNTIME kernel invocations (a kernel traced once inside a lax.scan
-# body still launches once per iteration — the thing the batched pool kernel
-# amortizes), via a debug callback staged next to each pallas_call.
-#
-# A STACK of counter frames makes the hook nesting-safe (an outer harness
-# and an inner assertion both observe their own window) and each frame keys
-# launches by kernel tag next to the all-kernel "count", so obs can
-# attribute launches to the self-block vs pool vs fetch paths.
+# ------------------------------------------------------- launch counting
+# Kernel launches are counted from the TRACED program, not at run time: each
+# jitted wrapper below appears once per call site in the jaxpr, and a call
+# site inside a ``lax.scan`` body launches once per iteration. Nothing is
+# staged into the program, so counting costs no host callback (and none
+# lands inside a manual shard_map region).
 
-_LAUNCH_FRAMES: list = []
+KERNEL_TAGS = ("chunk_attention", "pool_attention", "pool_attention_paged",
+               "ssd", "decode_attention")
 
 
-def _note_launch(tag: str) -> None:
-    if not _LAUNCH_FRAMES:  # read at TRACE time: zero cost when unused
-        return
+def _count_jaxpr(jaxpr, mult: int, out: dict) -> None:
+    from jax.extend import core as jex_core
+    for eqn in jaxpr.eqns:
+        name = eqn.params.get("name")
+        if eqn.primitive.name in ("jit", "pjit") and name in KERNEL_TAGS:
+            out["count"] += mult
+            out[name] = out.get(name, 0) + mult
+            continue
+        if eqn.primitive.name == "while":
+            raise ValueError("count_launches: a while loop has no static "
+                             "trip count")
+        inner = mult * int(eqn.params.get("length", 1))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(sub, jex_core.ClosedJaxpr):
+                    _count_jaxpr(sub.jaxpr, inner, out)
+                elif isinstance(sub, jex_core.Jaxpr):
+                    _count_jaxpr(sub, inner, out)
 
-    def _bump():
-        now = time.perf_counter()
-        for frame in _LAUNCH_FRAMES:
-            frame["count"] += 1
-            frame[tag] = frame.get(tag, 0) + 1
-            ev = frame.get("events")
-            if ev is not None:
-                ev.append((tag, now))
 
-    jax.debug.callback(_bump)
-
-
-@contextlib.contextmanager
-def count_launches(timed: bool = False):
-    """Context manager: count Pallas kernel launches executed inside.
-
-        with ops.count_launches() as launches:
-            fn(*args)  # must TRACE inside the context (caches are cleared)
-        assert launches["count"] == ...
-        assert launches["pool_attention"] == ...   # per-kernel attribution
-
-    The yielded dict holds the all-kernel ``"count"`` plus one key per
-    kernel tag (``chunk_attention`` / ``pool_attention`` /
-    ``pool_attention_paged`` / ``ssd`` / ``decode_attention``) that
-    launched at least once. Contexts nest: every
-    active frame counts every launch in its window.
-
-    ``timed=True`` additionally records ``frame["events"]`` — the ordered
-    ``(tag, perf_counter)`` stream — and ``frame["t0"]`` at entry, the raw
-    material for per-kernel-tag span attribution
-    (``obs.profile.kernel_tag_times``). Callers may rebase ``frame["t0"]``
-    right before dispatch to exclude compile time from the first span.
-
-    The stack is read at trace time, so the wrappers' jit caches are
-    cleared on entry/exit — callers pay a retrace, tests only."""
-    jitted = (chunk_attention, pool_attention, pool_attention_paged, ssd,
-              decode_attention)
-    frame = {"count": 0}
-    if timed:
-        frame["events"] = []
-        frame["t0"] = time.perf_counter()
-    for f in jitted:
-        f.clear_cache()
-    _LAUNCH_FRAMES.append(frame)
-    try:
-        yield frame
-    finally:
-        # debug callbacks flush asynchronously under real (TPU) dispatch —
-        # block_until_ready() alone does not order them before the caller's
-        # read of launches["count"]
-        jax.effects_barrier()
-        _LAUNCH_FRAMES.remove(frame)
-        for f in jitted:
-            f.clear_cache()
+def count_launches(fn, *args, **kwargs) -> dict:
+    """Pallas kernel launches one call of ``fn(*args, **kwargs)`` issues:
+    kernel call sites in the traced program, each multiplied by the trip
+    counts of the scans around it (launches per tick x ticks for the
+    pipeline). Returns ``{"count": total, <tag>: n, ...}`` with one key per
+    kernel tag in ``KERNEL_TAGS`` that launches at least once. ``fn`` is
+    traced, not run."""
+    out = {"count": 0}
+    _count_jaxpr(jax.make_jaxpr(fn)(*args, **kwargs).jaxpr, 1, out)
+    return out
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -109,6 +79,17 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
+def _heads_major(x: jax.Array) -> jax.Array:
+    """[..., T, H, D] <-> [..., H, T, D]."""
+    return jnp.swapaxes(x, -3, -2)
+
+
+def _state_out(m, l, acc, d: int):
+    """Kernel state rows [B, H, 1, C] + acc [B, H, C, Dp] -> the wrappers'
+    (m, l) [B, H, C] + token-major acc [B, C, H, D]."""
+    return m[:, :, 0], l[:, :, 0], _heads_major(acc[..., :d])
+
+
 @partial(jax.jit, static_argnames=("causal_offset", "scale", "block_q",
                                    "block_k", "return_state"))
 def chunk_attention(q, k, v, *, causal_offset: int = 0,
@@ -119,15 +100,15 @@ def chunk_attention(q, k, v, *, causal_offset: int = 0,
                     k_scale=None, v_scale=None):
     """Chunked-prefill flash attention (MOCAP hot spot). See chunk_attn.py.
 
-    ``return_state=True`` also returns the fp32 online-softmax residuals
-    ``(m, l) [B, H, C]`` and the unnormalized fp32 accumulator
-    ``acc [B, C, H, D]`` so partial results combine across KV sources at
-    full precision — used by the pipeline's "pallas" attention backend
-    (core.attention).
+    q [B, C, H, D]; k, v [B, T, KVH, D]. ``return_state=True`` also returns
+    the fp32 online-softmax residuals ``(m, l) [B, H, C]`` and the
+    unnormalized fp32 accumulator ``acc [B, C, H, D]`` so partial results
+    combine across KV sources at full precision — used by the pipeline's
+    "pallas" attention backend (core.attention).
 
     ``k_scale``/``v_scale`` [B, T, KVH]: k/v are quantized KV-page payloads
     (``repro.kvstore``, one scale row per kv token) and the kernel
-    dequantizes in its epilogue.
+    dequantizes after the block load.
     """
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
@@ -136,21 +117,20 @@ def chunk_attention(q, k, v, *, causal_offset: int = 0,
     while c % bq:
         bq //= 2
     bk = min(block_k, t)
-    qp = _pad_to(q, 3, LANE)
-    kp = _pad_to(_pad_to(k, 3, LANE), 1, bk)
-    vp = _pad_to(_pad_to(v, 3, LANE), 1, bk)
-    if k_scale is not None:
-        k_scale = _pad_to(k_scale, 1, bk)  # pad rows are masked via kv_len
-        v_scale = _pad_to(v_scale, 1, bk)
-    _note_launch("chunk_attention")
+    qp = _heads_major(_pad_to(q, 3, LANE))
+    kp = _heads_major(_pad_to(_pad_to(k, 3, LANE), 1, bk))
+    vp = _heads_major(_pad_to(_pad_to(v, 3, LANE), 1, bk))
+    if k_scale is not None:  # pad rows are masked via kv_len
+        k_scale = jnp.swapaxes(_pad_to(k_scale, 1, bk), 1, 2)[..., None]
+        v_scale = jnp.swapaxes(_pad_to(v_scale, 1, bk), 1, 2)[..., None]
     res = _ca.chunk_attention_pallas(
         qp, kp, vp, causal_offset=causal_offset, scale=scale, kv_len=t,
         block_q=bq, block_k=bk, interpret=not _on_tpu(),
         return_state=return_state, k_scale=k_scale, v_scale=v_scale)
     if return_state:
         out, m, l, acc = res
-        return out[..., :d], m, l, acc[..., :d]
-    return res[..., :d]
+        return (_heads_major(out[..., :d]),) + _state_out(m, l, acc, d)
+    return _heads_major(res[..., :d])
 
 
 @partial(jax.jit, static_argnames=("scale", "block_q", "block_k"))
@@ -164,8 +144,8 @@ def pool_attention(q, k, v, valid, *, scale: Optional[float] = None,
     q [B, C, H, D]; k, v [S, B, T, KVH, D] — a stack of S stored chunks,
     each fully visible; ``valid`` [S] bool/int gates slots (False slot ==
     identity-state contribution, exactly). ``k_scale``/``v_scale``
-    [S, B, T, KVH]: quantized page payloads, dequantized in the kernel
-    epilogue. Returns the fp32 online-softmax state ``(m, l) [B, H, C]`` +
+    [S, B, T, KVH]: quantized page payloads, dequantized after the block
+    load. Returns the fp32 online-softmax state ``(m, l) [B, H, C]`` +
     unnormalized ``acc [B, C, H, D]`` for the caller's combine chain —
     the launch count is O(1) in pool depth instead of O(slots)."""
     d = q.shape[-1]
@@ -175,75 +155,59 @@ def pool_attention(q, k, v, valid, *, scale: Optional[float] = None,
     while c % bq:
         bq //= 2
     bk = min(block_k, t)
-    qp = _pad_to(q, 3, LANE)
-    kp = _pad_to(_pad_to(k, 4, LANE), 2, bk)
-    vp = _pad_to(_pad_to(v, 4, LANE), 2, bk)
-    if k_scale is not None:
-        k_scale = _pad_to(k_scale, 2, bk)  # pad rows are masked via kv_len
-        v_scale = _pad_to(v_scale, 2, bk)
-    _note_launch("pool_attention")
+    qp = _heads_major(_pad_to(q, 3, LANE))
+    kp = _heads_major(_pad_to(_pad_to(k, 4, LANE), 2, bk))
+    vp = _heads_major(_pad_to(_pad_to(v, 4, LANE), 2, bk))
+    if k_scale is not None:  # pad rows are masked via kv_len
+        k_scale = jnp.swapaxes(_pad_to(k_scale, 2, bk), 2, 3)[..., None]
+        v_scale = jnp.swapaxes(_pad_to(v_scale, 2, bk), 2, 3)[..., None]
     m, l, acc = _ca.pool_attention_pallas(
-        qp, kp, vp, valid.astype(jnp.int32).reshape(-1, 1),
-        scale=scale, kv_len=t, block_q=bq, block_k=bk,
+        qp, kp, vp, valid, scale=scale, kv_len=t, block_q=bq, block_k=bk,
         interpret=not _on_tpu(), k_scale=k_scale, v_scale=v_scale)
-    return m, l, acc[..., :d]
+    return _state_out(m, l, acc, d)
 
 
-def _paged_use_dma() -> bool:
-    """The paged kernel's buffering scheme: manual double-buffered
-    ``make_async_copy`` by default (the TPU-native path, also exercised in
-    interpret mode so both CI legs validate it); ``REPRO_PAGED_DMA=0`` falls
-    back to automatically pipelined handle-indexed BlockSpecs — same
-    zero-gather property, for environments whose interpret mode lacks DMA
-    support."""
-    import os
-    return os.environ.get("REPRO_PAGED_DMA", "1") != "0"
-
-
-@partial(jax.jit, static_argnames=("ppc", "scale", "kv_len", "block_q",
-                                   "use_dma"))
+@partial(jax.jit, static_argnames=("ppc", "scale", "kv_len", "block_q"))
 def pool_attention_paged(q, k_pages, v_pages, handles, valid, *, ppc: int,
                          scale: Optional[float] = None,
                          kv_len: Optional[int] = None,
                          block_q: int = _ca.DEFAULT_BLOCK_Q,
-                         k_scale=None, v_scale=None,
-                         use_dma: Optional[bool] = None):
+                         k_scale=None, v_scale=None):
     """Ragged paged pool attention (MOCAP pool scan, single launch, ZERO
     gather). See ``chunk_attn.pool_attention_paged_pallas``.
 
-    q [B, C, H, D]; ``k_pages``/``v_pages`` [P, B, pt, KVH, D] — the page
-    store's layer slice in STORAGE dtype, read in place (``pltpu.ANY``);
-    ``handles`` [S*ppc] int32 flattened page-handle rows; ``valid`` [S]
-    bool/int per-slot occupancy (both scalar-prefetched into SMEM).
-    ``k_scale``/``v_scale`` [P, B, 1, KVH, 1] fp32: the pool's per-page
-    scales, dequantized on the VMEM landing buffer. ``kv_len`` < ppc*pt
-    handles a partial last page. Returns the fp32 online-softmax state like
-    ``pool_attention`` — one launch per (layer, tick), O(1) in pool depth,
-    and HBM traffic O(resident pages), not O(padded pool)."""
+    q [B, C, H, D]; ``k_pages``/``v_pages`` [P, B, KVH, pt, D] — the
+    head-major page store's layer slice in STORAGE dtype, read in place
+    (``pl.ANY``); ``handles`` [S*ppc] int32 flattened page-handle rows;
+    ``valid`` [S] bool/int per-slot occupancy (both scalar-prefetched into
+    SMEM). ``k_scale``/``v_scale`` [P, B, KVH, 1, 1] fp32: the pool's
+    per-page scales, dequantized on the VMEM landing buffer. ``kv_len`` <
+    ppc*pt handles a partial last page. Returns the
+    fp32 online-softmax state like ``pool_attention`` — one launch per
+    (layer, tick), O(1) in pool depth, and HBM traffic O(resident pages),
+    not O(padded pool)."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     c = q.shape[1]
-    bq = min(_ca.DEFAULT_BLOCK_Q if block_q is None else block_q, c)
+    bq = min(block_q, c)
     while c % bq:
         bq //= 2
-    qp = _pad_to(q, 3, LANE)
+    qp = _heads_major(_pad_to(q, 3, LANE))
     # lane-pad the PAGE STORE only when head_dim is off-lane (a one-off
     # [P, ...] copy — real configs keep hd a multiple of 128 and pass
     # through untouched; there is never an [S, B, C, KVH, D] gather)
     kp = _pad_to(k_pages, 4, LANE)
     vp = _pad_to(v_pages, 4, LANE)
-    kvh = k_pages.shape[3]
     if k_scale is not None:
         k_scale = k_scale.reshape(k_scale.shape[0], -1)  # [P, B*KVH]
         v_scale = v_scale.reshape(v_scale.shape[0], -1)
-        assert k_scale.shape[1] == q.shape[0] * kvh, k_scale.shape
-    use_dma = _paged_use_dma() if use_dma is None else use_dma
-    _note_launch("pool_attention_paged")
+        assert k_scale.shape[1] == q.shape[0] * k_pages.shape[2], \
+            k_scale.shape
     m, l, acc = _ca.pool_attention_paged_pallas(
         qp, kp, vp, handles, valid, ppc=ppc, scale=scale, kv_len=kv_len,
         block_q=bq, interpret=not _on_tpu(), k_scale=k_scale,
-        v_scale=v_scale, use_dma=use_dma)
-    return m, l, acc[..., :d]
+        v_scale=v_scale)
+    return _state_out(m, l, acc, d)
 
 
 def full_attention(q, k, v, *, scale: Optional[float] = None,
@@ -267,7 +231,6 @@ def ssd(x, dt, a_log, b, c, d_skip, *, chunk: int = 128, init_state=None,
     while t % ck:
         ck //= 2
     interpret = (not _on_tpu()) if interpret is None else interpret
-    _note_launch("ssd")
     return _ssd.ssd_pallas(x, dt, a_log, b, c, d_skip, chunk=ck,
                            init_state=init_state, interpret=interpret)
 
@@ -285,7 +248,6 @@ def decode_attention(q, k, v, kv_len, *, scale: Optional[float] = None,
     bs = min(block_s, s_len)
     while s_len % bs:
         bs //= 2
-    _note_launch("decode_attention")
     out = _da.decode_attention_pallas(qp, kp, vp, kv_len, scale=scale,
                                       block_s=bs, interpret=not _on_tpu())
     return out[..., :d]

@@ -16,8 +16,13 @@ quantized — block-wise quantization at page granularity, so smaller pages
 mean tighter amax windows and lower error.
 
 Layouts (P = total physical pages incl. the scratch slot's):
-    k_pages / v_pages   [P, lps, B, page_tokens, kvh, hd]   storage dtype
-    k_scale / v_scale   [P, lps, B, 1, kvh, 1]              fp32 (quantized)
+    k_pages / v_pages   [P, lps, B, kvh, page_tokens, hd]   storage dtype
+    k_scale / v_scale   [P, lps, B, kvh, 1, 1]              fp32 (quantized)
+
+Pages are HEAD-MAJOR so one kv head of one page is a run of whole
+(tokens, hd) tiles — the slice the paged kernel DMAs. Chunks outside the
+store keep the token-major ``[.., C, kvh, hd]`` layout with per-page scales
+``[ppc, .., 1, kvh, 1]`` (``kvstore.quant``); scatter and gather transpose.
 """
 from __future__ import annotations
 
@@ -123,13 +128,13 @@ jax.tree_util.register_pytree_node(PagedPool, _pool_flatten, _pool_unflatten)
 
 def alloc_pool(geom: PageGeometry, codec: Q.KVCodec, lps: int, b: int,
                kvh: int, hd: int) -> PagedPool:
-    shape = (geom.num_pages, lps, b, geom.page_tokens, kvh, hd)
+    shape = (geom.num_pages, lps, b, kvh, geom.page_tokens, hd)
     dt = jnp.dtype(codec.storage_dtype)
     k = jnp.zeros(shape, dt)
     v = jnp.zeros(shape, dt)
     if not codec.quantized:
         return PagedPool(k, v)
-    sshape = (geom.num_pages, lps, b, 1, kvh, 1)
+    sshape = (geom.num_pages, lps, b, kvh, 1, 1)
     one = jnp.ones(sshape, jnp.float32)  # benign scale for never-written pages
     return PagedPool(k, v, one, one)
 
@@ -146,10 +151,10 @@ def pool_bytes(geom: PageGeometry, codec: Q.KVCodec, lps: int, b: int,
 # ----------------------------------------------------------- write (scatter)
 
 def _paginate(x: jax.Array, ppc: int) -> jax.Array:
-    """[lps, B, C, kvh, hd] -> [ppc, lps, B, page_tokens, kvh, hd]."""
+    """[lps, B, C, kvh, hd] -> [ppc, lps, B, kvh, page_tokens, hd]."""
     lps, b, c, kvh, hd = x.shape
     x = x.reshape(lps, b, ppc, c // ppc, kvh, hd)
-    return x.transpose(2, 0, 1, 3, 4, 5)
+    return x.transpose(2, 0, 1, 4, 3, 5)
 
 
 def scatter_chunk(pool: PagedPool, pages: jax.Array, k: jax.Array,
@@ -177,8 +182,8 @@ def scatter_chunk_raw(pool: PagedPool, pages: jax.Array, kq: jax.Array,
     v_pool = pool.v.at[pages].set(vp)
     k_sc, v_sc = pool.k_scale, pool.v_scale
     if k_sc is not None:
-        k_sc = k_sc.at[pages].set(ks)
-        v_sc = v_sc.at[pages].set(vs)
+        k_sc = k_sc.at[pages].set(jnp.swapaxes(ks, -3, -2))
+        v_sc = v_sc.at[pages].set(jnp.swapaxes(vs, -3, -2))
     return PagedPool(k_pool, v_pool, k_sc, v_sc)
 
 
@@ -194,20 +199,20 @@ def gather_chunk(k_l: jax.Array, v_l: jax.Array,
     not a perf path: the paged kernel (``ops.pool_attention_paged``) reads
     pages in place and never materializes this copy.
 
-    k_l/v_l [P, B, page_tokens, kvh, hd]; ks_l/vs_l [P, B, 1, kvh, 1];
+    k_l/v_l [P, B, kvh, page_tokens, hd]; ks_l/vs_l [P, B, kvh, 1, 1];
     pages [ppc] (traced). Returns the ENCODED chunk ([B, C, kvh, hd] payload
     + per-PAGE scales [ppc, B, 1, kvh, 1]) — decode is the reader's
     business (the jnp backend multiplies out; the Pallas kernel dequantizes
     in its epilogue)."""
-    kq = jnp.take(k_l, pages, axis=0)          # [ppc, B, pt, kvh, hd]
+    kq = jnp.take(k_l, pages, axis=0)          # [ppc, B, kvh, pt, hd]
     vq = jnp.take(v_l, pages, axis=0)
-    ppc, b, pt, kvh, hd = kq.shape
-    kq = kq.transpose(1, 0, 2, 3, 4).reshape(b, ppc * pt, kvh, hd)
-    vq = vq.transpose(1, 0, 2, 3, 4).reshape(b, ppc * pt, kvh, hd)
+    ppc, b, kvh, pt, hd = kq.shape
+    kq = kq.transpose(1, 0, 3, 2, 4).reshape(b, ppc * pt, kvh, hd)
+    vq = vq.transpose(1, 0, 3, 2, 4).reshape(b, ppc * pt, kvh, hd)
     ks = vs = None
-    if ks_l is not None:
-        ks = jnp.take(ks_l, pages, axis=0)     # [ppc, B, 1, kvh, 1]
-        vs = jnp.take(vs_l, pages, axis=0)
+    if ks_l is not None:                       # -> [ppc, B, 1, kvh, 1]
+        ks = jnp.swapaxes(jnp.take(ks_l, pages, axis=0), -3, -2)
+        vs = jnp.swapaxes(jnp.take(vs_l, pages, axis=0), -3, -2)
     return kq, vq, ks, vs
 
 
@@ -227,15 +232,17 @@ def gather_chunks(k_l: jax.Array, v_l: jax.Array,
     takes ``handle_rows`` and reads pages in place."""
     s, ppc = page_rows.shape
     flat = page_rows.reshape(-1)
-    kq = jnp.take(k_l, flat, axis=0)           # [S*ppc, B, pt, kvh, hd]
+    kq = jnp.take(k_l, flat, axis=0)           # [S*ppc, B, kvh, pt, hd]
     vq = jnp.take(v_l, flat, axis=0)
-    _, b, pt, kvh, hd = kq.shape
-    kq = kq.reshape(s, ppc, b, pt, kvh, hd).transpose(0, 2, 1, 3, 4, 5) \
+    _, b, kvh, pt, hd = kq.shape
+    kq = kq.reshape(s, ppc, b, kvh, pt, hd).transpose(0, 2, 1, 4, 3, 5) \
            .reshape(s, b, ppc * pt, kvh, hd)
-    vq = vq.reshape(s, ppc, b, pt, kvh, hd).transpose(0, 2, 1, 3, 4, 5) \
+    vq = vq.reshape(s, ppc, b, kvh, pt, hd).transpose(0, 2, 1, 4, 3, 5) \
            .reshape(s, b, ppc * pt, kvh, hd)
     ks = vs = None
-    if ks_l is not None:
-        ks = jnp.take(ks_l, flat, axis=0).reshape(s, ppc, *ks_l.shape[1:])
-        vs = jnp.take(vs_l, flat, axis=0).reshape(s, ppc, *vs_l.shape[1:])
+    if ks_l is not None:                       # -> [S, ppc, B, 1, kvh, 1]
+        ks = jnp.swapaxes(jnp.take(ks_l, flat, axis=0), -3, -2).reshape(
+            s, ppc, b, 1, kvh, 1)
+        vs = jnp.swapaxes(jnp.take(vs_l, flat, axis=0), -3, -2).reshape(
+            s, ppc, b, 1, kvh, 1)
     return kq, vq, ks, vs

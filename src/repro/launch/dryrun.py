@@ -13,6 +13,11 @@ Usage:
 
 Artifacts: artifacts/dryrun/<mesh>/<arch>__<shape>__<mode>.json — consumed by
 EXPERIMENTS.md §Dry-run / §Roofline and benchmarks/roofline_report.py.
+
+A CPU-only tool: it fakes 512 host devices at import and ``--jobs`` spawns
+one child process per cell, so never run it on a machine with a chip (a
+chip belongs to one process; ``chip_smoke.py`` and ``serve`` are the chip
+paths). Run it with ``JAX_PLATFORMS=cpu``.
 """
 import argparse
 import json
@@ -170,8 +175,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--kv-page-tokens", type=int, default=0,
                     help="tokens per KV page (0 = one page per chunk)")
     ap.add_argument("--calibrated-profile", default=None,
-                    help="calibrated-profile JSON (obs.calibrate / serve "
-                         "--calibrate): records per-(chunk, stage) fit "
+                    help="calibrated-profile JSON (obs.calibrate): records per-(chunk, stage) fit "
                          "residuals and the nominal-vs-calibrated predicted "
                          "chunk costs next to wire_model/occupancy_model")
     ap.add_argument("--out", default="artifacts/dryrun")

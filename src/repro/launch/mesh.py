@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import axis_types_kw as _axis_kw  # shared jax-drift shim
+from repro.compat import axis_types_kw as _axis_kw
 from repro.models.topology import Topology
 
 

@@ -64,7 +64,6 @@ class ServeOptions:
     metrics_out: Optional[str] = None
     profile_dir: Optional[str] = None
     calibrated_profile: Optional[str] = None
-    calibrate: Optional[str] = None
     health: bool = False
 
     # ------------------------------------------------------------ round-trip
@@ -199,9 +198,6 @@ def add_serve_args(ap: argparse.ArgumentParser) -> None:
                     help="HardwareProfile for planning/admission costs: a "
                          "registered name or a calibrated-profile JSON "
                          "(obs.calibrate)")
-    ap.add_argument("--calibrate", default=S, metavar="OUT",
-                    help="fit the effective HardwareProfile from measured "
-                         "spans (jax executor only) and write it to OUT")
     ap.add_argument("--health", action="store_true", default=S,
                     help="arm the runtime health sentinels (obs.health)")
 

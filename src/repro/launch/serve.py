@@ -1,8 +1,12 @@
 """Prefill-only serving driver: the MOCAP engine end-to-end.
 
-Real execution on the available devices (chunked pipeline via shard_map needs
->= 2 devices; on a bare CPU host the driver forces 8 fake host devices
-itself), or --executor sim for the analytic executor at production scale.
+Real execution on the available devices — one pipeline stage per device
+(tp=2 once there are >= 4, unless Mosaic kernels need tp=1; see
+``pipeline_shape``), so a single TPU chip runs a one-stage pipeline;
+under ``JAX_PLATFORMS=cpu`` the driver gives the host 8 fake devices itself
+(the multi-stage CPU test path) — or --executor sim for the analytic
+executor at production scale. ``--preset full`` runs the model's published
+widths in its configured dtype; ``smoke`` is the float32 toy config.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-8b --requests 12 \
       --executor jax --attn-backend pallas
@@ -32,6 +36,7 @@ profiles); ``--router`` picks jsf | rr | least-loaded:
 from __future__ import annotations
 
 import math
+import os
 import time
 
 import numpy as np
@@ -44,6 +49,13 @@ from repro.runtime.engine import (ContinuousEngine, EngineConfig, JaxExecutor,
                                   PrefillEngine, Request, SimExecutor)
 
 H2D_BW = 16e9  # host<->device staging bandwidth for the cold tier (B/s)
+
+# persistent XLA compile cache for accelerator runs: one FIXED path inside
+# the checkout (the path is part of the cache key) unless the environment
+# names one through JAX_COMPILATION_CACHE_DIR, which JAX reads itself
+COMPILE_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".jax_cache"))
 
 SIM_BUCKETS = (8192, 32768, 131072)
 
@@ -88,28 +100,65 @@ def _print_tier_summary(cfg, ec, kv_dtype: str, kv_page_tokens: int) -> None:
           f"{'FEASIBLE' if s['feasible'] else 'INFEASIBLE'}")
 
 
+def jax_devices():
+    """Bring JAX up for the jax executor and return ``jax.devices()`` — the
+    one place ``serve`` and ``chip_smoke.py`` configure the process: fake
+    host devices only when the platform is the CPU, and the persistent
+    compile cache (``COMPILE_CACHE_DIR``) on an accelerator."""
+    import jax
+    from repro import compat
+    if (jax.config.jax_platforms or "").split(",")[0] == "cpu":
+        compat.ensure_host_devices()
+    devices = jax.devices()
+    if (devices[0].platform != "cpu"
+            and not os.environ.get("JAX_COMPILATION_CACHE_DIR")):
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return devices
+
+
+def pipeline_shape(n_dev: int, opts: ServeOptions, platform: str):
+    """(stages, tp) over ``n_dev`` devices: tp=2 once there are >= 4,
+    otherwise one stage per device (a single chip is a one-stage
+    pipeline; MBKR then has no pair and is off). Compiled (Mosaic) Pallas
+    kernels cannot be auto-partitioned, so on a TPU with a Pallas backend
+    under ``tp_lowering="auto"`` every device is a stage of its own."""
+    pallas = {opts.attn_backend, opts.pool_backend,
+              opts.ssm_backend} & {"pallas", "paged"}
+    mosaic_auto = (platform == "tpu" and bool(pallas)
+                   and opts.tp_lowering == "auto")
+    tp = 2 if n_dev >= 4 and not mosaic_auto else 1
+    return n_dev // tp, tp
+
+
 def _resolve_hw(opts: ServeOptions):
     if opts.calibrated_profile:
         hw = cm.resolve_profile(opts.calibrated_profile)
         print(f"[profile] {opts.calibrated_profile} -> {hw.name} "
               f"(gemm_eff={hw.gemm_eff:.3f} attn_eff={hw.attn_eff:.3f})")
         return hw
+    if opts.executor == "jax":
+        import jax
+        return cm.device_profile(jax.devices()[0])
     return cm.TPU_V5E
 
 
-def _build_engine(opts: ServeOptions, *, topo=None, jax_ctx=None):
+def _build_engine(opts: ServeOptions, *, cfg=None, topo=None, jax_ctx=None):
     """One serving cell from ONE declarative ServeOptions: (cfg, ec, engine).
 
-    ``jax_ctx`` (a dict) carries the device-dependent pieces shared across
-    fleet cells: {"stages": N, "tp": T}. ``topo`` pins the cell to a
-    specific mesh block (``launch.cells.enumerate_cell_meshes``); None =
-    one mesh over all devices. Engines come out config-constructed — all
-    policy/slo/trace knobs ride on EngineConfig, none on kwargs."""
+    ``cfg`` overrides the model config the options name (``chip_smoke.py``
+    passes a depth-cut config). ``jax_ctx`` (a dict) carries the
+    device-dependent pieces shared across fleet cells: {"stages": N,
+    "tp": T}. ``topo`` pins the cell to a specific mesh block
+    (``launch.cells.enumerate_cell_meshes``); None = one mesh over all
+    devices. Engines come out config-constructed — all policy/slo/trace
+    knobs ride on EngineConfig, none on kwargs."""
+    if opts.executor == "jax":
+        jax_devices()
     hw = _resolve_hw(opts)
     slo = opts.slo_ms / 1e3 if opts.slo_ms else None
     want_trace = opts.trace_out is not None
     if opts.executor == "sim":
-        cfg = get_config(opts.arch)
+        cfg = cfg or get_config(opts.arch)
         ec = EngineConfig(model=cfg, hw=hw, num_stages=16, tp=16,
                           num_chunks=16, max_batch=opts.max_batch,
                           buckets=opts.buckets or SIM_BUCKETS,
@@ -120,22 +169,16 @@ def _build_engine(opts: ServeOptions, *, topo=None, jax_ctx=None):
                           prefix_min_pages=opts.prefix_min_pages)
         executor = SimExecutor(cfg, hw)
     else:
-        from repro import compat
-        compat.ensure_host_devices()
         import jax
         from repro.core import pipeline as pp
         from repro.launch.mesh import make_test_topology
-        from repro.models.api import build_model
-        cfg = replace(get_smoke_config(opts.arch)
-                      if opts.preset == "smoke" else get_config(opts.arch),
-                      dtype="float32")
+        if cfg is None:
+            cfg = (replace(get_smoke_config(opts.arch), dtype="float32")
+                   if opts.preset == "smoke" else get_config(opts.arch))
         if jax_ctx is None:
-            n_dev = jax.device_count()
-            # tp=2 when the device count affords it; old jaxlib takes the
-            # MANUAL TP lowering (build_plan resolves tp_lowering="auto" via
-            # compat.resolve_tp_lowering — no more tp=1 fallback)
-            tp = 2 if n_dev >= 4 else 1
-            jax_ctx = {"stages": max(n_dev // tp, 2), "tp": tp}
+            stages, tp = pipeline_shape(jax.device_count(), opts,
+                                        jax.devices()[0].platform)
+            jax_ctx = {"stages": stages, "tp": tp}
         stages, tp = jax_ctx["stages"], jax_ctx["tp"]
         if topo is None:
             topo = make_test_topology(stages, tp)
@@ -153,9 +196,8 @@ def _build_engine(opts: ServeOptions, *, topo=None, jax_ctx=None):
         if plan.tp_lowering == "manual" and tp > 1:
             print(f"[transport] manual TP lowering (tp={tp}, "
                   f"transport={plan.transport})")
-        model = build_model(cfg)
-        params = model.init(jax.random.key(opts.seed))
-        staged = pp.stage_params(cfg, params, plan)
+        staged = pp.init_stage_params(cfg, plan, topo,
+                                      jax.random.key(opts.seed))
         ec = EngineConfig(model=cfg, hw=hw, num_stages=stages, tp=tp,
                           num_chunks=opts.num_chunks,
                           max_batch=opts.max_batch,
@@ -201,13 +243,10 @@ def _run_fleet(opts: ServeOptions) -> int:
     topos = [None] * len(cell_opts)
     jax_ctx = None
     if opts.executor == "jax":
-        from repro import compat
-        compat.ensure_host_devices()
-        import jax
         from repro.launch.cells import enumerate_cell_meshes
-        n_dev = jax.device_count()
-        tp = 2 if n_dev >= 4 else 1
-        stages = max(n_dev // tp, 2)
+        devices = jax_devices()
+        n_dev = len(devices)
+        stages, tp = pipeline_shape(n_dev, opts, devices[0].platform)
         jax_ctx = {"stages": stages, "tp": tp}
         topos = list(enumerate_cell_meshes(len(cell_opts), stages, tp))
         if len(cell_opts) * stages * tp > n_dev:
@@ -272,16 +311,6 @@ def _run_single(opts: ServeOptions) -> int:
         from repro.obs.health import HealthMonitor
         monitor = HealthMonitor()
         eng.configure_obs(health=monitor)
-    calibrate_out = opts.calibrate
-    if calibrate_out:
-        if opts.executor == "jax":
-            eng.configure_obs(measured=True)
-        else:
-            print("note: --calibrate measures the jax executor; the sim "
-                  "path IS the analytic model — skipping (the sim-backed "
-                  "calibration leg lives in benchmarks/calibration.py)")
-            calibrate_out = None
-
     arrival_rate = opts.arrival_rate
     if opts.scheduler == "batch" and arrival_rate > 0:
         # the batch-synchronous engine admits everything at clock 0 and its
@@ -305,25 +334,6 @@ def _run_single(opts: ServeOptions) -> int:
     wall = time.time() - t0
     finished = eng.poll()
 
-    if calibrate_out:
-        meas = eng.measured_waves()
-        if not meas:
-            print("note: no measured waves; nothing to calibrate")
-        else:
-            from repro.core import mbkr
-            from repro.obs import calibrate as cal
-            w = meas[-1]            # later waves are warm (compile is paid)
-            sm = cm.StageModel.build(cfg, w["num_stages"], ec.tp)
-            mplan = (mbkr.plan(len(w["chunks"]), w["num_stages"])
-                     if not cfg.attn_free else None)
-            fit = cal.fit_profile(sm, w["chunks"], w["measured"], ec.hw,
-                                  mbkr_plan=mplan)
-            cal.save_profile(calibrate_out, fit.profile, fit=fit,
-                             meta={"arch": opts.arch, "seq": opts.seq,
-                                   "source": "serve"})
-            print(f"[calibrate] {ec.hw.name} -> {fit.profile.name}: span "
-                  f"MAPE {fit.mape_nominal:.3f} -> {fit.mape_calibrated:.3f}"
-                  f" over {len(fit.rows)} spans -> {calibrate_out}")
     if monitor is not None:
         if slo is not None and opts.scheduler == "continuous":
             from repro.obs.metrics import Histogram

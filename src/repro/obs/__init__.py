@@ -14,29 +14,22 @@ Coordinated surfaces (DESIGN.md §8-§9):
                       tracks, one merged file (atomic export).
 - ``obs.metrics``     counters/gauges/histograms with JSON-lines and
                       Prometheus-textfile export for serving runs.
-- ``obs.profile``     MEASURED wall-clock spans: per-(stage, tick)
-                      ``MeasuredProfile`` aligned with the telemetry
-                      profiles, plus per-kernel-tag attribution riding
-                      ``kernels.ops.count_launches(timed=True)``.
 - ``obs.calibrate``   least-squares fit of the ``HardwareProfile`` effective
-                      rates against measured spans; calibrated-profile JSON
+                      rates against ``[N, T]`` tick spans; calibrated-profile JSON
                       accepted by ``lbcp.plan_partition`` /
                       ``chunk_cost_arrays`` / scheduler admission.
 - ``obs.health``      runtime sentinels: non-finite activations, telemetry
                       vs analytic drift, SLO burn-rate — one structured
                       alert stream into metrics + trace.
 
-``obs.trace`` / ``obs.metrics`` / ``obs.health`` / ``obs.calibrate`` /
-``obs.profile`` are import-light (stdlib/numpy) so scheduler and benchmark
-code can depend on them; ``obs.telemetry`` pulls in jax and is imported only
-by ``repro.core`` and engine code (``health``/``profile`` reach jax lazily,
-inside methods).
+``obs.trace`` / ``obs.metrics`` / ``obs.health`` / ``obs.calibrate`` are
+import-light (stdlib/numpy) so scheduler and benchmark code can depend on
+them; ``obs.telemetry`` pulls in jax and is imported only by ``repro.core``
+and engine code (``health`` reaches jax lazily, inside methods).
 """
 from repro.obs.health import Alert, HealthMonitor, slo_burn_rate
 from repro.obs.metrics import MetricsRegistry, export_engine_metrics
-from repro.obs.profile import MeasuredProfile, TickSpanCollector
 from repro.obs.trace import TraceRecorder
 
-__all__ = ["Alert", "HealthMonitor", "MeasuredProfile", "MetricsRegistry",
-           "TickSpanCollector", "TraceRecorder", "export_engine_metrics",
-           "slo_burn_rate"]
+__all__ = ["Alert", "HealthMonitor", "MetricsRegistry", "TraceRecorder",
+           "export_engine_metrics", "slo_burn_rate"]

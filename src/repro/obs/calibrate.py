@@ -9,7 +9,7 @@ for the work-quantity matrix ``X = chunk_cost_features(...)`` and
 
     theta* = argmin_theta || X @ theta - measured ||_2
 
-over one design row per VALID (stage, tick) of a ``MeasuredProfile`` (the
+over one design row per VALID (stage, tick) of an ``[N, T]`` span array (the
 positions where ``0 <= phase < M`` — the same index alignment as the device
 ``TelemetryProfile``). Columns with no signal in the run (e.g. no
 bandwidth-bound chunk) and non-positive fitted rates (unidentifiable under
@@ -99,13 +99,11 @@ def fit_profile(sm: cm.StageModel, chunks: Sequence[int], measured,
                 name: Optional[str] = None) -> FitResult:
     """Least-squares fit of the effective rates against measured spans.
 
-    ``measured``: an ``obs.profile.MeasuredProfile`` or a raw ``[N, T]``
-    seconds array aligned like the telemetry profiles (stage-major,
-    ``T = M + N - 1``).
+    ``measured``: an ``[N, T]`` seconds array aligned like the telemetry
+    profiles (stage-major, ``T = M + N - 1``).
     """
     hw = cm.resolve_profile(hw)
-    tick_s = getattr(measured, "tick_s", measured)
-    x, y, rows = design_matrix(sm, chunks, hw, tick_s,
+    x, y, rows = design_matrix(sm, chunks, hw, measured,
                                mbkr_plan=mbkr_plan, compress=compress)
     theta0 = cm.profile_theta(hw, sm.tp)
     theta = theta0.copy()

@@ -156,9 +156,8 @@ class CellHandle(Protocol):
                    extra: Optional[Dict[str, float]] = None,
                    health: Any = None) -> Dict[str, str]: ...
     def configure_obs(self, *, telemetry: Optional[bool] = None,
-                      measured: Optional[bool] = None,
                       health: Any = None) -> None: ...
-    def measured_waves(self) -> List[Dict[str, Any]]: ...
+    def waves(self) -> List[Dict[str, Any]]: ...
 
 
 # ---------------------------------------------------------------- executors
@@ -228,12 +227,6 @@ class JaxExecutor:
     into engine wave spans, per-stage tick spans and KV/wire counter
     tracks. Off by default: the compiled program is the plain pipeline.
 
-    ``collect_measured`` additionally arms the pipeline's ``tick_hook``
-    (``obs.profile.TickSpanCollector``) and lands a measured per-(stage,
-    tick) wall-clock span array in ``wave["measured"]`` — the calibration
-    input (``obs.calibrate.fit_profile``). The first wave at a given key
-    includes compile in tick 0; calibrate against a repeat wave.
-
     ``health`` (an ``obs.health.HealthMonitor``) arms the non-finite
     sentinels in the pipeline and, when telemetry is also on, runs the
     occupancy-drift check against each wave. Attach BEFORE the first run
@@ -256,9 +249,7 @@ class JaxExecutor:
         self._fns: Dict[Tuple, Tuple[Callable, Any]] = {}
         self._pp = pp
         self.collect_telemetry = False
-        self.collect_measured = False
         self.health = None
-        self._span_col = None
         self.waves: List[Dict[str, Any]] = []
         self._epoch = time.perf_counter()
         self.prefix_enabled = False
@@ -297,7 +288,6 @@ class JaxExecutor:
         import jax
         seq = int(sum(chunks))
         collect = bool(self.collect_telemetry)
-        measured = bool(self.collect_measured)
         health = self.health
         armed = bool(self.prefix_enabled)
         # ---- device prefix: wave-uniform seedable hit length k (static —
@@ -308,8 +298,7 @@ class JaxExecutor:
             chains = self._wave_chains(requests)
             if all(chains):
                 k = min(seed_cache.match(ch) for ch in chains)
-        key = (seq, len(chunks), collect, measured, health is not None,
-               armed, k)
+        key = (seq, len(chunks), collect, health is not None, armed, k)
         if key not in self._fns:
             plan = self._pp.build_plan(
                 self.cfg, num_stages, seq,
@@ -325,32 +314,24 @@ class JaxExecutor:
             self.prefix_device_hit_chunks += k
         if self._fns[key][0] is None:
             cfg, topo = self.cfg, self.topo
-            hook = None
-            if measured:
-                if self._span_col is None:
-                    from repro.obs.profile import TickSpanCollector
-                    self._span_col = TickSpanCollector()
-                hook = self._span_col.note
             kk = k
             if armed and kk > 0:
                 fn = jax.jit(lambda st, tk, pool: self._pp.prefill_pipeline(
                     cfg, st, tk, plan, topo, return_telemetry=collect,
                     prefix_chunks=kk, prefix_pool=pool, return_kv=True,
-                    tick_hook=hook, health=health))
+                    health=health))
             elif armed:
                 fn = jax.jit(lambda st, tk: self._pp.prefill_pipeline(
                     cfg, st, tk, plan, topo, return_telemetry=collect,
-                    return_kv=True, tick_hook=hook, health=health))
+                    return_kv=True, health=health))
             else:
                 fn = jax.jit(lambda st, tk: self._pp.prefill_pipeline(
                     cfg, st, tk, plan, topo, return_telemetry=collect,
-                    tick_hook=hook, health=health))
+                    health=health))
             self._fns[key] = (fn, plan)
         fn, plan = self._fns[key]
         toks = np.stack([np.pad(r.tokens, (0, seq - len(r.tokens)))
                          for r in requests]).astype(np.int32)
-        if measured and self._span_col is not None:
-            self._span_col.reset()
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation(
                 f"prefill_wave seq{seq} b{len(requests)}"):
@@ -372,7 +353,7 @@ class JaxExecutor:
                         f: (None if getattr(kv, f) is None else
                             np.asarray(getattr(kv, f)[:, :, :, i]))
                         for f in ("k", "v", "k_scale", "v_scale")})
-        if measured or health is not None:
+        if health is not None:
             jax.effects_barrier()    # order debug callbacks before the reads
         for r, row in zip(requests, np.asarray(out)):
             r.result = row
@@ -382,9 +363,6 @@ class JaxExecutor:
             "chunks": list(chunks), "rids": [r.rid for r in requests],
             "prefix_chunks": k,
         }
-        if measured and self._span_col is not None:
-            wave["measured"] = self._span_col.finalize(
-                num_stages, int(plan.num_ticks)).tick_s
         if tel is not None:
             from repro.obs import telemetry as obs_t
             wave["telemetry"] = {k: np.asarray(v) for k, v in tel.items()}
@@ -397,6 +375,14 @@ class JaxExecutor:
 
 
 # ------------------------------------------------------------------- engine
+
+def _configure_executor(ex, telemetry: Optional[bool], health: Any) -> None:
+    """The shared ``configure_obs`` seam of both engines."""
+    if telemetry is not None and hasattr(ex, "collect_telemetry"):
+        ex.collect_telemetry = bool(telemetry)
+    if health is not None:
+        ex.health = health
+
 
 class PrefillEngine:
     def __init__(self, ec: EngineConfig, executor):
@@ -481,21 +467,13 @@ class PrefillEngine:
         return list(new)
 
     def configure_obs(self, *, telemetry: Optional[bool] = None,
-                      measured: Optional[bool] = None,
                       health: Any = None) -> None:
         """See ``ContinuousEngine.configure_obs`` — shared executor seam."""
-        ex = self.executor
-        if telemetry is not None and hasattr(ex, "collect_telemetry"):
-            ex.collect_telemetry = bool(telemetry)
-        if measured is not None and hasattr(ex, "collect_measured"):
-            ex.collect_measured = bool(measured)
-        if health is not None:
-            ex.health = health
+        _configure_executor(self.executor, telemetry, health)
 
-    def measured_waves(self) -> List[Dict[str, Any]]:
-        """See ``ContinuousEngine.measured_waves`` — the calibration input."""
-        return [w for w in getattr(self.executor, "waves", [])
-                if w.get("measured") is not None]
+    def waves(self) -> List[Dict[str, Any]]:
+        """See ``ContinuousEngine.waves``."""
+        return list(getattr(self.executor, "waves", []))
 
     # ------------------------------------------------------ fault handling
     def _handle_failure(self, stage: int, batch: Sequence[Request]) -> None:
@@ -837,26 +815,18 @@ class ContinuousEngine:
         return list(self.scheduler.metrics.records)
 
     def configure_obs(self, *, telemetry: Optional[bool] = None,
-                      measured: Optional[bool] = None,
                       health: Any = None) -> None:
         """Arm executor-side observability WITHOUT poking the executor from
-        outside (the protocol seam): device telemetry (``return_telemetry``),
-        measured tick spans (``collect_measured``) and a health monitor.
-        Flags an executor does not support are ignored (SimExecutor IS the
-        analytic model — there is nothing to measure)."""
-        ex = self.executor
-        if telemetry is not None and hasattr(ex, "collect_telemetry"):
-            ex.collect_telemetry = bool(telemetry)
-        if measured is not None and hasattr(ex, "collect_measured"):
-            ex.collect_measured = bool(measured)
-        if health is not None:
-            ex.health = health
+        outside (the protocol seam): device telemetry (``return_telemetry``)
+        and a health monitor. Flags an executor does not support are
+        ignored (SimExecutor IS the analytic model)."""
+        _configure_executor(self.executor, telemetry, health)
 
-    def measured_waves(self) -> List[Dict[str, Any]]:
-        """Executor waves that carry a measured per-(stage, tick) span array
-        (``configure_obs(measured=True)``) — the calibration input."""
-        return [w for w in getattr(self.executor, "waves", [])
-                if w.get("measured") is not None]
+    def waves(self) -> List[Dict[str, Any]]:
+        """The device executor's waves, in run order: wall-clock ``start``
+        / ``dur`` (bounded by ``block_until_ready``), ``seq``, ``rids``,
+        ``chunks`` (and telemetry when armed). [] for SimExecutor."""
+        return list(getattr(self.executor, "waves", []))
 
     def _execute_real(self, order) -> None:
         """Chunk-interleaved token batches: stack consecutive same-bucket
@@ -918,9 +888,6 @@ class ContinuousEngine:
         - engine wave spans + per-(stage, tick) device spans and
           ``kv_resident_bytes`` tracks from JaxExecutor telemetry waves
           (wall clock since executor construction, pid = "engine"),
-        - MEASURED per-(stage, tick) wall-clock spans (``wave["measured"]``
-          from ``collect_measured``) on their own ``measured`` process row
-          next to the analytic tracks,
         - health-sentinel alerts (``executor.health``) on a ``health``
           process row.
 
@@ -980,26 +947,6 @@ class ContinuousEngine:
                                 values={f"w{wi}": float(kv[s, t])})
                     rec.counter("device_wire_bytes", pid=s, time=ts,
                                 values={f"w{wi}": float(wire[s, t])})
-        # measured wall-clock spans: one process row, one thread per stage;
-        # per-stage span starts are the cumulative measured tick durations
-        if any(w.get("measured") is not None for w in waves):
-            rec.process_name("measured", "measured spans (wall clock)")
-        for wi, w in enumerate(waves):
-            ms = w.get("measured")
-            if ms is None:
-                continue
-            for s in range(ms.shape[0]):
-                cursor = w["start"]
-                for t in range(ms.shape[1]):
-                    d = float(ms[s, t])
-                    phase = t - s
-                    if 0 <= phase < len(w["chunks"]) and d > 0:
-                        rec.span(f"tick{t} c{phase}", pid="measured", tid=s,
-                                 start=cursor, finish=cursor + d,
-                                 cat="measured",
-                                 args={"stage": s, "chunk": phase,
-                                       "wave": wi})
-                    cursor += d
         health = getattr(self.executor, "health", None)
         if health is not None:
             health.to_trace(rec)

@@ -1,28 +1,15 @@
-"""Assert the tier-1 SKIP matrix matches the installed jax capabilities.
+"""Assert the tier-1 SKIP matrix: the only expected skips are hypothesis.
 
-The CI ``tier1`` job runs on a jax matrix (current release + the oldest
-supported jaxlib, which predates ``jax.shard_map``). Since the manual TP
-lowering landed (``compat.resolve_tp_lowering`` / DESIGN.md §3.6) the
-old-jaxlib leg runs TP=2 and the kv_split / EP perf-variant tests instead
-of skipping them — the partial-auto skip count is 0 on BOTH legs, and a
-reappearing "old jaxlib"/PartitionId skip means the manual-lowering
-fallback regressed. This script parses a ``pytest -rs`` log and asserts the
-exact expected skip counts per reason class:
+This script parses a ``pytest -rs`` log and asserts the exact expected skip
+counts per reason class:
 
-- "old jaxlib"/PartitionId skips: exactly 0 on every leg (the manual
-  lowering replaced the tp=1 fallback).
 - hypothesis skips: exactly 0 when hypothesis is importable (CI installs
   it), exactly 5 otherwise (4 importorskip modules — including the prefix
   radix property tests — + the guarded ragged-occupancy property test).
-- anything else: unknown skip reason -> fail. Notably the paged pool
-  kernel (DESIGN.md §3.7) introduces NO TPU-only skip class: its manual-
-  DMA path runs under interpret mode on every supported jaxlib, and the
-  deterministic ragged cases in test_pool_batched.py run unconditionally
-  (no hypothesis needed).
-
-It also asserts the resolved TP lowering matches ``REPRO_EXPECT_TP_LOWERING``
-when the CI matrix sets it (the old-jaxlib leg pins "manual"), so a compat
-drift that silently flips the lowering fails CI instead of shipping.
+- anything else: unknown skip reason -> fail. Notably the TPU compile tests
+  (tests/test_tpu_compile.py) must compile, not skip, wherever the TPU
+  compiler is installed, and the paged pool kernel's manual-DMA path runs
+  under interpret mode on the CPU.
 
 Usage:
   PYTHONPATH=src python -m pytest -q -rs 2>&1 | tee pytest-report.log
@@ -30,7 +17,6 @@ Usage:
 """
 from __future__ import annotations
 
-import os
 import re
 import sys
 
@@ -41,7 +27,6 @@ _ALLOWED_CONDITIONAL = ("causal-only",)
 
 
 def main(path: str) -> int:
-    from repro import compat
     try:
         import hypothesis  # noqa: F401
         have_hyp = True
@@ -51,23 +36,13 @@ def main(path: str) -> int:
     text = open(path).read()
     skips = [(int(m.group(1)), m.group(2).strip())
              for m in SKIP_RE.finditer(text)]
-    n_partial = sum(c for c, r in skips
-                    if "old jaxlib" in r or "PartitionId" in r)
     n_hyp = sum(c for c, r in skips if "hypothesis" in r)
     unknown = [(c, r) for c, r in skips
-               if "old jaxlib" not in r and "PartitionId" not in r
-               and "hypothesis" not in r
+               if "hypothesis" not in r
                and not any(a in r for a in _ALLOWED_CONDITIONAL)]
 
     exp_hyp = 0 if have_hyp else 5
     ok = True
-    if n_partial != 0:
-        ok = False
-        print(f"FAIL: {n_partial} partial-auto-SPMD skips, expected 0 on "
-              "every leg (supports_partial_auto_spmd()="
-              f"{compat.supports_partial_auto_spmd()}) — the manual TP "
-              "lowering should have replaced the tp=1 fallback; either it "
-              "regressed or a new gated test wasn't registered here")
     if n_hyp != exp_hyp:
         ok = False
         print(f"FAIL: {n_hyp} hypothesis skips, expected {exp_hyp} "
@@ -75,17 +50,8 @@ def main(path: str) -> int:
     if unknown:
         ok = False
         print(f"FAIL: unknown skip reasons: {unknown}")
-    expect_tl = os.environ.get("REPRO_EXPECT_TP_LOWERING")
-    resolved_tl = compat.resolve_tp_lowering("auto")
-    if expect_tl and resolved_tl != expect_tl:
-        ok = False
-        print(f"FAIL: tp_lowering resolves to {resolved_tl!r} but this CI "
-              f"leg expects {expect_tl!r} (REPRO_EXPECT_TP_LOWERING) — the "
-              "matrix env and compat.resolve_tp_lowering disagree")
     if ok:
-        print(f"skip matrix OK: partial-auto={n_partial} "
-              f"hypothesis={n_hyp} tp_lowering={resolved_tl} "
-              "(jax capabilities match expectations)")
+        print(f"skip matrix OK: hypothesis={n_hyp}")
     return 0 if ok else 1
 
 

@@ -10,14 +10,6 @@ family it also picks the SSD inner loop); ``both`` additionally asserts
 jnp-vs-pallas parity directly. ``kv_dtype`` (auto | int8 | fp8) selects the
 KV page codec and ``page_tokens`` the page size (0 = one page per chunk).
 Prints "PASS <max_err>" or raises.
-
-jax-version note: on old jaxlib (no partial-auto SPMD — see
-``repro.compat.supports_partial_auto_spmd``) the shallow 4-stage x tp=2 mesh
-cannot lower with GSPMD-auto TP (PartitionId) — ``build_plan`` resolves
-``tp_lowering="auto"`` to the MANUAL lowering there (explicit transport
-psums, all mesh axes manual; DESIGN.md §3.6), so TP=2 coverage runs on BOTH
-jaxlib legs. ``REPRO_TP_LOWERING`` pins the choice (the CI matrix asserts
-the manual path is exercised on the old-jaxlib leg).
 """
 import sys
 

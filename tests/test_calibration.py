@@ -3,8 +3,7 @@ an exact linear factorization of the analytic cost, ``obs.calibrate``
 recovers a perturbed ground-truth profile from noiseless spans, the
 calibrated-profile JSON round-trips bit-identically into ``plan_partition``
 / ``chunk_cost_arrays``, a mid-stream scheduler recalibration never reorders
-admitted history, the measured-span replay returns bit-identical logits
-with a telemetry-aligned ``MeasuredProfile``, and the health sentinels are
+admitted history, and the health sentinels are
 provably free when disarmed (zero extra collectives) and bit-identical when
 armed."""
 import os
@@ -220,123 +219,6 @@ def test_engine_recalibrate_swaps_costs_in_place():
         eng.submit(Request(rid=i, arrival=0.0, seq_len=8192))
     eng.run_until_drained()
     assert eng.metrics()["completed"] == done_before + 2
-
-
-# ------------------------------------------------- measured spans (8 chips)
-
-SNIPPET_MEASURED = """
-import numpy as np, jax
-from repro import compat
-from repro.compat import AxisType
-from repro.configs.base import RunConfig, get_smoke_config, replace
-from repro.core import pipeline as pp
-from repro.models.api import build_model
-from repro.models.topology import Topology
-from repro.obs.profile import measure_prefill
-
-cfg = replace(get_smoke_config("qwen3-8b"), dtype="float32")
-n, m, s, b = 8, 8, 128, 2
-mesh = compat.make_mesh((n, 1), ("data", "model"),
-                        axis_types=(AxisType.Auto,) * 2)
-topo = Topology(mesh=mesh)
-model = build_model(cfg)
-params = model.init(jax.random.key(0))
-toks = jax.random.randint(jax.random.key(1), (b, s), 0, cfg.vocab_size)
-plan = pp.build_plan(cfg, n, s, RunConfig(num_chunks=m, num_stages=n))
-staged = pp.stage_params(cfg, params, plan)
-
-with compat.set_mesh(mesh):
-    logits0 = jax.jit(lambda st, tk: pp.prefill_pipeline(
-        cfg, st, tk, plan, topo))(staged, toks)
-    logits, meas = measure_prefill(cfg, staged, toks, plan, topo)
-
-# the hooked replay computes the SAME program: bit-identical logits
-assert (np.asarray(logits) == np.asarray(logits0)).all()
-# telemetry-aligned layout: [N, T] with T = M + N - 1
-assert meas.tick_s.shape == (n, m + n - 1)
-valid = meas.valid(m)
-assert valid.sum() == n * m
-# lockstep ticks all beaconed -> every VALID cell carries a real positive
-# span (the tick's wall clock, broadcast to the stages active that tick);
-# bubble cells stay exactly zero
-assert (meas.tick_s[valid] > 0).all()
-assert (meas.tick_s[~valid] == 0).all()
-assert meas.total() > 0
-assert meas.to_dict()["tick_s"][0][0] == float(meas.tick_s[0, 0])
-
-# timed-kernel attribution: per-tag totals ride count_launches(timed=True).
-# The default jnp backend launches no Pallas kernels, so time the pallas
-# plan — its self block + pool scan are what the tag stream attributes.
-plan_pl = pp.build_plan(cfg, n, s, RunConfig(num_chunks=m, num_stages=n,
-                                             attn_backend="pallas"))
-with compat.set_mesh(mesh):
-    _, meas_k = measure_prefill(cfg, staged, toks, plan_pl, topo,
-                                timed_kernels=True)
-assert "chunk_attention" in meas_k.kernel_s, meas_k.kernel_s
-assert all(v >= 0 for v in meas_k.kernel_s.values())
-print("PASS")
-"""
-
-
-def test_measured_profile_matches_run():
-    """Tentpole acceptance (measure leg): the timed replay is bit-identical
-    to the bare pipeline, and its spans land index-aligned with the
-    telemetry profiles, with per-kernel-tag attribution available."""
-    _run(SNIPPET_MEASURED)
-
-
-SNIPPET_FIT_FROM_MEASURED = """
-import numpy as np, jax
-from repro import compat
-from repro.compat import AxisType
-from repro.configs.base import RunConfig, get_smoke_config, replace
-from repro.core import costmodel as cm
-from repro.core import pipeline as pp
-from repro.models.api import build_model
-from repro.models.topology import Topology
-from repro.obs import calibrate as cal
-from repro.obs.profile import measure_prefill
-
-cfg = replace(get_smoke_config("qwen3-8b"), dtype="float32")
-n, m, s, b = 8, 8, 128, 2
-mesh = compat.make_mesh((n, 1), ("data", "model"),
-                        axis_types=(AxisType.Auto,) * 2)
-topo = Topology(mesh=mesh)
-model = build_model(cfg)
-params = model.init(jax.random.key(0))
-toks = jax.random.randint(jax.random.key(1), (b, s), 0, cfg.vocab_size)
-plan = pp.build_plan(cfg, n, s, RunConfig(num_chunks=m, num_stages=n))
-staged = pp.stage_params(cfg, params, plan)
-with compat.set_mesh(mesh):
-    _, meas = measure_prefill(cfg, staged, toks, plan, topo)
-
-# end-to-end closed loop on REAL spans: fit -> calibrated profile whose
-# L2 residual on its own measurements never beats the datasheet's. (The
-# fit minimizes L2, not MAPE, so the L2 residual is the guaranteed
-# quantity; the non-positive-rate clamp can substitute nominal theta
-# components, which we detect by exact equality and allow slack for.)
-sm = cm.StageModel.build(cfg, n, 1)
-chunks = [s // m] * m
-fit = cal.fit_profile(sm, chunks, meas, cm.WSC_PAPER)
-assert fit.profile.name.endswith("+cal")
-assert len(fit.rows) == n * m
-assert np.isfinite(fit.mape_calibrated) and np.isfinite(fit.mape_nominal)
-X, y, rows = cal.design_matrix(sm, chunks, cm.WSC_PAPER, meas.tick_s)
-r_cal = float(np.linalg.norm(fit.residual_s))
-r_nom = float(np.linalg.norm(y - X @ fit.theta_nominal))
-clamped = fit.theta == fit.theta_nominal
-if not clamped.any():
-    assert r_cal <= r_nom * (1 + 1e-9), (r_cal, r_nom)
-else:
-    assert r_cal <= r_nom * 1.5, (r_cal, r_nom, clamped)
-print("PASS")
-"""
-
-
-def test_fit_from_real_measured_spans():
-    """The loop closes on real (host-clock) spans too: fitting never does
-    worse than the nominal profile on the spans it was fit to."""
-    _run(SNIPPET_FIT_FROM_MEASURED)
 
 
 # ------------------------------------------------- health sentinels (8 chips)

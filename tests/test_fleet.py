@@ -92,7 +92,7 @@ def test_protocol_only_access_source_scan():
     poking executor observability flags, no reading .done/.waves directly
     (the PR 5 transport-grep idiom applied to the engine seam)."""
     forbidden = re.compile(
-        r"\.scheduler\.|\.lease\.|\.collect_telemetry|\.collect_measured"
+        r"\.scheduler\.|\.lease\.|\.collect_telemetry"
         r"|\.stage_free|\.metrics\.records|\bexecutor\.[a-z_]+\s*="
         r"|eng\.done\b|cell\.done\b|\.executor\.")
     files = [os.path.join(ROOT, "src", "repro", "launch", "serve.py")]

@@ -373,24 +373,33 @@ def test_atomic_write(tmp_path):
 # ------------------------------------------------------------ kernel launches
 
 def test_count_launches_nested_and_tagged():
+    """Launches come from the traced program: a kernel call inside a jitted
+    function inside a scan counts once per iteration, per kernel tag, and
+    counting runs nothing."""
     import jax
+    import jax.numpy as jnp
     from repro.kernels import ops
-    q = np.zeros((1, 8, 2, 16), np.float32)
-    k = np.zeros((1, 8, 2, 16), np.float32)
+    q = jnp.zeros((1, 8, 2, 16), jnp.float32)
+    k = jnp.zeros((1, 8, 2, 16), jnp.float32)
 
-    def attend():
-        ops.chunk_attention(jax.numpy.asarray(q), jax.numpy.asarray(k),
-                            jax.numpy.asarray(k)).block_until_ready()
+    def attend(q):
+        return ops.chunk_attention(q, k, k)
 
-    with ops.count_launches() as outer:
-        attend()
-        with ops.count_launches() as inner:
-            attend()
-    assert inner["count"] == 1 and inner["chunk_attention"] == 1
-    assert outer["count"] == 2 and outer["chunk_attention"] == 2
-    assert "pool_attention" not in outer  # only tags that actually launched
-    # the stack drained: launches outside any context cost nothing
-    assert not ops._LAUNCH_FRAMES
+    assert ops.count_launches(attend, q) == {"count": 1,
+                                             "chunk_attention": 1}
+
+    @jax.jit
+    def twice(q):
+        return attend(attend(q))
+
+    def scanned(q):
+        return jax.lax.scan(lambda c, _: (twice(c), None), q, None,
+                            length=3)[0]
+
+    lc = ops.count_launches(scanned, q)
+    assert lc == {"count": 6, "chunk_attention": 6}
+    assert "pool_attention" not in lc  # only tags that actually launch
+    assert ops.count_launches(lambda q: q * 2, q) == {"count": 0}
 
 
 # ----------------------------------------------------------- serve smoke

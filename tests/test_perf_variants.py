@@ -1,13 +1,10 @@
 """Optimized-lowering variants (§Perf) stay bit-comparable to the oracle:
 kv_split attention mesh, q-head padding, expert parallelism padding.
 
-Under GSPMD these lowerings need auto-typed TP axes of size > 1 inside
-shard_map, which old jaxlib cannot partition ("UNIMPLEMENTED:
-PartitionId..."). ``build_plan`` resolves ``tp_lowering="auto"`` to the
-MANUAL lowering there (explicit transport psums + manual expert
-parallelism, DESIGN.md §3.6), so these tests now run — and the kv_split /
-EP numerics hold — on BOTH jaxlib legs. The snippets print the resolved
-lowering so CI logs show which path ran."""
+Under GSPMD these lowerings use auto-typed TP axes of size > 1 inside
+shard_map; the MANUAL lowering (explicit transport psums + manual expert
+parallelism, DESIGN.md §3.6) is the alternative. The snippets print the
+resolved lowering so CI logs show which path ran."""
 import os
 import subprocess
 import sys

@@ -7,7 +7,7 @@ explicitly here: within 1e-6 (fp32 combine) on float pages, < 2e-3 headroom
 on int8 pages (both paths read IDENTICAL quantized pages, so the observed
 divergence stays at fp32-rounding level).
 
-Also: the launch-counting hook (`ops.count_launches`) pins the O(1)-in-pool-
+Also: the launch count (`ops.count_launches`) pins the O(1)-in-pool-
 depth property, and a hypothesis property test sweeps ragged occupancy
 (random slot subsets, mixed chunk ids vs. limit, empty pool, single slot).
 """
@@ -138,8 +138,9 @@ def test_batched_pool_all_invalid_is_identity(backend):
 def test_launch_count_is_o1_in_pool_depth():
     """The acceptance hook: kernel launches per pool scan must be 1 under
     the batched path regardless of pool depth, vs one per slot in the
-    per-slot order (counted at RUNTIME via ops.count_launches, so scan
-    iterations are counted, not trace sites)."""
+    per-slot order (ops.count_launches multiplies each kernel call site by
+    the trip counts of the scans around it, so scan iterations are counted,
+    not trace sites)."""
     import jax
     import jax.numpy as jnp
     from repro.core import attention as A
@@ -153,9 +154,7 @@ def test_launch_count_is_o1_in_pool_depth():
         fn = jax.jit(lambda q: A.attn_finish(A.pool_scan(
             be, q, pool_l, tbl, sc, jnp.int32(nslots), 0.25,
             A.attn_init(b, c, kvh, g, d)), jnp.float32))
-        with ops.count_launches() as launches:
-            fn(qg).block_until_ready()
-        return dict(launches)
+        return ops.count_launches(fn, qg)
 
     batched = A.get_backend("pallas")
     paged = A.get_backend("paged")
@@ -272,18 +271,18 @@ def test_ragged_occupancy_cases(nslots, chunk_ids, limit, subset_mask,
                      np.asarray(subset_mask), kv_dtype)
 
 
-@pytest.mark.parametrize("use_dma", [True, False])
-def test_paged_partial_last_page(use_dma):
-    """``kv_len`` < C: the paged kernel masks the partial page's tail AND
-    statically drops trailing all-dead pages (np_eff), on both buffering
-    schemes (manual double-buffered DMA and the BlockSpec fallback) —
-    parity vs the gathered kernel on token-truncated stacks."""
+@pytest.mark.parametrize("kv_len", [20, 24])
+def test_paged_partial_last_page(kv_len):
+    """``kv_len`` < C: the paged kernel masks the partial page's tail (20)
+    or ends on a page boundary (24), AND statically drops trailing all-dead
+    pages (np_eff) — parity vs the gathered kernel on token-truncated
+    stacks."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import ops
     from repro.kvstore import pages as PG
     b, c, kvh, g, d = 1, 32, 2, 2, 32
-    nslots, kv_len = 3, 20  # pt=8: pages 0-1 full, page 2 partial, page 3 dead
+    nslots = 3  # pt=8: pages 0-1 full, page 2 partial or full, page 3 dead
     _, tbl, pool_l = _build_pool(nslots, "float32", b, c, kvh, d,
                                  page_tokens=8)
     k_l, v_l, ks_l, vs_l = pool_l
@@ -293,8 +292,7 @@ def test_paged_partial_last_page(use_dma):
     valid = jnp.ones((nslots,), jnp.int32)
     q = jax.random.normal(jax.random.key(9), (b, c, kvh * g, d), jnp.float32)
     m, l, acc = ops.pool_attention_paged(q, k_l, v_l, handles, valid,
-                                         ppc=rows.shape[1], kv_len=kv_len,
-                                         use_dma=use_dma)
+                                         ppc=rows.shape[1], kv_len=kv_len)
     kq, vq, _, _ = PG.gather_chunks(k_l, v_l, ks_l, vs_l, jnp.asarray(rows))
     mr, lr, accr = ops.pool_attention(q, kq[:, :, :kv_len], vq[:, :, :kv_len],
                                       valid)

@@ -228,3 +228,86 @@ def test_engine_checkpoint_roundtrip_field_fidelity():
         got = done2[orig.rid]
         assert got.finish_time == pytest.approx(orig.finish_time)
         assert (got.arrival, got.seq_len) == (orig.arrival, orig.seq_len)
+
+
+# ------------------------------------------------- serve: device-facing launch
+
+def test_pipeline_shape_one_stage_per_device():
+    from repro.launch.options import ServeOptions
+    from repro.launch.serve import pipeline_shape
+    jnp_opts = ServeOptions(attn_backend="jnp", pool_backend="jnp")
+    for platform in ("cpu", "tpu"):
+        # one chip: a one-stage pipeline
+        assert pipeline_shape(1, jnp_opts, platform) == (1, 1)
+        assert pipeline_shape(2, jnp_opts, platform) == (2, 1)
+        assert pipeline_shape(4, jnp_opts, platform) == (2, 2)
+        assert pipeline_shape(8, jnp_opts, platform) == (4, 2)
+    # Mosaic kernels lower only in a fully manual region: on a TPU with a
+    # Pallas backend under auto TP lowering every device is a stage
+    for kw in ({"attn_backend": "pallas"}, {"pool_backend": "paged"},
+               {"ssm_backend": "pallas"}):
+        opts = ServeOptions(**kw)
+        assert pipeline_shape(4, opts, "tpu") == (4, 1)
+        assert pipeline_shape(8, opts, "tpu") == (8, 1)
+        assert pipeline_shape(4, opts, "cpu") == (2, 2)  # interpret mode
+        manual = opts.override(tp_lowering="manual")
+        assert pipeline_shape(4, manual, "tpu") == (2, 2)
+
+
+def test_device_profile_by_kind():
+    """The jax executor prices with the profile of the device it runs on;
+    an accelerator kind without a profile is an error, not a v5e default."""
+    from types import SimpleNamespace as NS
+    assert cm.device_profile(NS(platform="tpu",
+                                device_kind="TPU v5 lite")) is cm.TPU_V5E
+    assert cm.device_profile(NS(platform="cpu",
+                                device_kind="cpu")) is cm.TPU_V5E
+    with pytest.raises(ValueError, match="TPU v4"):
+        cm.device_profile(NS(platform="tpu", device_kind="TPU v4"))
+
+
+SNIPPET_ONE_STAGE = """
+import jax, numpy as np
+from repro.launch import serve
+from repro.launch.options import ServeOptions
+from repro.models.api import build_model
+
+assert len(serve.jax_devices()) == 1
+opts = ServeOptions(requests=2, seq=64, num_chunks=4, max_batch=2,
+                    attn_backend="pallas", pool_backend="paged")
+cfg, ec, eng = serve._build_engine(opts)
+assert (ec.num_stages, ec.tp) == (1, 1)
+reqs = serve._make_requests(opts, cfg.vocab_size)
+for r in reqs:
+    eng.submit(r)
+eng.run_until_drained()
+done = sorted(eng.poll(), key=lambda r: r.rid)
+assert [w["rids"] for w in eng.waves()] == [[0, 1]]
+# the one-stage pipeline's logits == the plain forward on the same params
+staged = eng.executor.staged
+flat = {k: v for k, v in staged.items() if k != "stage_layers"}
+flat["layers"] = jax.tree.map(lambda a: a[0], staged["stage_layers"])
+toks = np.stack([r.tokens for r in reqs])
+ref = np.asarray(build_model(cfg).forward(flat, toks)[:, -1])
+got = np.stack([r.result for r in done])
+err = float(np.max(np.abs(got - ref)))
+assert err < 1e-4, err
+print("PASS", err)
+"""
+
+
+def test_serve_jax_one_stage_single_device():
+    """``serve``'s jax path on ONE device builds a one-stage engine (no
+    second device demanded), serves through the Pallas kernels, and
+    matches the full-sequence forward."""
+    import subprocess
+    import sys
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.path.join(root, "src")
+    r = subprocess.run([sys.executable, "-c", SNIPPET_ONE_STAGE],
+                       capture_output=True, text=True, env=env, timeout=600)
+    assert r.returncode == 0, f"{r.stdout}\n{r.stderr}"
+    assert "PASS" in r.stdout, r.stdout

@@ -48,16 +48,11 @@ def test_transport_registry():
         tx.get_transport("nope")
 
 
-def test_resolve_tp_lowering(monkeypatch):
+def test_resolve_tp_lowering():
     from repro import compat
     assert compat.resolve_tp_lowering("manual") == "manual"
-    monkeypatch.setenv("REPRO_TP_LOWERING", "manual")
-    assert compat.resolve_tp_lowering("auto") == "manual"
-    monkeypatch.setenv("REPRO_TP_LOWERING", "auto")
     assert compat.resolve_tp_lowering("auto") == "auto"
-    monkeypatch.delenv("REPRO_TP_LOWERING")
-    expected = "auto" if compat.supports_partial_auto_spmd() else "manual"
-    assert compat.resolve_tp_lowering("auto") == expected
+    assert compat.resolve_tp_lowering("") == "auto"
     with pytest.raises(ValueError):
         compat.resolve_tp_lowering("gspmd")
 
@@ -108,6 +103,32 @@ def test_analytic_model_shapes():
     only_early = [ppc] * plan_i8.p2 + [1] * (plan_i8.num_chunks - plan_i8.p2)
     wearly = tx.analytic_wire_bytes(plan_i8, cfg, 2, resident_pages=only_early)
     assert wearly["spill"] == wrag["spill"]  # chunks < p2 never spill
+
+
+@pytest.mark.parametrize("spill", [0, 1, 2, 4])
+def test_mbkr_spill_chunks_reach_remote_attention(spill):
+    """At N=4, M=16 the fewest-slots plan spills only the last chunk, which
+    no later chunk reads: no remote attention at all. ``mbkr_spill_chunks``
+    moves p2 so that later chunks read spilled ones (qship traffic)."""
+    from repro.configs.base import RunConfig, get_smoke_config
+    from repro.core import mbkr
+    from repro.core import pipeline as pp
+    from repro.core import transport as tx
+    cfg = get_smoke_config("qwen3-8b")
+    run = RunConfig(num_chunks=16, num_stages=4, mbkr_spill_chunks=spill)
+    plan = pp.build_plan(cfg, 4, 256, run)
+    w = tx.analytic_wire_bytes(plan, cfg, 1)
+    if spill == 0:
+        assert plan.p2 == mbkr.best_p2(16, 4)[0] == 15
+    else:
+        assert plan.p2 == 16 - spill
+        mbkr.verify_plan(mbkr.plan(16, 4, p2=plan.p2))
+    assert w["spill"] > 0
+    # chunk j reads the spilled chunks p2..j-1: none when only the last spills
+    assert (w["qship_q"] > 0) == (plan.p2 <= 14)
+    with pytest.raises(ValueError, match="mbkr_spill_chunks"):
+        pp.build_plan(cfg, 4, 256, RunConfig(num_chunks=16, num_stages=4,
+                                              mbkr_spill_chunks=16))
 
 
 # ---------------------------------------- runtime ledger vs the §3.4 model
@@ -236,10 +257,7 @@ for fb in ("off", "on"):
     with compat.set_mesh(mesh):
         fn = jax.jit(lambda st, tk: pp.prefill_pipeline(
             cfg, st, tk, plan, topo))
-        with ops.count_launches() as lc:
-            out = fn(staged, toks)
-            out.block_until_ready()
-        launches[fb] = lc["count"]
+        launches[fb] = ops.count_launches(fn, staged, toks)["count"]
         outs[fb] = np.asarray(fn(staged, toks))
 
 # batched == streamed numerics at 1e-6 (same kernel, combine moved into the
@@ -250,7 +268,7 @@ assert diff < 1e-6, diff
 # O(1) attention launches per tick for the fetch part: per (tick, layer)
 # the streamed path launches one chunk_attention per landed chunk, the
 # batched path ONE pool_attention regardless of n_remote (count_launches
-# counts per traced program, SPMD-wide)
+# counts kernel call sites x scan trip counts of the per-stage program)
 ticks, lps = m + n - 1, plan.layers_per_stage
 # streamed: self + own-pool + n_remote fetch; batched: self + own-pool + 1
 assert launches["off"] == ticks * lps * (2 + n_remote), launches
@@ -364,10 +382,8 @@ for pool in ("pallas", "paged"):
     with compat.set_mesh(mesh):
         fn = jax.jit(lambda st, tk: pp.prefill_pipeline(
             cfg, st, tk, plan, topo, return_ledger=True))
-        with ops.count_launches() as lc:
-            out, led = fn(staged, toks)
-            out.block_until_ready()
-        launches[pool] = dict(lc)
+        launches[pool] = ops.count_launches(fn, staged, toks)
+        out, led = fn(staged, toks)
     outs[pool] = np.asarray(out)
     if pool == "paged":
         # wire traffic is IDENTICAL under the paged kernel: it changes the
