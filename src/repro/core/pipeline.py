@@ -237,24 +237,28 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
                            transport=transport, mtp=mtp, x_spec=x_spec,
                            prefix_chunks=prefix_chunks)
             # ---- input: stage 0 embeds chunk t; others consume the ring buffer
-            tc = jnp.clip(t, 0, m - 1)
-            if n_front:
-                pos = tc * c + jnp.arange(c)               # global positions
-                tok_idx = jnp.clip(pos - n_front, 0, tokens.shape[1] - 1)
-                tok_chunk = jnp.take(tokens, tok_idx, axis=1)
-                x_tok = jnp.take(embed, tok_chunk, axis=0)
-                fstart = jnp.minimum(tc * c, embeds_pad.shape[1] - c)
-                x_front = jax.lax.dynamic_slice(
-                    embeds_pad, (0, fstart, 0), (b, c, cfg.d_model)).astype(x_tok.dtype)
-                x_emb = jnp.where((pos < n_front)[None, :, None], x_front, x_tok)
-            else:
-                tok_chunk = jax.lax.dynamic_slice(tokens, (0, tc * c), (b, c))
-                x_emb = jnp.take(embed, tok_chunk, axis=0)
-            if cfg.embedding_multiplier != 1.0:
-                x_emb = x_emb * cfg.embedding_multiplier
-            x = jnp.where(stage == 0, x_emb.astype(dt), x_prev)
-            if auto_tp(topo, mtp):
-                x = jax.lax.with_sharding_constraint(x, x_spec)
+            with jax.named_scope("stage.embed"):
+                tc = jnp.clip(t, 0, m - 1)
+                if n_front:
+                    pos = tc * c + jnp.arange(c)           # global positions
+                    tok_idx = jnp.clip(pos - n_front, 0, tokens.shape[1] - 1)
+                    tok_chunk = jnp.take(tokens, tok_idx, axis=1)
+                    x_tok = jnp.take(embed, tok_chunk, axis=0)
+                    fstart = jnp.minimum(tc * c, embeds_pad.shape[1] - c)
+                    x_front = jax.lax.dynamic_slice(
+                        embeds_pad, (0, fstart, 0),
+                        (b, c, cfg.d_model)).astype(x_tok.dtype)
+                    x_emb = jnp.where((pos < n_front)[None, :, None],
+                                      x_front, x_tok)
+                else:
+                    tok_chunk = jax.lax.dynamic_slice(tokens, (0, tc * c),
+                                                      (b, c))
+                    x_emb = jnp.take(embed, tok_chunk, axis=0)
+                if cfg.embedding_multiplier != 1.0:
+                    x_emb = x_emb * cfg.embedding_multiplier
+                x = jnp.where(stage == 0, x_emb.astype(dt), x_prev)
+                if auto_tp(topo, mtp):
+                    x = jax.lax.with_sharding_constraint(x, x_spec)
             # ---- stage compute
             if is_ssm:
                 x_out, state, led, tel = ssm_stage_step(ctx, stage_layers, x,
@@ -271,8 +275,10 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
                 tel = obs_t.charge_tick_residency(tel, ctx, chunk_bytes, rep)
             tel_ys = None if tel is None else dict(tel)
             # ---- capture the last token's hidden state at the last stage
-            take = (stage == n - 1) & (phase == m - 1)
-            x_last = jnp.where(take, x_out[:, -1].astype(jnp.float32), x_last)
+            with jax.named_scope("stage.head"):
+                take = (stage == n - 1) & (phase == m - 1)
+                x_last = jnp.where(take, x_out[:, -1].astype(jnp.float32),
+                                   x_last)
             # ---- ring transfer to the next stage (useful while my chunk is
             # real and a downstream stage consumes it)
             ring_active = (phase >= 0) & (phase < m) & (stage < n - 1)
@@ -374,14 +380,16 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
 
     # final norm + unembed of the single output token (prefill-only)
     from jax.sharding import NamedSharding
-    x_last = L.rms_norm(x_last[:, None, :].astype(dt), staged["final_norm"],
-                        cfg.norm_eps)
-    w = staged["embed"].T if ("lm_head" not in staged) else staged["lm_head"]
-    logits = L.unembed_logits(x_last, w, scale=cfg.logits_scaling)
-    logits = jax.lax.with_sharding_constraint(
-        logits, NamedSharding(topo.mesh, P(
-            tuple(a for a in topo.batch_axes if a != topo.stage_axis) or None,
-            None, None if mtp is not None else topo.tp_axis)))
+    with jax.named_scope("stage.head"):
+        x_last = L.rms_norm(x_last[:, None, :].astype(dt),
+                            staged["final_norm"], cfg.norm_eps)
+        w = (staged["embed"].T if ("lm_head" not in staged)
+             else staged["lm_head"])
+        logits = L.unembed_logits(x_last, w, scale=cfg.logits_scaling)
+        logits = jax.lax.with_sharding_constraint(
+            logits, NamedSharding(topo.mesh, P(
+                tuple(a for a in topo.batch_axes if a != topo.stage_axis)
+                or None, None, None if mtp is not None else topo.tp_axis)))
     ret = [logits[:, 0]]
     if return_ledger:
         ret.append(ledger)

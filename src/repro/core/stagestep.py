@@ -126,10 +126,6 @@ def attend_chunk(ctx: StageCtx, l_idx: jax.Array, q: jax.Array,
         else get_backend(plan.pool_backend)
     b, c, h, d = q.shape
     kvh = k_new.shape[2]
-    qg = group_queries(q, kvh)
-    st = attn_init(b, c, kvh, h // kvh, d)
-
-    pool_l = remote._pool_layer(pool, l_idx)
 
     # telemetry: actual attention work this (layer, tick) — the LBCP cost
     # term with the TRACED prefix (phase * c tokens behind this chunk)
@@ -139,29 +135,33 @@ def attend_chunk(ctx: StageCtx, l_idx: jax.Array, q: jax.Array,
                            cm.attn_flops(ctx.cfg, c, prefix),
                            ctx.active, _rep(ctx))
 
-    # 1. own local prefix: chunks j < min(phase, p2)
-    limit = jnp.minimum(ctx.phase, plan.p2)
-    st = pool_scan(pool_be, qg, pool_l, plan.slot_pages, plan.slot_own_chunk,
-                   limit, ctx.scale, st)
-    # lockstep: the pool scan launches every tick (batched = one slot-grid
-    # block; streamed = one block per slot)
-    tel = obs_t.charge(tel, "launches",
-                       1.0 if pool_be.batched_pool else float(plan.num_slots),
-                       None, _rep(ctx))
-
-    # 2. remote prefix: chunks p2 <= j < phase live at my pair
-    if plan.p2 < plan.num_chunks and plan.mode == "mocap":
-        if plan.remote_attn == "fetch":
-            st, led, tel = remote.fetch_remote(ctx, pool_be, qg, pool_l, st,
-                                               led, tel)
-        else:
-            st, led, tel = remote.qship_remote(ctx, pool_be, qg, pool_l, st,
-                                               led, tel)
+    with jax.named_scope("layer.attn_pool"):
+        qg = group_queries(q, kvh)
+        st = attn_init(b, c, kvh, h // kvh, d)
+        pool_l = remote._pool_layer(pool, l_idx)
+        # 1. own local prefix: chunks j < min(phase, p2)
+        limit = jnp.minimum(ctx.phase, plan.p2)
+        st = pool_scan(pool_be, qg, pool_l, plan.slot_pages,
+                       plan.slot_own_chunk, limit, ctx.scale, st)
+        # lockstep: the pool scan launches every tick (batched = one
+        # slot-grid block; streamed = one block per slot)
+        tel = obs_t.charge(tel, "launches",
+                           1.0 if pool_be.batched_pool
+                           else float(plan.num_slots), None, _rep(ctx))
+        # 2. remote prefix: chunks p2 <= j < phase live at my pair
+        if plan.p2 < plan.num_chunks and plan.mode == "mocap":
+            if plan.remote_attn == "fetch":
+                st, led, tel = remote.fetch_remote(ctx, pool_be, qg, pool_l,
+                                                   st, led, tel)
+            else:
+                st, led, tel = remote.qship_remote(ctx, pool_be, qg, pool_l,
+                                                   st, led, tel)
 
     # 3. self block (causal)
-    st = backend.self_block(qg, k_new, v_new, ctx.scale, st)
+    with jax.named_scope("layer.attn_self"):
+        st = backend.self_block(qg, k_new, v_new, ctx.scale, st)
+        att = attn_finish(st, q.dtype)
     tel = obs_t.charge(tel, "launches", 1.0, None, _rep(ctx))
-    att = attn_finish(st, q.dtype)
     return att, led, tel
 
 
@@ -177,9 +177,10 @@ def tfm_stage_step(ctx: StageCtx, layers: Params, x: jax.Array,
     tr = ctx.transport
     b, c, dm = x.shape
     hd = cfg.resolved_head_dim
-    positions = jnp.clip(ctx.phase, 0, plan.num_chunks - 1) * plan.chunk_len \
-        + jnp.arange(c)[None, :]
-    cos, sin = L.rope_angles(positions, hd, cfg.rope_theta)
+    with jax.named_scope("layer.attn_proj"):
+        positions = jnp.clip(ctx.phase, 0, plan.num_chunks - 1) \
+            * plan.chunk_len + jnp.arange(c)[None, :]
+        cos, sin = L.rope_angles(positions, hd, cfg.rope_theta)
     tp_apply = _tp_apply(ctx)
     # mirrors ffn_block's psum condition exactly: ONE reduce iff any FFN
     # part is actually sharded for THIS config (dense for non-MoE; expert
@@ -192,33 +193,37 @@ def tfm_stage_step(ctx: StageCtx, layers: Params, x: jax.Array,
     def layer_body(carry, xs):
         xc, li, led, tel = carry
         lp = xs if cross is None else xs[0]
-        hn = L.rms_norm(xc, lp["ln1"], cfg.norm_eps)
-        # LOCAL head counts come from the (possibly TP-sharded) params
-        q = jnp.einsum("bcd,dq->bcq", hn, lp["wq"])
-        k = jnp.einsum("bcd,dq->bcq", hn, lp["wk"])
-        v = jnp.einsum("bcd,dq->bcq", hn, lp["wv"])
-        q = q.reshape(b, c, q.shape[-1] // hd, hd)
-        k = k.reshape(b, c, k.shape[-1] // hd, hd)
-        v = v.reshape(b, c, v.shape[-1] // hd, hd)
-        if cfg.qk_norm:
-            q = L.rms_norm(q, lp["q_norm"], cfg.norm_eps)
-            k = L.rms_norm(k, lp["k_norm"], cfg.norm_eps)
-        q = L.apply_rope(q, cos, sin)
-        k = L.apply_rope(k, cos, sin)
-        if ctx.auto_tp:
-            q = jax.lax.with_sharding_constraint(
-                q, P(None, None, ctx.topo.tp_axis, None))
-            if isinstance(ctx.topo.tp_axis, tuple):
-                kv_ax = ctx.topo.tp_axis[0]
-                k = jax.lax.with_sharding_constraint(k, P(None, None, kv_ax, None))
-                v = jax.lax.with_sharding_constraint(v, P(None, None, kv_ax, None))
+        with jax.named_scope("layer.attn_proj"):
+            hn = L.rms_norm(xc, lp["ln1"], cfg.norm_eps)
+            # LOCAL head counts come from the (possibly TP-sharded) params
+            q = jnp.einsum("bcd,dq->bcq", hn, lp["wq"])
+            k = jnp.einsum("bcd,dq->bcq", hn, lp["wk"])
+            v = jnp.einsum("bcd,dq->bcq", hn, lp["wv"])
+            q = q.reshape(b, c, q.shape[-1] // hd, hd)
+            k = k.reshape(b, c, k.shape[-1] // hd, hd)
+            v = v.reshape(b, c, v.shape[-1] // hd, hd)
+            if cfg.qk_norm:
+                q = L.rms_norm(q, lp["q_norm"], cfg.norm_eps)
+                k = L.rms_norm(k, lp["k_norm"], cfg.norm_eps)
+            q = L.apply_rope(q, cos, sin)
+            k = L.apply_rope(k, cos, sin)
+            if ctx.auto_tp:
+                q = jax.lax.with_sharding_constraint(
+                    q, P(None, None, ctx.topo.tp_axis, None))
+                if isinstance(ctx.topo.tp_axis, tuple):
+                    kv_ax = ctx.topo.tp_axis[0]
+                    k = jax.lax.with_sharding_constraint(
+                        k, P(None, None, kv_ax, None))
+                    v = jax.lax.with_sharding_constraint(
+                        v, P(None, None, kv_ax, None))
         att, led, tel = attend_chunk(ctx, li, q, k, v, pool, led, tel)
-        h_loc = att.shape[2]
-        upd = jnp.einsum("bcq,qd->bcd", att.reshape(b, c, h_loc * hd),
-                         lp["wo"])
-        if mtp is not None and mtp.attn:
-            upd, led = tr.tp_psum(upd, mtp.axes, led, active=ctx.active)
-        xc = xc + cfg.residual_multiplier * upd
+        with jax.named_scope("layer.attn_proj"):
+            h_loc = att.shape[2]
+            upd = jnp.einsum("bcq,qd->bcd", att.reshape(b, c, h_loc * hd),
+                             lp["wo"])
+            if mtp is not None and mtp.attn:
+                upd, led = tr.tp_psum(upd, mtp.axes, led, active=ctx.active)
+            xc = xc + cfg.residual_multiplier * upd
         if cross is not None:
             xk_l = jax.lax.dynamic_index_in_dim(cross[0], li, 0, keepdims=False)
             xv_l = jax.lax.dynamic_index_in_dim(cross[1], li, 0, keepdims=False)
@@ -241,24 +246,28 @@ def tfm_stage_step(ctx: StageCtx, layers: Params, x: jax.Array,
             tel = obs_t.charge(tel, "launches", 1.0, None, _rep(ctx))
         ep_axis = ctx.topo.tp_axis if (cfg.moe is not None and isinstance(
             ctx.topo.tp_axis, tuple) and ctx.auto_tp) else None
-        if ep_axis is not None:
-            # EP dispatch gathers tokens arbitrarily: replicate x first
-            xc = jax.lax.with_sharding_constraint(xc, P(None, None, None))
-        xc = T.ffn_block(cfg, lp, xc, topo=None, ep_axis=ep_axis, tp=tp_apply)
-        if ffn_reduced:
-            # one [B,C,d] psum inside ffn_block — charge it here
-            led = tx.charge(led, "tp", _psum_bytes(ctx, xc), ctx.active)
-        # kv_split: keep the residual stream SEQUENCE-SHARDED between layers
-        # (Megatron-SP): psums become reduce-scatters and the stage-boundary
-        # ring permute moves C/tp tokens per chip instead of C
-        if ctx.auto_tp:
-            xc = jax.lax.with_sharding_constraint(xc, ctx.x_spec)
+        with jax.named_scope("layer.mlp"):
+            if ep_axis is not None:
+                # EP dispatch gathers tokens arbitrarily: replicate x first
+                xc = jax.lax.with_sharding_constraint(xc, P(None, None, None))
+            xc = T.ffn_block(cfg, lp, xc, topo=None, ep_axis=ep_axis,
+                             tp=tp_apply)
+            if ffn_reduced:
+                # one [B,C,d] psum inside ffn_block — charge it here
+                led = tx.charge(led, "tp", _psum_bytes(ctx, xc), ctx.active)
+            # kv_split: keep the residual stream SEQUENCE-SHARDED between
+            # layers (Megatron-SP): psums become reduce-scatters and the
+            # stage-boundary ring permute moves C/tp tokens per chip
+            # instead of C
+            if ctx.auto_tp:
+                xc = jax.lax.with_sharding_constraint(xc, ctx.x_spec)
         return (xc, li + 1, led, tel), (k, v)
 
     xs = layers if cross is None else (layers,)
     (x, _, led, tel), (ks, vs) = jax.lax.scan(
         layer_body, (x, jnp.int32(0), led, tel), xs)
-    pool, led, tel = remote.write_pools(ctx, pool, ks, vs, led, tel)
+    with jax.named_scope("layer.kv_write"):
+        pool, led, tel = remote.write_pools(ctx, pool, ks, vs, led, tel)
     return x, pool, led, tel
 
 
@@ -331,30 +340,34 @@ def hybrid_stage_step(ctx: StageCtx, groups: Params, shared: Params,
         # shared attention: only for REAL groups (global group id < n_groups)
         gid = ctx.stage * plan.layers_per_stage + gi
         has_attn = gid < n_groups
-        hn = L.rms_norm(xc2, shared["ln1"], cfg.norm_eps)
-        q = jnp.einsum("bcd,dq->bcq", hn, shared["wq"])
-        k = jnp.einsum("bcd,dq->bcq", hn, shared["wk"])
-        v = jnp.einsum("bcd,dq->bcq", hn, shared["wv"])
-        q = q.reshape(b, c, q.shape[-1] // hd, hd)
-        k = k.reshape(b, c, k.shape[-1] // hd, hd)
-        v = v.reshape(b, c, v.shape[-1] // hd, hd)
-        q = L.apply_rope(q, cos, sin)
-        k = L.apply_rope(k, cos, sin)
+        with jax.named_scope("layer.attn_proj"):
+            hn = L.rms_norm(xc2, shared["ln1"], cfg.norm_eps)
+            q = jnp.einsum("bcd,dq->bcq", hn, shared["wq"])
+            k = jnp.einsum("bcd,dq->bcq", hn, shared["wk"])
+            v = jnp.einsum("bcd,dq->bcq", hn, shared["wv"])
+            q = q.reshape(b, c, q.shape[-1] // hd, hd)
+            k = k.reshape(b, c, k.shape[-1] // hd, hd)
+            v = v.reshape(b, c, v.shape[-1] // hd, hd)
+            q = L.apply_rope(q, cos, sin)
+            k = L.apply_rope(k, cos, sin)
         att, led, tel = attend_chunk(ctx, gi, q, k, v, pool, led, tel)
-        h_loc = att.shape[2]
-        upd = jnp.einsum("bcq,qd->bcd", att.reshape(b, c, h_loc * hd),
-                         shared["wo"])
-        if mtp is not None and mtp.attn:
-            upd, led = tr.tp_psum(upd, mtp.axes, led, active=ctx.active)
-        xc3 = xc2 + jnp.where(has_attn, upd, 0.0)
-        ffn = T.ffn_block(scfg, shared, xc3, topo=None,
-                          tp=tp_apply) - xc3  # isolate update
-        if tp_apply is not None and tp_apply.dense:
-            led = tx.charge(led, "tp", _psum_bytes(ctx, xc3), ctx.active)
-        xc3 = xc3 + jnp.where(has_attn, ffn, 0.0)
+        with jax.named_scope("layer.attn_proj"):
+            h_loc = att.shape[2]
+            upd = jnp.einsum("bcq,qd->bcd", att.reshape(b, c, h_loc * hd),
+                             shared["wo"])
+            if mtp is not None and mtp.attn:
+                upd, led = tr.tp_psum(upd, mtp.axes, led, active=ctx.active)
+            xc3 = xc2 + jnp.where(has_attn, upd, 0.0)
+        with jax.named_scope("layer.mlp"):
+            ffn = T.ffn_block(scfg, shared, xc3, topo=None,
+                              tp=tp_apply) - xc3  # isolate update
+            if tp_apply is not None and tp_apply.dense:
+                led = tx.charge(led, "tp", _psum_bytes(ctx, xc3), ctx.active)
+            xc3 = xc3 + jnp.where(has_attn, ffn, 0.0)
         return (xc3, gi + 1, led, tel), (conv2, ssd2, k, v)
 
     (x, _, led, tel), (conv2, ssd2, ks, vs) = jax.lax.scan(
         group_body, (x, jnp.int32(0), led, tel), (groups, state[0], state[1]))
-    pool, led, tel = remote.write_pools(ctx, pool, ks, vs, led, tel)
+    with jax.named_scope("layer.kv_write"):
+        pool, led, tel = remote.write_pools(ctx, pool, ks, vs, led, tel)
     return x, (conv2, ssd2), pool, led, tel

@@ -36,6 +36,7 @@ in closed form from the plan (DESIGN.md §3.4/§3.6) — dryrun records it and
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -131,6 +132,19 @@ class Transport:
         raise NotImplementedError
 
 
+def _scoped(method):
+    """Run a transport method under the device scope
+    ``transport.<method name>`` (``obs.trace.SCOPES``): the metadata that
+    names its collectives in a profile; the compiled code is unchanged."""
+    scope = "transport." + method.__name__
+
+    @functools.wraps(method)
+    def inner(*args, **kwargs):
+        with jax.named_scope(scope):
+            return method(*args, **kwargs)
+    return inner
+
+
 class JaxCollectiveTransport(Transport):
     """Default transport: ``jax.lax`` collectives, ring-algorithm byte model.
 
@@ -139,6 +153,9 @@ class JaxCollectiveTransport(Transport):
       all-reduce (psum):     2 * (k-1)/k * nbytes(x)   ring all-reduce
       reduce-scatter:        (k-1)/k * nbytes(x)
       all-gather:            (k-1) * nbytes(x_local)
+
+    Each method runs under its ``transport.<method>`` scope; a pair shift
+    also under its ledger ``tag`` (``.../transport.pair_shift/qship_state``).
     """
 
     name = "jax"
@@ -148,26 +165,32 @@ class JaxCollectiveTransport(Transport):
         sizes = jax.lax.psum(1, axes)
         return int(sizes)
 
+    @_scoped
     def ring_shift(self, x, axis, perm, led: Ledger = None, *, active=None):
         out = jax.lax.ppermute(x, axis, perm)
         return out, charge(led, "ring", nbytes(x), active)
 
+    @_scoped
     def pair_shift(self, x, axis, perm, led: Ledger = None, *,
                    tag: str, active=None):
-        out = jax.lax.ppermute(x, axis, perm)
+        with jax.named_scope(tag):
+            out = jax.lax.ppermute(x, axis, perm)
         return out, charge(led, tag, nbytes(x), active)
 
+    @_scoped
     def stage_psum(self, x, axis, led: Ledger = None, *, active=None):
         k = self._axis_size(axis)
         out = jax.lax.psum(x, axis)
         return out, charge(led, "collect", 2.0 * (k - 1) / k * nbytes(x),
                             active)
 
+    @_scoped
     def tp_psum(self, x, axes, led: Ledger = None, *, active=None):
         k = self._axis_size(axes)
         out = jax.lax.psum(x, axes)
         return out, charge(led, "tp", 2.0 * (k - 1) / k * nbytes(x), active)
 
+    @_scoped
     def tp_reduce_scatter(self, x, axes, led: Ledger = None, *,
                           scatter_axis: int = 0, active=None):
         k = self._axis_size(axes)
@@ -175,6 +198,7 @@ class JaxCollectiveTransport(Transport):
                                    tiled=True)
         return out, charge(led, "tp", (k - 1) / k * nbytes(x), active)
 
+    @_scoped
     def tp_all_gather(self, x, axes, led: Ledger = None, *,
                       concat_axis: int = 0, active=None):
         k = self._axis_size(axes)

@@ -48,6 +48,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
+
+# Fixed kernel names: the HLO custom call of each kernel (and so the op a
+# profiler trace shows, ``chunk_attention.<n>``) takes this name, whatever
+# the Python functions around it are called.
+SELF_KERNEL = "chunk_attention"
+POOL_KERNEL = "pool_attention"
+PAGED_POOL_KERNEL = "pool_attention_paged"
 NEG_INF = float(-1e30)
 LANES = 128
 
@@ -257,7 +264,7 @@ def pool_attention_pallas(
         scratch_shapes=_state_scratch(block_q, d))
     m, l, acc = pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=_state_shapes(b, h, c, d),
-        interpret=interpret,
+        interpret=interpret, name=POOL_KERNEL,
     )(valid.astype(jnp.int32).reshape(-1), *args)
     return m, l, acc
 
@@ -414,7 +421,7 @@ def pool_attention_paged_pallas(
         scratch_shapes=scratch)
     m, l, acc = pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=_state_shapes(b, h, c, d),
-        interpret=interpret,
+        interpret=interpret, name=PAGED_POOL_KERNEL,
     )(handles.astype(jnp.int32), valid.astype(jnp.int32), *args)
     return m, l, acc
 
@@ -490,6 +497,6 @@ def chunk_attention_pallas(
         out_specs=out_specs if return_state else q_spec,
         out_shape=out_shapes if return_state else out_shapes[0],
         scratch_shapes=_state_scratch(block_q, d),
-        interpret=interpret,
+        interpret=interpret, name=SELF_KERNEL,
     )(*args)
     return tuple(res) if return_state else res

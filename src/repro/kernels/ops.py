@@ -33,7 +33,7 @@ def _on_tpu() -> bool:
 # staged into the program, so counting costs no host callback (and none
 # lands inside a manual shard_map region).
 
-KERNEL_TAGS = ("chunk_attention", "pool_attention", "pool_attention_paged",
+KERNEL_TAGS = (_ca.SELF_KERNEL, _ca.POOL_KERNEL, _ca.PAGED_POOL_KERNEL,
                "ssd", "decode_attention")
 
 
@@ -69,6 +69,16 @@ def count_launches(fn, *args, **kwargs) -> dict:
     return out
 
 
+def _kernel_jit(name: str, **jit_kwargs):
+    """``jax.jit`` of a kernel wrapper under the fixed ``name`` (its jaxpr
+    equation's name, which ``count_launches`` reads), independent of the
+    Python function's own name."""
+    def wrap(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return jax.jit(fn, **jit_kwargs)
+    return wrap
+
+
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     n = x.shape[axis]
     pad = (-n) % mult
@@ -90,8 +100,9 @@ def _state_out(m, l, acc, d: int):
     return m[:, :, 0], l[:, :, 0], _heads_major(acc[..., :d])
 
 
-@partial(jax.jit, static_argnames=("causal_offset", "scale", "block_q",
-                                   "block_k", "return_state"))
+@_kernel_jit(_ca.SELF_KERNEL,
+             static_argnames=("causal_offset", "scale", "block_q", "block_k",
+                              "return_state"))
 def chunk_attention(q, k, v, *, causal_offset: int = 0,
                     scale: Optional[float] = None,
                     block_q: int = _ca.DEFAULT_BLOCK_Q,
@@ -133,7 +144,7 @@ def chunk_attention(q, k, v, *, causal_offset: int = 0,
     return _heads_major(res[..., :d])
 
 
-@partial(jax.jit, static_argnames=("scale", "block_q", "block_k"))
+@_kernel_jit(_ca.POOL_KERNEL, static_argnames=("scale", "block_q", "block_k"))
 def pool_attention(q, k, v, valid, *, scale: Optional[float] = None,
                    block_q: int = _ca.DEFAULT_BLOCK_Q,
                    block_k: int = _ca.DEFAULT_BLOCK_K,
@@ -167,7 +178,8 @@ def pool_attention(q, k, v, valid, *, scale: Optional[float] = None,
     return _state_out(m, l, acc, d)
 
 
-@partial(jax.jit, static_argnames=("ppc", "scale", "kv_len", "block_q"))
+@_kernel_jit(_ca.PAGED_POOL_KERNEL,
+             static_argnames=("ppc", "scale", "kv_len", "block_q"))
 def pool_attention_paged(q, k_pages, v_pages, handles, valid, *, ppc: int,
                          scale: Optional[float] = None,
                          kv_len: Optional[int] = None,

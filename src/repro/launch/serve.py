@@ -366,17 +366,17 @@ def _run_single(opts: ServeOptions) -> int:
                 print(f"{kind} -> {path}")
     else:
         print(f"completed {m['completed']} requests in {wall:.2f}s wall | "
-              f"engine clock {eng.clock:.3f}s | avg E2E {m['avg_e2e']:.3f}s | "
-              f"p99 {m['p99_e2e']:.3f}s | {m['throughput']:.3f} req/s | "
-              f"stages {m['num_stages']}")
-        if opts.trace_out:
-            print("note: --trace-out needs --scheduler continuous; skipping")
-        if opts.metrics_out:
-            from repro.obs.metrics import export_engine_metrics
-            path = export_engine_metrics(opts.metrics_out, m,
-                                         extra={"wall_seconds": wall},
-                                         health=monitor)
-            print(f"metrics -> {path}")
+              f"avg TTFT {m['avg_ttft']:.3f}s | p99 {m['p99_ttft']:.3f}s | "
+              f"avg queue {m['avg_queue_wait']:.3f}s | engine clock "
+              f"{eng.clock:.3f}s, avg E2E {m['avg_e2e']:.3f}s | "
+              f"{m['throughput']:.3f} req/s | stages {m['num_stages']}")
+        if opts.trace_out or opts.metrics_out:
+            paths = eng.export_obs(trace_out=opts.trace_out,
+                                   metrics_out=opts.metrics_out,
+                                   extra={"wall_seconds": wall},
+                                   health=monitor)
+            for kind, path in paths.items():
+                print(f"{kind} -> {path}")
     if opts.executor == "jax":
         for r in sorted(finished, key=lambda r: r.rid)[:3]:
             top = int(np.argmax(r.result))

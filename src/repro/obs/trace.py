@@ -2,8 +2,8 @@
 
 One recorder, one file, three event families (ISSUE 6 tentpole #2):
 
-- scheduler task intervals (``task``/``mark``, the original
-  ``sched/trace.py`` surface — pid = stage, tid = request),
+- scheduler task intervals (``task``/``mark``, the scheduler's surface —
+  pid = stage, tid = request),
 - engine wave / per-tick stage spans (``span`` — arbitrary pid/tid),
 - counter tracks (``counter`` — ``"ph": "C"`` events Perfetto renders as
   stacked area charts: KV occupancy and wire bytes per stage).
@@ -12,16 +12,46 @@ Timestamps are SECONDS on whatever clock the caller uses (the scheduler's
 virtual clock or ``time.perf_counter`` deltas); export converts to the
 trace-event microsecond unit. ``export`` writes atomically
 (``_io.atomic_write_text``) so an interrupted run never leaves a truncated
-JSON artifact. ``sched.trace`` re-exports this module's names, so existing
-imports keep working.
+JSON artifact.
+
+Inside the served path (``runtime.engine``) three more pieces live here:
+
+- ``SpanLog``: host spans of the engine (``engine.step``, ``engine.admit``,
+  ``engine.prepare``, ``engine.dispatch``, ``engine.compile``,
+  ``engine.device_wait``, ``engine.fetch``, ``prefill_wave ...``), each a
+  ``jax.profiler.TraceAnnotation`` under its bare name (so it lands on the
+  device trace's clock) and a ``(name, start, end, ids)`` entry in a
+  bounded in-memory record on the ``perf_counter`` clock;
+- ``process_events()``: one process-wide listener for JAX's tracing,
+  backend-compile and persistent-cache-hit events and for Python GC pauses
+  longer than ``GC_PAUSE_S`` (``host.gc`` spans); executors read it as
+  deltas;
+- ``hlo_op_scopes``: the device scopes (``SCOPES``, set with
+  ``jax.named_scope`` in ``core``) of a compiled program's HLO
+  instructions, read from the ``op_name`` metadata of its text
+  (``fresh_compiled_text``: a compile that no cache answers).
 """
 from __future__ import annotations
 
+import collections
+import gc
 import json
+import re
+import time
 from dataclasses import asdict, dataclass, field
-from typing import Any, Dict, List, Mapping, Optional
+from typing import Any, Deque, Dict, List, Mapping, Optional, Tuple
 
 from repro.obs._io import atomic_write_text
+
+# the device scopes of the served path, outermost first where they nest
+# (a transport or kernel call inside a layer scope is named by the inner)
+SCOPES = ("stage.embed", "stage.head", "layer.attn_proj", "layer.attn_self",
+          "layer.attn_pool", "layer.kv_write", "layer.mlp",
+          "transport.ring_shift", "transport.pair_shift",
+          "transport.stage_psum", "transport.tp_psum",
+          "transport.tp_reduce_scatter", "transport.tp_all_gather")
+GC_PAUSE_S = 1e-3          # GC pauses recorded as host.gc spans
+SPAN_LOG_MAX = 1 << 16     # entries kept per SpanLog
 
 
 @dataclass(frozen=True)
@@ -210,3 +240,157 @@ class TraceRecorder:
     def export(self, path: str) -> str:
         """Atomically write the Chrome trace JSON to ``path``."""
         return atomic_write_text(path, json.dumps(self.chrome_trace()))
+
+
+# ------------------------------------------------------- engine host spans
+
+class _Span:
+    """One open span of a ``SpanLog``: a profiler annotation under the bare
+    name plus ``perf_counter`` start/end."""
+
+    __slots__ = ("log", "name", "ids", "ann", "start", "end")
+
+    def __init__(self, log: "SpanLog", name: str, ids: Dict[str, Any]):
+        self.log, self.name, self.ids = log, name, ids
+
+    def __enter__(self) -> "_Span":
+        from jax.profiler import TraceAnnotation
+        self.ann = TraceAnnotation(self.name)
+        self.ann.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end = time.perf_counter()
+        self.ann.__exit__(*exc)
+        self.log.spans.append((self.name, self.start, self.end, self.ids))
+
+    @property
+    def dur(self) -> float:
+        return self.end - self.start
+
+
+class SpanLog:
+    """Bounded record of host spans ``(name, start, end, ids)``, times in
+    ``perf_counter`` seconds; ``ids`` holds the wave index (``wave``) that
+    ties a span to its wave record and, through it, to request ids."""
+
+    def __init__(self, maxlen: int = SPAN_LOG_MAX):
+        self.spans: Deque[Tuple[str, float, float, Dict[str, Any]]] = \
+            collections.deque(maxlen=maxlen)
+
+    def span(self, name: str, **ids) -> _Span:
+        """``with log.span("engine.fetch", wave=3) as s: ...``; ``s.dur``
+        after the block."""
+        return _Span(self, name, ids)
+
+    def add(self, name: str, start: float, end: float, **ids) -> None:
+        """Record a span measured elsewhere (no profiler annotation)."""
+        self.spans.append((name, start, end, ids))
+
+
+# --------------------------------------------- process-wide event listener
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+class ProcessEvents:
+    """Counts of JAX tracing, backend compiles (or cache-backed compile
+    requests) and persistent-cache loads, and the GC pauses longer than
+    ``GC_PAUSE_S``, since the process started listening. Readers take
+    deltas (``counts()`` before and after; ``gc_pauses(since)``)."""
+
+    def __init__(self):
+        self._counts = {"traces": 0, "compiles": 0, "cache_loads": 0}
+        self._gc: Deque[Tuple[float, float, int]] = collections.deque(
+            maxlen=4096)
+        self._gc_t0 = 0.0
+
+    def counts(self) -> Dict[str, int]:
+        return dict(self._counts)
+
+    def gc_pauses(self, since: float) -> List[Tuple[float, float, int]]:
+        """``(start, end, generation)`` of the pauses that ended after
+        ``since`` (``perf_counter`` seconds)."""
+        return [p for p in self._gc if p[1] > since]
+
+    def _on_duration(self, event: str, duration: float, **kw) -> None:
+        if event == _TRACE_EVENT:
+            self._counts["traces"] += 1
+        elif event == _COMPILE_EVENT:
+            self._counts["compiles"] += 1
+
+    def _on_event(self, event: str, **kw) -> None:
+        if event == _CACHE_HIT_EVENT:
+            self._counts["cache_loads"] += 1
+
+    def _on_gc(self, phase: str, info: Dict[str, Any]) -> None:
+        now = time.perf_counter()
+        if phase == "start":
+            self._gc_t0 = now
+        elif now - self._gc_t0 > GC_PAUSE_S:
+            self._gc.append((self._gc_t0, now, int(info["generation"])))
+
+
+_PROCESS_EVENTS: Optional[ProcessEvents] = None
+
+
+def process_events() -> ProcessEvents:
+    """The process's one listener, registered on first use (executors are
+    built many times per process; listeners are never added twice)."""
+    global _PROCESS_EVENTS
+    if _PROCESS_EVENTS is None:
+        import jax
+        pe = ProcessEvents()
+        jax.monitoring.register_event_duration_secs_listener(pe._on_duration)
+        jax.monitoring.register_event_listener(pe._on_event)
+        gc.callbacks.append(pe._on_gc)
+        _PROCESS_EVENTS = pe
+    return _PROCESS_EVENTS
+
+
+# ------------------------------------------------------ device op scopes
+
+_HLO_INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?'
+                        r'metadata=\{[^}]*?op_name="([^"]*)"')
+
+
+def fresh_compiled_text(lowered) -> str:
+    """The compiled text of a ``jax.stages.Lowered``, compiled afresh.
+
+    The persistent compile cache keys a program without its debug info, so
+    an entry written by the same program under other scope names returns
+    their ``op_name`` metadata; and ``Lowered.compile()`` hands back the
+    executable the jitted call already holds. A compiler option forces a
+    new compile (``xla_detailed_logging`` only changes XLA's logging), and
+    the persistent cache is off while it runs."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    cc.reset_cache()
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        return lowered.compile({"xla_detailed_logging": True}).as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def hlo_op_scopes(hlo_text: str, scopes=SCOPES) -> Dict[str, str]:
+    """``{HLO instruction name: innermost scope of ``scopes``}`` for every
+    instruction of a compiled program's text whose ``op_name`` path passes
+    through one of ``scopes``. Instruction names are the names a profiler
+    trace gives the device ops."""
+    wanted = set(scopes)
+    out: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m is None:
+            continue
+        for part in reversed(m.group(2).split("/")):
+            if part in wanted:
+                out[m.group(1)] = part
+                break
+    return out

@@ -30,6 +30,7 @@ Two engines share the executors:
 from __future__ import annotations
 
 import math
+import time
 import warnings
 from dataclasses import dataclass, replace as dc_replace
 from typing import (Any, Callable, Dict, List, Optional, Protocol, Sequence,
@@ -40,6 +41,7 @@ import numpy as np
 from repro.configs.base import ModelConfig, RunConfig
 from repro.core import costmodel as cm
 from repro.core import lbcp, mbkr
+from repro.obs import trace as obs_trace
 
 
 @dataclass
@@ -58,6 +60,12 @@ class Request:
     # ContinuousEngine.submit from tokens when the prefix cache is armed,
     # or supplied directly by token-free (sim / bench) callers
     prefix_hashes: Tuple[int, ...] = ()
+    # measured host times (time.perf_counter seconds): handed to the
+    # engine, picked into a batch, result on the host. ``arrival`` and
+    # ``finish_time`` stay on the engine's own clock.
+    t_submit: float = math.nan
+    t_admit: float = math.nan
+    t_done: float = math.nan
 
 
 def bucket_of(buckets: Sequence[int], seq_len: int) -> int:
@@ -239,19 +247,31 @@ class JaxExecutor:
     ``kvstore.prefix.DeviceSeedCache``; a later wave whose requests all
     share a cached prefix of ``k`` chunks is seeded from those snapshots
     and compiled with ``prefix_chunks=k`` (hit chunks read cached KV, their
-    writes land in the scratch slot)."""
+    writes land in the scratch slot).
+
+    Every wave is measured from inside: ``spans`` (``obs.trace.SpanLog``)
+    records ``prefill_wave seq<S> b<B>`` around ``engine.prepare`` (token
+    pad and stack, seed assembly), ``engine.dispatch`` (the jitted call
+    returns; ``engine.compile`` inside it on a jit-cache miss),
+    ``engine.device_wait`` (``block_until_ready``) and ``engine.fetch``
+    (results to the host), plus the ``host.gc`` pauses; the wave record
+    carries the phase durations, whether the wave compiled, traced or
+    loaded anything, its requests' ``t_admit`` and its ``Program``, whose
+    ``op_scopes()`` names the device ops."""
 
     def __init__(self, cfg: ModelConfig, staged_params, topo, run: RunConfig):
-        import time
         from repro.core import pipeline as pp
         self.cfg, self.topo, self.run_cfg = cfg, topo, run
         self.staged = staged_params
         self._fns: Dict[Tuple, Tuple[Callable, Any]] = {}
+        self._programs: Dict[Tuple, Program] = {}
         self._pp = pp
         self.collect_telemetry = False
         self.health = None
         self.waves: List[Dict[str, Any]] = []
         self._epoch = time.perf_counter()
+        self.spans = obs_trace.SpanLog()
+        self._gc_read = self._epoch
         self.prefix_enabled = False
         self.prefix_seed_entries = 8       # DeviceSeedCache LRU bound
         self._seed_caches: Dict[Tuple, Any] = {}   # (seq, m) -> DeviceSeedCache
@@ -284,94 +304,166 @@ class JaxExecutor:
 
     def run(self, requests: Sequence[Request], chunks: Sequence[int],
             num_stages: int, tp: int) -> Tuple[float, np.ndarray]:
-        import time
         import jax
         seq = int(sum(chunks))
         collect = bool(self.collect_telemetry)
         health = self.health
         armed = bool(self.prefix_enabled)
-        # ---- device prefix: wave-uniform seedable hit length k (static —
-        # keyed into the jit cache) + the stacked seed pool when k > 0
-        k, seed_pool, chains, seed_cache = 0, None, [], None
-        if armed:
-            seed_cache = self._seed_cache(seq, len(chunks))
-            chains = self._wave_chains(requests)
-            if all(chains):
-                k = min(seed_cache.match(ch) for ch in chains)
-        key = (seq, len(chunks), collect, health is not None, armed, k)
-        if key not in self._fns:
-            plan = self._pp.build_plan(
-                self.cfg, num_stages, seq,
-                dc_replace(self.run_cfg, num_chunks=len(chunks)))
-            self._fns[key] = (None, plan)   # fn built below (needs the plan)
-        _, plan = self._fns[key]
-        if armed:
-            k = min(k, plan.p2, len(chunks) - 1)
-            if k > 0:
-                seed_pool = self._assemble_seed(seed_cache, chains, k)
-                if seed_pool is None:
-                    k = 0
-            self.prefix_device_hit_chunks += k
-        if self._fns[key][0] is None:
-            cfg, topo = self.cfg, self.topo
-            kk = k
-            if armed and kk > 0:
-                fn = jax.jit(lambda st, tk, pool: self._pp.prefill_pipeline(
-                    cfg, st, tk, plan, topo, return_telemetry=collect,
-                    prefix_chunks=kk, prefix_pool=pool, return_kv=True,
-                    health=health))
-            elif armed:
-                fn = jax.jit(lambda st, tk: self._pp.prefill_pipeline(
-                    cfg, st, tk, plan, topo, return_telemetry=collect,
-                    return_kv=True, health=health))
-            else:
-                fn = jax.jit(lambda st, tk: self._pp.prefill_pipeline(
-                    cfg, st, tk, plan, topo, return_telemetry=collect,
-                    health=health))
-            self._fns[key] = (fn, plan)
-        fn, plan = self._fns[key]
-        toks = np.stack([np.pad(r.tokens, (0, seq - len(r.tokens)))
-                         for r in requests]).astype(np.int32)
-        t0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation(
-                f"prefill_wave seq{seq} b{len(requests)}"):
-            res = (fn(self.staged, toks, seed_pool) if seed_pool is not None
-                   else fn(self.staged, toks))
-            if not isinstance(res, tuple):
-                res = (res,)
-            out = res[0]
-            tel = res[1] if collect else None
-            kv = res[1 + int(collect)] if armed else None
-            out.block_until_ready()
-        dt = time.perf_counter() - t0
-        if kv is not None and seed_cache is not None:
-            # snapshot each request's batch element of the final pool for
-            # future waves (keyed by its full hash chain)
-            for i, ch in enumerate(chains):
-                if ch:
-                    seed_cache.put(ch, {
-                        f: (None if getattr(kv, f) is None else
-                            np.asarray(getattr(kv, f)[:, :, :, i]))
-                        for f in ("k", "v", "k_scale", "v_scale")})
-        if health is not None:
-            jax.effects_barrier()    # order debug callbacks before the reads
-        for r, row in zip(requests, np.asarray(out)):
-            r.result = row
+        wi = len(self.waves)
+        span = self.spans.span
+        events = obs_trace.process_events()
+        events0 = events.counts()
+        with span(f"prefill_wave seq{seq} b{len(requests)}", wave=wi):
+            with span("engine.prepare", wave=wi) as prepare:
+                # ---- device prefix: wave-uniform seedable hit length k
+                # (static — keyed into the jit cache) + the stacked seed
+                # pool when k > 0
+                k, seed_pool, chains, seed_cache = 0, None, [], None
+                if armed:
+                    seed_cache = self._seed_cache(seq, len(chunks))
+                    chains = self._wave_chains(requests)
+                    if all(chains):
+                        k = min(seed_cache.match(ch) for ch in chains)
+                key = (seq, len(chunks), collect, health is not None, armed, k)
+                if key not in self._fns:
+                    plan = self._pp.build_plan(
+                        self.cfg, num_stages, seq,
+                        dc_replace(self.run_cfg, num_chunks=len(chunks)))
+                    self._fns[key] = (None, plan)   # fn built below
+                _, plan = self._fns[key]
+                if armed:
+                    k = min(k, plan.p2, len(chunks) - 1)
+                    if k > 0:
+                        seed_pool = self._assemble_seed(seed_cache, chains, k)
+                        if seed_pool is None:
+                            k = 0
+                    self.prefix_device_hit_chunks += k
+                miss = self._fns[key][0] is None
+                if miss:
+                    cfg, topo = self.cfg, self.topo
+                    kk = k
+                    if armed and kk > 0:
+                        fn = jax.jit(lambda st, tk, pool: self._pp.prefill_pipeline(
+                            cfg, st, tk, plan, topo, return_telemetry=collect,
+                            prefix_chunks=kk, prefix_pool=pool, return_kv=True,
+                            health=health))
+                    elif armed:
+                        fn = jax.jit(lambda st, tk: self._pp.prefill_pipeline(
+                            cfg, st, tk, plan, topo, return_telemetry=collect,
+                            return_kv=True, health=health))
+                    else:
+                        fn = jax.jit(lambda st, tk: self._pp.prefill_pipeline(
+                            cfg, st, tk, plan, topo, return_telemetry=collect,
+                            health=health))
+                    self._fns[key] = (fn, plan)
+                fn, plan = self._fns[key]
+                toks = np.stack([np.pad(r.tokens, (0, seq - len(r.tokens)))
+                                 for r in requests]).astype(np.int32)
+                args = ((self.staged, toks, seed_pool) if seed_pool is not None
+                        else (self.staged, toks))
+                if miss:
+                    self._programs[key] = Program(fn, args, self.topo, plan)
+            t0 = time.perf_counter()
+            with span("engine.dispatch", wave=wi) as dispatch:
+                if miss:
+                    with span("engine.compile", wave=wi):
+                        res = fn(*args)
+                else:
+                    res = fn(*args)
+                if not isinstance(res, tuple):
+                    res = (res,)
+                out = res[0]
+                tel = res[1] if collect else None
+                kv = res[1 + int(collect)] if armed else None
+            with span("engine.device_wait", wave=wi) as device_wait:
+                out.block_until_ready()
+            dt = time.perf_counter() - t0
+            with span("engine.fetch", wave=wi) as fetch:
+                if kv is not None and seed_cache is not None:
+                    # snapshot each request's batch element of the final
+                    # pool for future waves (keyed by its full hash chain)
+                    for i, ch in enumerate(chains):
+                        if ch:
+                            seed_cache.put(ch, {
+                                f: (None if getattr(kv, f) is None else
+                                    np.asarray(getattr(kv, f)[:, :, :, i]))
+                                for f in ("k", "v", "k_scale", "v_scale")})
+                if health is not None:
+                    jax.effects_barrier()  # order debug callbacks first
+                for r, row in zip(requests, np.asarray(out)):
+                    r.result = row
+                if tel is not None:
+                    tel = {name: np.asarray(v) for name, v in tel.items()}
+        events1 = events.counts()
+        compile_events = {e: events1[e] - events0[e] for e in events1}
+        pauses = events.gc_pauses(self._gc_read)
+        self._gc_read = time.perf_counter()
+        for g0, g1, gen in pauses:
+            self.spans.add("host.gc", g0, g1, wave=wi, generation=gen)
         wave: Dict[str, Any] = {
             "start": t0 - self._epoch, "dur": dt, "seq": seq,
             "num_ticks": int(plan.num_ticks), "num_stages": num_stages,
             "chunks": list(chunks), "rids": [r.rid for r in requests],
             "prefix_chunks": k,
+            "phases": {"prepare": prepare.dur, "dispatch": dispatch.dur,
+                       "device_wait": device_wait.dur, "fetch": fetch.dur},
+            "jit_miss": miss, "compile_events": compile_events,
+            "compiled": miss or any(compile_events.values()),
+            "t_admit": [r.t_admit for r in requests],
+            "program": self._programs[key],
         }
         if tel is not None:
             from repro.obs import telemetry as obs_t
-            wave["telemetry"] = {k: np.asarray(v) for k, v in tel.items()}
+            wave["telemetry"] = tel
             wave["per_event_wire"] = obs_t.per_event_wire_bytes(
                 plan, self.cfg, len(requests))
             if health is not None:
                 health.check_occupancy(wave["telemetry"], plan)
         self.waves.append(wave)
         return dt, np.full(num_stages, dt / max(len(chunks), 1))
+
+    def op_scopes(self) -> Dict[Tuple, Dict[str, str]]:
+        """Per jit key of every program built so far: ``{HLO instruction
+        name: innermost device scope}`` (``Program.op_scopes``). Lowers and
+        compiles each program's text on the first call; never called on
+        the serving path."""
+        return {key: p.op_scopes() for key, p in self._programs.items()}
+
+
+class Program:
+    """One jitted pipeline program of a ``JaxExecutor``: the function, the
+    shapes and shardings it was first called with (a host array or an
+    uncommitted one leaves its sharding to jit, as the call did), and
+    which devices hold each stage (``stage_devices[s]``: device ids of
+    stage ``s``)."""
+
+    def __init__(self, fn, args, topo, plan):
+        import jax
+
+        def spec(a):
+            sharding = a.sharding if getattr(a, "committed", False) else None
+            return jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                        sharding=sharding)
+        self.fn = fn
+        self.arg_specs = jax.tree.map(spec, args)
+        mesh = topo.mesh
+        ids = np.vectorize(lambda d: int(d.id))(mesh.devices)
+        ids = np.moveaxis(ids, mesh.axis_names.index(topo.stage_axis), 0)
+        self.stage_devices = ids.reshape(ids.shape[0], -1).tolist()
+        self.num_ticks = int(plan.num_ticks)
+        self.num_chunks = int(plan.num_chunks)
+        self._scopes: Optional[Dict[str, str]] = None
+
+    def op_scopes(self) -> Dict[str, str]:
+        """``{HLO instruction name: innermost scope of obs.trace.SCOPES}``
+        from the compiled program's text (``op_name`` metadata); computed
+        on the first call, by a fresh compile (``obs.trace.
+        fresh_compiled_text``)."""
+        if self._scopes is None:
+            text = obs_trace.fresh_compiled_text(
+                self.fn.lower(*self.arg_specs))
+            self._scopes = obs_trace.hlo_op_scopes(text)
+        return self._scopes
 
 
 # ------------------------------------------------------------------- engine
@@ -382,6 +474,44 @@ def _configure_executor(ex, telemetry: Optional[bool], health: Any) -> None:
         ex.collect_telemetry = bool(telemetry)
     if health is not None:
         ex.health = health
+
+
+def _trace_waves(rec, waves: Sequence[Dict[str, Any]]) -> None:
+    """Engine wave spans + per-(stage, tick) device spans and
+    ``kv_resident_bytes`` / ``device_wire_bytes`` tracks of the waves that
+    carry telemetry, on the ``engine`` row (wall clock since executor
+    construction)."""
+    if waves:
+        rec.process_name("engine", "engine (wall clock)")
+    for wi, w in enumerate(waves):
+        rec.span(f"wave{wi} seq{w['seq']} b{len(w['rids'])}",
+                 pid="engine", tid=0, start=w["start"],
+                 finish=w["start"] + w["dur"], cat="wave",
+                 args={"rids": w["rids"], "chunks": w["chunks"]})
+        tel = w.get("telemetry")
+        if tel is None:
+            continue
+        pe = w.get("per_event_wire", {})
+        n_st, ticks = tel["own_chunks"].shape
+        tick_dur = w["dur"] / max(ticks, 1)
+        kv, occ = tel["kv_bytes"], tel["own_chunks"] + tel["hosted_chunks"]
+        wire = (tel["spill_events"] * pe.get("spill", 0.0)
+                + tel["fetch_events"] * pe.get("fetch", 0.0)
+                + tel["qship_events"] * pe.get("qship", 0.0))
+        for s in range(n_st):
+            for t in range(ticks):
+                ts = w["start"] + t * tick_dur
+                phase = t - s
+                if 0 <= phase < len(w["chunks"]):
+                    rec.span(f"tick{t} c{phase}", pid="engine",
+                             tid=s + 1, start=ts, finish=ts + tick_dur,
+                             cat="tick",
+                             args={"stage": s, "chunk": phase,
+                                   "occupancy": float(occ[s, t])})
+                rec.counter("kv_resident_bytes", pid=s, time=ts,
+                            values={f"w{wi}": float(kv[s, t])})
+                rec.counter("device_wire_bytes", pid=s, time=ts,
+                            values={f"w{wi}": float(wire[s, t])})
 
 
 class PrefillEngine:
@@ -398,10 +528,15 @@ class PrefillEngine:
         self.replans = 0
         self.remeshes = 0
         self._plans: Dict[Tuple[int, int], List[int]] = {}
+        # engine.step / engine.admit land in the executor's span record
+        # (the engine keeps its own beside an executor without one)
+        self.spans = getattr(executor, "spans", None) or obs_trace.SpanLog()
+        self._epoch = getattr(executor, "_epoch", time.perf_counter())
 
     # ---------------------------------------------------------- admission
     def submit(self, req: Request) -> None:
         req.bucket = self._bucket(req.seq_len)
+        req.t_submit = time.perf_counter()
         self.queue.append(req)
 
     def _bucket(self, seq_len: int) -> int:
@@ -432,26 +567,38 @@ class PrefillEngine:
         pending = [r for r in self.queue if r.state == "queued"]
         if not pending:
             return False
-        oldest = min(pending, key=lambda r: (r.arrival, r.rid))
-        bucket = oldest.bucket
-        batch = sorted((r for r in pending if r.bucket == bucket),
-                       key=lambda r: (r.arrival, r.rid))[: self.ec.max_batch]
-        chunks = self._plan_for(bucket)
-        for r in batch:
-            r.state = "running"
-        try:
-            makespan, stage_lat = self.executor.run(
-                batch, chunks, self.num_stages, self.ec.tp)
-        except StageFailure as e:
-            self._handle_failure(e.stage, batch)
-            return True
-        self.clock += makespan
-        self._observe(stage_lat)
-        for r in batch:
-            r.state = "done"
-            r.finish_time = self.clock
-            self.queue.remove(r)
-            self.done.append(r)
+        waves = getattr(self.executor, "waves", None)
+        wi = len(waves) if waves is not None else None
+        with self.spans.span("engine.step", wave=wi) as step:
+            with self.spans.span("engine.admit", wave=wi) as admit:
+                oldest = min(pending, key=lambda r: (r.arrival, r.rid))
+                bucket = oldest.bucket
+                batch = sorted((r for r in pending if r.bucket == bucket),
+                               key=lambda r: (r.arrival, r.rid)
+                               )[: self.ec.max_batch]
+                chunks = self._plan_for(bucket)
+                now = time.perf_counter()
+                for r in batch:
+                    r.state = "running"
+                    r.t_admit = now
+            try:
+                makespan, stage_lat = self.executor.run(
+                    batch, chunks, self.num_stages, self.ec.tp)
+            except StageFailure as e:
+                self._handle_failure(e.stage, batch)
+                return True
+            self.clock += makespan
+            self._observe(stage_lat)
+            now = time.perf_counter()
+            for r in batch:
+                r.state = "done"
+                r.finish_time = self.clock
+                r.t_done = now
+                self.queue.remove(r)
+                self.done.append(r)
+        if waves is not None and len(waves) > wi:
+            waves[wi].setdefault("phases", {})["admit"] = admit.dur
+            waves[wi]["step"] = step.dur
         return True
 
     def run_until_drained(self, max_steps: int = 10_000) -> None:
@@ -509,16 +656,74 @@ class PrefillEngine:
 
     # ----------------------------------------------------------- metrics
     def metrics(self) -> Dict[str, float]:
+        """TTFT (``t_done - t_submit``) and queue wait (``t_admit -
+        t_submit``) as measured on the host clock; ``avg_e2e``/``p99_e2e``
+        (``finish_time - arrival``), ``throughput`` and ``makespan`` on the
+        engine clock, which sums the executor's wave times (the analytic
+        model's under ``SimExecutor``)."""
+        timed = [r for r in self.done if math.isfinite(r.t_done - r.t_submit)]
+        ttft = [r.t_done - r.t_submit for r in timed]
+        wait = [r.t_admit - r.t_submit for r in timed]
         lat = [r.finish_time - r.arrival for r in self.done]
         return {
             "completed": len(self.done),
+            "avg_ttft": float(np.mean(ttft)) if ttft else math.nan,
+            "p99_ttft": float(np.percentile(ttft, 99)) if ttft else math.nan,
+            "avg_queue_wait": float(np.mean(wait)) if wait else math.nan,
             "avg_e2e": float(np.mean(lat)) if lat else math.nan,
             "p99_e2e": float(np.percentile(lat, 99)) if lat else math.nan,
             "throughput": len(self.done) / self.clock if self.clock else 0.0,
+            "makespan": self.clock,
             "replans": self.replans,
             "remeshes": self.remeshes,
             "num_stages": self.num_stages,
         }
+
+    # ------------------------------------------------------ observability
+    def merged_trace(self) -> obs_trace.TraceRecorder:
+        """One Perfetto trace of the run on the host clock (seconds since
+        the executor was built): the engine's spans (``engine.*``,
+        ``prefill_wave``, ``host.gc``) and waves with any device telemetry
+        on the ``engine`` row, one ``r<rid>`` span per finished request
+        from submit to done. Pure: a fresh recorder each call."""
+        rec = obs_trace.TraceRecorder(enabled=True)
+        rec.process_name("engine", "engine (wall clock)")
+        for name, t0, t1, ids in self.spans.spans:
+            rec.span(name, pid="engine", tid=0, start=t0 - self._epoch,
+                     finish=t1 - self._epoch, cat="engine", args=dict(ids))
+        _trace_waves(rec, getattr(self.executor, "waves", None) or [])
+        for r in self.done:
+            if math.isfinite(r.t_done - r.t_submit):
+                rec.span(f"r{r.rid}", pid="requests", tid=r.rid,
+                         start=r.t_submit - self._epoch,
+                         finish=r.t_done - self._epoch, cat="request",
+                         args={"seq_len": r.seq_len,
+                               "admit": r.t_admit - self._epoch})
+        return rec
+
+    def export_obs(self, trace_out: Optional[str] = None,
+                   metrics_out: Optional[str] = None,
+                   extra: Optional[Dict[str, float]] = None,
+                   health=None) -> Dict[str, str]:
+        """Export the merged trace and/or the metrics summary with TTFT and
+        queue-wait histograms of the measured timestamps; returns {"trace":
+        path, "metrics": path} for whichever was asked."""
+        from repro.obs.metrics import export_engine_metrics
+        from repro.sched.metrics import RequestRecord
+        paths: Dict[str, str] = {}
+        if health is None:
+            health = getattr(self.executor, "health", None)
+        if trace_out:
+            paths["trace"] = self.merged_trace().export(trace_out)
+        if metrics_out:
+            records = [RequestRecord(r.rid, r.t_submit, r.seq_len, r.bucket,
+                                     admit=r.t_admit, finish=r.t_done)
+                       for r in self.done
+                       if math.isfinite(r.t_done - r.t_submit)]
+            paths["metrics"] = export_engine_metrics(
+                metrics_out, self.metrics(), records=records, extra=extra,
+                health=health)
+        return paths
 
     # ------------------------------------------------------- checkpointing
     def state_dict(self) -> Dict[str, Any]:
@@ -677,6 +882,7 @@ class ContinuousEngine:
                 "cell is draining: admission is closed (route the request "
                 "to another cell — the fleet router skips draining cells)")
         req.bucket = bucket_of(self.ec.buckets, req.seq_len)
+        req.t_submit = time.perf_counter()
         if self.slo is not None and not math.isfinite(req.deadline):
             req.deadline = req.arrival + self.slo
         if (self.prefix_cache is not None and not req.prefix_hashes
@@ -841,8 +1047,14 @@ class ContinuousEngine:
                 wave.append(order[i])
                 i += 1
             chunks = list(self._chunk_plan(bucket).chunks)
-            self.executor.run([sr.payload for sr in wave], chunks,
-                              self.ec.num_stages, self.ec.tp)
+            reqs = [sr.payload for sr in wave]
+            now = time.perf_counter()
+            for r in reqs:
+                r.t_admit = now
+            self.executor.run(reqs, chunks, self.ec.num_stages, self.ec.tp)
+            now = time.perf_counter()
+            for r in reqs:
+                r.t_done = now
 
     # -------------------------------------------------------- calibration
     def recalibrate(self, hw: cm.ProfileSpec) -> cm.HardwareProfile:
@@ -915,38 +1127,7 @@ class ContinuousEngine:
                 rec.counter("wire_bytes", pid=ev.stage, time=ev.finish,
                             values={"bytes": lvl})
         # engine waves (wall clock) + device telemetry
-        waves = getattr(self.executor, "waves", None) or []
-        if waves:
-            rec.process_name("engine", "engine (wall clock)")
-        for wi, w in enumerate(waves):
-            rec.span(f"wave{wi} seq{w['seq']} b{len(w['rids'])}",
-                     pid="engine", tid=0, start=w["start"],
-                     finish=w["start"] + w["dur"], cat="wave",
-                     args={"rids": w["rids"], "chunks": w["chunks"]})
-            tel = w.get("telemetry")
-            if tel is None:
-                continue
-            pe = w.get("per_event_wire", {})
-            n_st, ticks = tel["own_chunks"].shape
-            tick_dur = w["dur"] / max(ticks, 1)
-            kv, occ = tel["kv_bytes"], tel["own_chunks"] + tel["hosted_chunks"]
-            wire = (tel["spill_events"] * pe.get("spill", 0.0)
-                    + tel["fetch_events"] * pe.get("fetch", 0.0)
-                    + tel["qship_events"] * pe.get("qship", 0.0))
-            for s in range(n_st):
-                for t in range(ticks):
-                    ts = w["start"] + t * tick_dur
-                    phase = t - s
-                    if 0 <= phase < len(w["chunks"]):
-                        rec.span(f"tick{t} c{phase}", pid="engine",
-                                 tid=s + 1, start=ts, finish=ts + tick_dur,
-                                 cat="tick",
-                                 args={"stage": s, "chunk": phase,
-                                       "occupancy": float(occ[s, t])})
-                    rec.counter("kv_resident_bytes", pid=s, time=ts,
-                                values={f"w{wi}": float(kv[s, t])})
-                    rec.counter("device_wire_bytes", pid=s, time=ts,
-                                values={f"w{wi}": float(wire[s, t])})
+        _trace_waves(rec, getattr(self.executor, "waves", None) or [])
         health = getattr(self.executor, "health", None)
         if health is not None:
             health.to_trace(rec)

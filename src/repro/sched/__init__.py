@@ -10,4 +10,4 @@ from repro.sched.kvlease import (KVLeaseManager, Lease, LeaseEvent,
 from repro.sched.metrics import RequestRecord, SchedMetrics, fleet_summary
 from repro.sched.scheduler import (POLICIES, ChunkPlan, ChunkScheduler,
                                    SchedRequest, poisson_arrivals)
-from repro.sched.trace import TraceRecorder
+from repro.obs.trace import TraceRecorder

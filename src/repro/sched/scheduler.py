@@ -39,7 +39,7 @@ from repro.core import costmodel as cm
 from repro.core import mbkr as mb
 from repro.sched.kvlease import KVLeaseManager, request_lease_events
 from repro.sched.metrics import RequestRecord, SchedMetrics
-from repro.sched.trace import TraceRecorder
+from repro.obs.trace import TraceRecorder
 from repro.sim.engine import schedule_request
 
 

@@ -273,11 +273,16 @@ def test_trace_recorder_merged_format(tmp_path):
 
 
 def test_sched_trace_shim():
-    """sched.trace keeps re-exporting the recorder (old imports work)."""
+    """The scheduler records into ``obs.trace``'s recorder: ``repro.sched``
+    exports that one class, and the old ``sched.trace`` re-export module
+    is gone."""
+    import importlib.util
+    import repro.sched
     from repro.obs import trace as obs_trace
-    from repro.sched import trace as sched_trace
-    assert sched_trace.TraceRecorder is obs_trace.TraceRecorder
-    assert sched_trace.TaskEvent is obs_trace.TaskEvent
+    from repro.sched import scheduler
+    assert repro.sched.TraceRecorder is obs_trace.TraceRecorder
+    assert scheduler.TraceRecorder is obs_trace.TraceRecorder
+    assert importlib.util.find_spec("repro.sched.trace") is None
 
 
 def test_engine_merged_trace_sim(tmp_path):

@@ -4,8 +4,10 @@ Interpret mode (every other kernel test) checks results, not what Mosaic
 accepts: block shapes, tiling-aligned DMA slices, VMEM use. These tests
 compile each kernel at qwen3-8b widths (chunk 2048, 32 query / 8 kv heads,
 head_dim 128, bf16) for a described ``v5e:2x2`` chip — no chip attached,
-nothing runs. The topology is described inside a module fixture (never at
-import: only one process may load the TPU library at a time).
+nothing runs; one more compiles the four-stage pipeline at reduced widths
+and checks the names a profiler trace will show. The topology is described
+inside a module fixture (never at import: only one process may load the
+TPU library at a time).
 """
 import os
 
@@ -96,3 +98,189 @@ def test_pool_attention_paged_compiles_for_v5e(spec, kv_dtype, page_tokens):
                                               ppc=ppc, k_scale=ks,
                                               v_scale=vs)
     _compile(fn, *args)
+
+
+def test_pipeline_names_for_v5e(topo):
+    """The served four-stage pipeline (reduced widths, Pallas + paged pool,
+    MBKR qship as served) compiled for four described chips: the kernels'
+    custom calls keep their fixed names, and the device scopes name the
+    ring shift, the pair exchanges and the kernels' layers."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from repro.configs.base import RunConfig, get_config, replace
+    from repro.core import pipeline as pp
+    from repro.kernels import ops
+    from repro.models.api import build_model
+    from repro.models.topology import Topology
+    from repro.obs.trace import hlo_op_scopes
+    cfg = replace(get_config("qwen3-8b"), num_layers=8, d_model=512,
+                  num_heads=4, num_kv_heads=2, d_ff=1024, vocab_size=1024,
+                  head_dim=D, dtype="bfloat16")
+    seq, m = 4096, 16
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(4, 1), ("data", "model"))
+    tp = Topology(mesh=mesh)
+    plan = pp.build_plan(cfg, 4, seq, RunConfig(
+        num_chunks=m, num_stages=4, attn_backend="pallas",
+        pool_backend="paged"))
+    shapes = jax.eval_shape(lambda: pp.stage_params(
+        cfg, build_model(cfg).init(jax.random.key(0)), plan))
+    staged = jax.tree.map(
+        lambda a, p: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                          sharding=NamedSharding(mesh, p)),
+        shapes, pp.stage_param_specs(cfg, plan, tp),
+        is_leaf=lambda x: isinstance(x, PartitionSpec))
+    toks = jax.ShapeDtypeStruct((1, seq), jnp.int32)
+    on_tpu = ops._on_tpu
+    ops._on_tpu = lambda: True      # the jitted step asks the CPU backend
+    try:
+        text = jax.jit(lambda s, t: pp.prefill_pipeline(
+            cfg, s, t, plan, tp)).lower(staged, toks).compile().as_text()
+    finally:
+        ops._on_tpu = on_tpu
+    kernels = {re.sub(r"\.\d+$", "", n) for n in re.findall(
+        r'%([\w.\-]+) = [^\n]*custom_call_target="tpu_custom_call"', text)}
+    assert kernels == {"chunk_attention", "pool_attention_paged"}, kernels
+    scopes = hlo_op_scopes(text)
+    coll = {n: s for n, s in scopes.items()
+            if n.startswith("collective-permute-start")}
+    assert sorted(set(coll.values())) == ["transport.pair_shift",
+                                          "transport.ring_shift"], coll
+    assert sum(s == "transport.ring_shift" for s in coll.values()) == 1
+    for name, scope in scopes.items():
+        if name.startswith("chunk_attention"):
+            assert scope == "layer.attn_self"
+        elif name.startswith("pool_attention_paged"):
+            assert scope == "layer.attn_pool"
+
+
+# --------------------------------------------- the served pp4 program vs a
+# chip trace of it
+
+_TYPE = r"\b[a-z]+[0-9]*\[[0-9,]*\](?:\{[^}]*\})?"
+_ASYNC = ("async-start", "async-update", "async-done")
+
+
+def _instruction(body):
+    """(result type, opcode, operands, attributes) of an HLO instruction's
+    text after ``name = ``, without its metadata. A trace writes each
+    operand with its type and an async op unsugared; both are undone."""
+    import re
+    body = re.split(r", (?:metadata|backend_config|frontend_attributes)=",
+                    body)[0]
+    result, op, rest = re.match(r"^(.*?) ([a-z][\w\-]*)\((.*)$",
+                                body).groups()
+    rest = re.sub(_TYPE, "", re.sub(r"/\*index=\d+\*/", "", rest))
+    prev = None
+    while prev != rest:     # the tuple types' empty shells
+        prev, rest = rest, re.sub(r"\(\s*(?:,\s*)*\)", "", rest)
+    rest = re.sub(r"\s+", "", rest)
+    operands, _, attrs = rest.partition(")")
+    return result, op, operands, attrs
+
+
+def _served_pp4_text(topo):
+    """The compiled text of the program the benchmark's
+    ``qwen3-8b-pp4.docs32k`` cell serves (``harness.build_engine``'s
+    executor, 32768 tokens in 16 chunks), for four described chips."""
+    import json
+    import sys
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                    "bench"))
+    import harness
+    from repro.configs.base import RunConfig
+    from repro.core import pipeline as pp
+    from repro.kernels import ops
+    from repro.models.api import build_model
+    from repro.models.topology import Topology
+    c = json.load(open(os.path.join(harness.BENCH, "configs",
+                                    "qwen3-8b-pp4.json")))
+    t = json.load(open(os.path.join(harness.BENCH, "traffic",
+                                    "docs32k.json")))
+    s = c["serve"]
+    cfg = harness.model_config(c)
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(4, 1),
+                ("data", "model"))
+    tp = Topology(mesh=mesh)
+    seq = t["buckets"][0]
+    plan = pp.build_plan(cfg, s["stages"], seq, RunConfig(
+        num_chunks=t["num_chunks"], num_stages=s["stages"],
+        attn_backend=s["attn_backend"], pool_backend=s["pool_backend"],
+        kv_dtype=s["kv_dtype"]))
+    shapes = jax.eval_shape(lambda: pp.stage_params(
+        cfg, build_model(cfg).init(jax.random.key(0)), plan))
+    staged = jax.tree.map(
+        lambda a, p: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                          sharding=NamedSharding(mesh, p)),
+        shapes, pp.stage_param_specs(cfg, plan, tp),
+        is_leaf=lambda x: isinstance(x, PartitionSpec))
+    toks = jax.ShapeDtypeStruct((1, seq), jnp.int32)
+    on_tpu = ops._on_tpu
+    ops._on_tpu = lambda: True      # the jitted step asks the CPU backend
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        return jax.jit(lambda st, tk: pp.prefill_pipeline(
+            cfg, st, tk, plan, tp)).lower(staged, toks).compile().as_text()
+    finally:
+        ops._on_tpu = on_tpu
+
+
+@pytest.mark.parametrize("recording,renumbered", [
+    ("pipeline4", {"broadcast_in_dim.207", "squeeze.232"})])
+def test_served_pp4_program_is_the_recorded_one(topo, recording,
+                                                renumbered):
+    """Every device op of a recorded pp4 chip trace is an instruction of
+    this tree's pp4 program compiled for four described chips, with the
+    same result shape, opcode, operands and attributes. ``pipeline4`` was
+    recorded from a program built without device scopes: the scopes change
+    metadata only, and the lowering numbers two instructions of the same
+    kind otherwise (``renumbered``: the same instruction under another
+    suffix)."""
+    import collections
+    import gzip
+    import re
+    from jax.profiler import ProfileData
+    path = os.path.join(os.path.dirname(__file__), "..", "bench",
+                        "testdata", recording + ".xplane.pb.gz")
+    traced = {}
+    with gzip.open(path) as f:
+        space = ProfileData.from_serialized_xspace(f.read())
+    for plane in space.planes:
+        if not plane.name.startswith("/device:TPU:"):
+            continue
+        for line in plane.lines:
+            if line.name == "XLA Ops":
+                for e in line.events:
+                    name, _, body = e.name.partition(" = ")
+                    traced[name.strip().lstrip("%")] = body
+    compiled = collections.defaultdict(list)
+    for line in _served_pp4_text(topo).splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%([\w.\-]+) = (.*)$", line)
+        if m:
+            compiled[m.group(1)].append(_instruction(m.group(2)))
+    def same(want, haves):
+        return any(have == want or (want[1] in _ASYNC
+                                    and have[::2] == want[::2])
+                   for have in haves)
+
+    assert len(traced) > 150
+    unmatched, renamed = [], set()
+    for name, body in sorted(traced.items()):
+        want = _instruction(body)
+        if same(want, compiled.get(name, ())):
+            continue
+        kind = name.rpartition(".")[0]
+        if name not in compiled and same(want, [
+                have for other, haves in compiled.items()
+                if other.rpartition(".")[0] == kind for have in haves]):
+            renamed.add(name)
+        else:
+            unmatched.append((name, want, compiled.get(name)))
+    assert not unmatched, unmatched[:4]
+    assert renamed == renumbered
