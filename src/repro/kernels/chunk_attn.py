@@ -33,7 +33,15 @@ straight from the head-major page store ``[P, B, KVH, pt, hd]`` — no
 ``gather_chunks`` copy, no dense slot stack in HBM — double-buffering each
 page HBM→VMEM with ``pltpu.make_async_copy`` while the MXU runs the previous
 page, and dequantizing int8/fp8 payloads on the landing buffer. Invalid
-slots issue zero copies and zero MXU work.
+slots cost no step, no copy and no MXU work. Its grid runs over blocks of
+the query heads that share a kv head — (B, KVH * blocks, nq) — and each
+program loops over the valid pages only, so a page crosses HBM→VMEM once
+per (head block, query tile) and meets all of its rows at once; each
+landed page is consumed whole, in one online-softmax update where its
+score tile fits (``paged_tiles`` picks the tile from the shapes). At
+qwen3-8b widths (g 4, 2048-token pages) a tile is the 4 heads x 128 rows,
+and a page is read 16 times per call where a (query head, 128-row block)
+grid read it 64 times.
 """
 from __future__ import annotations
 
@@ -58,15 +66,27 @@ PAGED_POOL_KERNEL = "pool_attention_paged"
 NEG_INF = float(-1e30)
 LANES = 128
 
+# Paged pool kernel tile (``paged_tiles``): the most query rows per tile;
+# the fewest rows a landed page should meet (a page read feeds R rows at R
+# FLOP per byte, and v5e does 240 per byte of HBM); the fp32 score tile's
+# bytes; and the VMEM estimate up to which the kernel keeps the default
+# scoped limit (v5e: 16 MiB of its 128 MiB). Within the default, the program
+# around the kernel compiles as it would without it.
+PAGED_TILE_ROWS = 2048
+PAGED_MIN_ROWS = 256
+PAGED_SCORE_BYTES = 4 << 20
+PAGED_VMEM_BYTES = 14 << 20
+
 
 def _block_update(q, k, v, mask, scale, m_ref, l_ref, acc_ref):
     """One online-softmax block update against the VMEM scratch state —
     shared by the per-chunk and the pool kernels (k/v already dequantized
-    to q's dtype; only the mask differs between callers). ``m_ref`` /
-    ``l_ref`` are ``(block_q, 1)`` fp32 columns."""
+    to q's dtype; only the mask differs between callers, None = every key
+    visible). ``m_ref`` / ``l_ref`` are ``(rows, 1)`` fp32 columns."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    s = jnp.where(mask, s, NEG_INF)
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_INF)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
     m_safe = jnp.where(m_new < NEG_INF / 2, 0.0, m_new)
@@ -269,98 +289,203 @@ def pool_attention_pallas(
     return m, l, acc
 
 
+def paged_tiles(group: int, c: int, pt: int):
+    """Tile of the paged pool kernel, from shapes alone: ``(heads,
+    block_q, block_k)``.
+
+    ``block_k`` is the key slice of a landed page per online-softmax update:
+    the whole page, unless a score tile of ``PAGED_MIN_ROWS`` rows ``[256,
+    pt]`` would pass ``PAGED_SCORE_BYTES`` (then the largest multiple of
+    128 dividing ``pt`` that fits). Whole pages matter most: every update
+    also rescales the accumulator and updates the row statistics, a cost
+    per row that a 512-token slice pays four times per 2048-token page.
+    The query tile is ``heads`` of the ``group`` query heads of one kv
+    head, ``block_q`` rows each (a divisor of ``c``: a multiple of 128, or
+    ``c`` itself), the most rows ``R = heads * block_q`` whose fp32 score
+    tile ``[R, block_k]`` fits ``PAGED_SCORE_BYTES`` and at most
+    ``PAGED_TILE_ROWS``, the most heads among equal ``R``. A page then
+    crosses HBM→VMEM ``c * group / R`` times per call."""
+    def largest(n, most):
+        """``n`` if it is at most ``most``, else the largest multiple of
+        128 that divides ``n`` and is at most ``most``; None if none is."""
+        if n <= most:
+            return n
+        fits = [m for m in range(LANES, most + 1, LANES) if n % m == 0]
+        return fits[-1] if fits else None
+
+    least = min(group * c, PAGED_MIN_ROWS)
+    block_k = largest(pt, PAGED_SCORE_BYTES // (4 * least)) or (
+        LANES if pt % LANES == 0 else pt)
+    most = min(PAGED_TILE_ROWS, PAGED_SCORE_BYTES // (4 * block_k))
+    tiles = [(h * bq, h, bq) for h in range(group, 0, -1) if group % h == 0
+             for bq in [largest(c, most // h)] if bq]
+    _, heads, block_q = max(tiles) if tiles else (
+        0, 1, LANES if c % LANES == 0 else c)
+    return heads, block_q, block_k
+
+
+def paged_vmem_bytes(rows: int, block_k: int, pt: int, d: int,
+                     kv_bytes: int, q_bytes: int = 2) -> int:
+    """VMEM of the paged pool kernel at a tile of ``rows`` query rows:
+    the pipelined q and accumulator blocks, the two page landing buffers,
+    the state scratch (lane-padded (R, 1) columns) and ~5 bytes per score
+    element of temporaries (fitted to Mosaic's v5e allocations, slightly
+    above them)."""
+    return (2 * rows * d * q_bytes          # q block, double-buffered
+            + 2 * rows * d * 4              # acc out block, double-buffered
+            + 2 * 2 * pt * d * kv_bytes     # k|v landing, two halves
+            + rows * (2 * LANES + d) * 4    # m, l columns + acc scratch
+            + 5 * rows * block_k)           # score temporaries
+
+
 def _paged_kernel(handles_ref, valid_ref, q_ref, k_src, v_src, *refs,
-                  scale: float, kv_len: int, block_q: int, pt: int,
-                  ppc: int, np_eff: int, group: int, kvh: int,
+                  scale: float, kv_len: int, block_q: int, block_k: int,
+                  pt: int, ppc: int, np_eff: int, kvh: int, blocks: int,
                   quantized: bool):
     """Ragged paged pool attention: ONE launch straight off the page store.
 
-    Grid = (B, H, nq, S, np_eff) with (slot, page) innermost and sequential.
-    ``handles_ref`` [S*ppc] and ``valid_ref`` [S] are scalar-prefetch SMEM
-    refs — available BEFORE the grid runs, so they can steer data movement.
-    ``k_src``/``v_src`` are the UNBLOCKED page stores (``pl.ANY`` memory
-    space). Each grid step issues a ``make_async_copy`` of the NEXT valid
-    page's ``[pt, hd]`` slice (one kv head of one page: whole (8, 128)
-    tiles of the head-major store) into the other half of a double buffer
-    while the MXU consumes the current half — the handle indirection
-    happens in the DMA source index, so no gathered stack ever exists in
-    HBM.
+    Grid = (B, KVH * blocks, nq); each program loops over the valid pages
+    itself. A program (bi, hb, qi) owns one query tile: ``heads`` query
+    heads of kv head ``hk = hb // blocks`` (a kv head's ``g`` query heads
+    make ``blocks = g // heads`` head blocks), ``block_q`` rows each. The q
+    block ``[heads, block_q, D]`` is viewed as ``R = heads*block_q`` rows,
+    so each landed page feeds all of them before the next page is read.
+    ``handles_ref`` [S*ppc] and ``valid_ref`` [S] are scalar-prefetch
+    SMEM refs; ``k_src``/``v_src`` are the UNBLOCKED page stores (``pl.ANY``
+    memory space).
 
-    A slot with ``valid == 0`` contributes the exact identity state: its
-    steps issue no copies (the prefetch for step t+1 is validity-gated) and
-    no MXU work. Quantized payloads are dequantized ON THE LANDING BUFFER:
-    the per-page scale rides in SMEM (indexed by the same handle) and the
-    multiply fuses into the upcast."""
+    The program first lists the valid slots in SMEM (``order``), then runs
+    one loop step per valid page: it issues a ``make_async_copy`` of the
+    NEXT page's ``[pt, hd]`` slice (one kv head of one page: whole (8, 128)
+    tiles of the head-major store) into the other half of a double buffer,
+    waits for its own page and runs the MXU on it — the handle indirection
+    happens in the DMA source index, so no gathered stack ever exists in
+    HBM. The last step prefetches the first page of the next program
+    (every program shares the slot list and handles), so only the call's
+    first program waits for a cold page. A slot with ``valid == 0`` costs
+    no loop step, no copy and no MXU work, and contributes the exact
+    identity state; with none valid the program writes the identity.
+
+    A landed page is consumed in ``block_k``-token slices, each one online-
+    softmax update of the ``[R, block_k]`` score tile (one slice, the whole
+    page, at the served shapes). Pages wholly inside ``kv_len`` build no
+    mask; only the slice that straddles ``kv_len`` on the last page masks,
+    and slices past it are skipped. Quantized payloads are dequantized ON
+    THE LANDING BUFFER: the per-page scale of (handle, batch, kv head) rides
+    in SMEM and the multiply fuses into the upcast."""
     if quantized:  # extra inputs: per-page per-(batch, kv-head) fp32 scales
         ksc_ref, vsc_ref, *refs = refs
-    mo_ref, lo_ref, ao_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref = refs
+    (mo_ref, lo_ref, ao_ref, kbuf, vbuf, sem, order,
+     m_ref, l_ref, acc_ref) = refs
 
-    bi, hi = pl.program_id(0), pl.program_id(1)
-    si, pi = pl.program_id(3), pl.program_id(4)
-    ns = pl.num_programs(3)
-    hk = hi // group
-    step = si * np_eff + pi          # page step within this (bi, hi, qi)
-    nsteps = ns * np_eff
-    cur_valid = valid_ref[si] != 0
+    bi, hb, qi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nhb, nq = pl.num_programs(1), pl.num_programs(2)
+    hk = hb // blocks                # head block -> its kv head
+    heads = q_ref.shape[1]
+    rows = heads * block_q
+    ns = valid_ref.shape[0]
+    prog = (bi * nhb + hb) * nq + qi
+    nprog = pl.num_programs(0) * nhb * nq
 
-    def page_copies(buf_i, s2, p2):
-        h = handles_ref[s2 * ppc + p2]
-        ck = pltpu.make_async_copy(k_src.at[h, bi, hk],
+    def collect(s, n):               # the valid slots, in order
+        order[n] = s
+        return n + (valid_ref[s] != 0).astype(jnp.int32)
+
+    nsteps = jax.lax.fori_loop(0, ns, collect, jnp.int32(0)) * np_eff
+    gbase = prog * nsteps            # buffer parity runs across programs
+
+    def page_copies(buf_i, b2, h2, t):
+        """Copies of loop step ``t``'s page of (batch b2, kv head h2)."""
+        s2 = order[t // np_eff]
+        h = handles_ref[s2 * ppc + jax.lax.rem(t, np_eff)]
+        ck = pltpu.make_async_copy(k_src.at[h, b2, h2],
                                    kbuf.at[buf_i], sem.at[buf_i, 0])
-        cv = pltpu.make_async_copy(v_src.at[h, bi, hk],
+        cv = pltpu.make_async_copy(v_src.at[h, b2, h2],
                                    vbuf.at[buf_i], sem.at[buf_i, 1])
         return ck, cv
 
-    # warm-up: the first page of each (bi, hi, qi) program has no
-    # predecessor to prefetch it — one stall per q-block program
-    @pl.when((step == 0) & cur_valid)
+    # warm-up: the call's first page has no predecessor to prefetch it
+    @pl.when((prog == 0) & (nsteps > 0))
     def _warm():
-        for c in page_copies(0, 0, 0):
+        for c in page_copies(0, bi, hk, 0):
             c.start()
 
-    # land the NEXT page in the other buffer half while this page's block
-    # update runs; invalid targets issue no copy at all
-    nxt = step + 1
-    n_si = jnp.minimum(nxt // np_eff, ns - 1)  # clamp: last step only
-    n_pi = jax.lax.rem(nxt, np_eff)
+    _init_state(m_ref, l_ref, acc_ref)
 
-    @pl.when((nxt < nsteps) & (valid_ref[n_si] != 0))
-    def _prefetch():
-        for c in page_copies(jax.lax.rem(nxt, 2), n_si, n_pi):
-            c.start()
+    def update(q, k, v, ksc, vsc, t, n_valid):
+        """One online-softmax update against key slice ``t`` of the page;
+        ``n_valid`` < block_k masks the slice's tail."""
+        sl = pl.ds(t * block_k, block_k)
+        k = _load_kv(k[sl], ksc, q.dtype)
+        v = _load_kv(v[sl], vsc, q.dtype)
+        mask = None
+        if n_valid < block_k:
+            mask = jax.lax.broadcasted_iota(
+                jnp.int32, (rows, block_k), 1) < n_valid
+        _block_update(q, k, v, mask, scale, m_ref, l_ref, acc_ref)
 
-    @pl.when(step == 0)
-    def _init():
-        _init_state(m_ref, l_ref, acc_ref)
+    def page(q, k, v, ksc, vsc, n_tok):
+        """The page's first ``n_tok`` tokens, slice by slice."""
+        for t in range(-(-n_tok // block_k)):
+            update(q, k, v, ksc, vsc, t, min(block_k, n_tok - t * block_k))
 
-    k_pos = pi * pt + jax.lax.broadcasted_iota(jnp.int32, (block_q, pt), 1)
+    last_tok = kv_len - (np_eff - 1) * pt   # valid tokens of the last page
 
-    @pl.when(cur_valid)
-    def _compute():
-        buf_i = jax.lax.rem(step, 2)
-        for c in page_copies(buf_i, si, pi):
+    def step(t, carry):
+        buf_i = jax.lax.rem(gbase + t, 2)
+        nbuf = 1 - buf_i
+
+        # land the NEXT page in the other buffer half while this one runs
+        @pl.when(t + 1 < nsteps)
+        def _prefetch():
+            for c in page_copies(nbuf, bi, hk, t + 1):
+                c.start()
+
+        # ... and on the last step, the next program's first page
+        @pl.when((t + 1 == nsteps) & (prog + 1 < nprog))
+        def _prefetch_next_program():
+            b2 = (prog + 1) // (nhb * nq)
+            h2 = jax.lax.rem((prog + 1) // nq, nhb) // blocks
+            for c in page_copies(nbuf, b2, h2, 0):
+                c.start()
+
+        for c in page_copies(buf_i, bi, hk, t):
             c.wait()
-        q = q_ref[0, 0]
+        pi = jax.lax.rem(t, np_eff)
+        q = q_ref[0].reshape(rows, q_ref.shape[-1])
         ksc = vsc = None
         if quantized:  # dequant on the landing buffer
-            sidx = (handles_ref[si * ppc + pi] * pl.num_programs(0) + bi) \
-                * kvh + hk
+            hnd = handles_ref[order[t // np_eff] * ppc + pi]
+            sidx = (hnd * pl.num_programs(0) + bi) * kvh + hk
             ksc, vsc = ksc_ref[sidx], vsc_ref[sidx]
-        k = _load_kv(kbuf[buf_i], ksc, q.dtype)
-        v = _load_kv(vbuf[buf_i], vsc, q.dtype)
-        # stored chunks are fully visible: only the partial last page masks
-        _block_update(q, k, v, k_pos < kv_len, scale, m_ref, l_ref, acc_ref)
+        k, v = kbuf.at[buf_i], vbuf.at[buf_i]
+        if last_tok == pt:               # whole pages: no mask anywhere
+            page(q, k, v, ksc, vsc, pt)
+            return carry
+        if np_eff > 1:
+            @pl.when(pi < np_eff - 1)
+            def _whole():
+                page(q, k, v, ksc, vsc, pt)
 
-    @pl.when(step == nsteps - 1)
-    def _finish():
-        _store_state(m_ref, l_ref, acc_ref, mo_ref, lo_ref, ao_ref)
+        @pl.when(pi == np_eff - 1)
+        def _partial():
+            page(q, k, v, ksc, vsc, last_tok)
+        return carry
+
+    jax.lax.fori_loop(0, nsteps, step, 0)
+    for j in range(heads):
+        sl = slice(j * block_q, (j + 1) * block_q)
+        mo_ref[0, j] = _row(m_ref[sl])
+        lo_ref[0, j] = _row(l_ref[sl])
+        ao_ref[0, j] = acc_ref[sl]
 
 
 def pool_attention_paged_pallas(
     q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     handles: jax.Array, valid: jax.Array, *, ppc: int,
     scale: Optional[float] = None, kv_len: Optional[int] = None,
-    block_q: int = DEFAULT_BLOCK_Q, interpret: bool = False,
+    heads: Optional[int] = None, block_q: Optional[int] = None,
+    block_k: Optional[int] = None, interpret: bool = False,
     k_scale: Optional[jax.Array] = None, v_scale: Optional[jax.Array] = None,
 ):
     """Ragged paged pool attention: q [B, H, C, D] vs the head-major PAGE
@@ -371,12 +496,21 @@ def pool_attention_paged_pallas(
     arguments. Returns the online-softmax state ``(m, l) [B, H, 1, C]`` fp32
     rows + unnormalized ``acc [B, H, C, D]`` fp32, exactly like
     ``pool_attention_pallas``, but with NO gathered intermediate: pages
-    stream HBM→VMEM per grid step (double-buffered ``make_async_copy``).
+    stream HBM→VMEM per loop step (double-buffered ``make_async_copy``).
+
+    The grid runs over head blocks of one kv head, not single query heads,
+    and each program loops over the valid pages only: q's ``[B, H, C, D]``
+    is blocked ``[1, heads, block_q, D]`` (``heads`` of the g query heads of
+    one kv head; head ``h = hk*g + j``), so a page is read once per (head
+    block, query tile) and meets ``heads*block_q`` query rows per read.
+    ``heads``, ``block_q`` and the key slice ``block_k`` default to
+    ``paged_tiles``' choice from the shapes; a tile past
+    ``PAGED_VMEM_BYTES`` raises the kernel's VMEM limit.
 
     ``kv_len``: valid tokens per chunk (< ppc*pt for a partial last page —
-    trailing fully-empty pages are excluded from the grid, the straddling
-    page is masked). ``k_scale``/``v_scale`` [P, B*KVH] fp32: per-page
-    dequant scales, SMEM-indexed by the same handles."""
+    trailing fully-empty pages are never visited, the straddling page is
+    masked). ``k_scale``/``v_scale`` [P, B*KVH] fp32: per-page dequant
+    scales, SMEM-indexed by the same handles."""
     b, h, c, d = q.shape
     kvh, pt = k_pages.shape[2], k_pages.shape[3]
     assert k_pages.shape[-1] == d, (k_pages.shape, d)
@@ -386,18 +520,26 @@ def pool_attention_paged_pallas(
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     kv_len = kv_len if kv_len is not None else ppc * pt
     np_eff = max(1, min(ppc, -(-kv_len // pt)))  # drop fully-empty pages
-    block_q = min(block_q, c)
-    assert c % block_q == 0, (c, block_q)
+    auto = paged_tiles(g, c, pt)
+    heads, block_q, block_k = (x or a for x, a in zip(
+        (heads, block_q, block_k), auto))
+    assert g % heads == 0 and c % block_q == 0 and pt % block_k == 0, (
+        g, heads, c, block_q, pt, block_k)
     nq = c // block_q
+    vmem = paged_vmem_bytes(heads * block_q, block_k, pt, d,
+                            k_pages.dtype.itemsize, q.dtype.itemsize)
     quantized = k_scale is not None
     assert quantized == (v_scale is not None)
 
     kernel = functools.partial(
-        _paged_kernel, scale=scale, kv_len=kv_len, block_q=block_q, pt=pt,
-        ppc=ppc, np_eff=np_eff, group=g, kvh=kvh, quantized=quantized)
-    # index maps take the grid indices PLUS the scalar-prefetch refs
-    q_spec = pl.BlockSpec((1, 1, block_q, d),
-                          lambda bi, hi, qi, si, pi, hr, vr: (bi, hi, qi, 0))
+        _paged_kernel, scale=scale, kv_len=kv_len, block_q=block_q,
+        block_k=block_k, pt=pt, ppc=ppc, np_eff=np_eff, kvh=kvh,
+        blocks=g // heads, quantized=quantized)
+    # index maps take the grid indices PLUS the scalar-prefetch refs; head
+    # block hb of ``heads`` heads is heads hb*heads .. hb*heads+heads-1, all
+    # of kv head hb // (g // heads)
+    q_spec = pl.BlockSpec((1, heads, block_q, d),
+                          lambda bi, hb, qi, hr, vr: (bi, hb, qi, 0))
     # unblocked page stores: the kernel DMAs page slices itself
     kv_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [q_spec, kv_spec, kv_spec]
@@ -408,19 +550,23 @@ def pool_attention_paged_pallas(
         in_specs += [sc_spec, sc_spec]
         args += [k_scale.astype(jnp.float32).reshape(-1),
                  v_scale.astype(jnp.float32).reshape(-1)]
-    ml_spec = pl.BlockSpec((1, 1, 1, block_q),
-                           lambda bi, hi, qi, si, pi, hr, vr: (bi, hi, 0, qi))
+    ml_spec = pl.BlockSpec((1, heads, 1, block_q),
+                           lambda bi, hb, qi, hr, vr: (bi, hb, 0, qi))
     scratch = [
         pltpu.VMEM((2, pt, d), k_pages.dtype),   # k landing buffers
         pltpu.VMEM((2, pt, d), v_pages.dtype),   # v landing buffers
         pltpu.SemaphoreType.DMA((2, 2)),         # [buffer, k|v]
-    ] + _state_scratch(block_q, d)
+        pltpu.SMEM((ns,), jnp.int32),            # the valid slots, in order
+    ] + _state_scratch(heads * block_q, d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(b, h, nq, ns, np_eff),
+        num_scalar_prefetch=2, grid=(b, h // heads, nq),
         in_specs=in_specs, out_specs=[ml_spec, ml_spec, q_spec],
         scratch_shapes=scratch)
     m, l, acc = pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=_state_shapes(b, h, c, d),
+        # a tile past the default scoped VMEM asks for twice its estimate
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=(
+            None if vmem <= PAGED_VMEM_BYTES else 2 * vmem)),
         interpret=interpret, name=PAGED_POOL_KERNEL,
     )(handles.astype(jnp.int32), valid.astype(jnp.int32), *args)
     return m, l, acc

@@ -179,11 +179,14 @@ def pool_attention(q, k, v, valid, *, scale: Optional[float] = None,
 
 
 @_kernel_jit(_ca.PAGED_POOL_KERNEL,
-             static_argnames=("ppc", "scale", "kv_len", "block_q"))
+             static_argnames=("ppc", "scale", "kv_len", "heads", "block_q",
+                              "block_k"))
 def pool_attention_paged(q, k_pages, v_pages, handles, valid, *, ppc: int,
                          scale: Optional[float] = None,
                          kv_len: Optional[int] = None,
-                         block_q: int = _ca.DEFAULT_BLOCK_Q,
+                         heads: Optional[int] = None,
+                         block_q: Optional[int] = None,
+                         block_k: Optional[int] = None,
                          k_scale=None, v_scale=None):
     """Ragged paged pool attention (MOCAP pool scan, single launch, ZERO
     gather). See ``chunk_attn.pool_attention_paged_pallas``.
@@ -197,13 +200,11 @@ def pool_attention_paged(q, k_pages, v_pages, handles, valid, *, ppc: int,
     ppc*pt handles a partial last page. Returns the
     fp32 online-softmax state like ``pool_attention`` — one launch per
     (layer, tick), O(1) in pool depth, and HBM traffic O(resident pages),
-    not O(padded pool)."""
+    not O(padded pool). ``heads``/``block_q``/``block_k`` (query heads and
+    rows per head of a tile, key slice of a page) default to
+    ``chunk_attn.paged_tiles``' choice from the shapes."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    c = q.shape[1]
-    bq = min(block_q, c)
-    while c % bq:
-        bq //= 2
     qp = _heads_major(_pad_to(q, 3, LANE))
     # lane-pad the PAGE STORE only when head_dim is off-lane (a one-off
     # [P, ...] copy — real configs keep hd a multiple of 128 and pass
@@ -217,8 +218,8 @@ def pool_attention_paged(q, k_pages, v_pages, handles, valid, *, ppc: int,
             k_scale.shape
     m, l, acc = _ca.pool_attention_paged_pallas(
         qp, kp, vp, handles, valid, ppc=ppc, scale=scale, kv_len=kv_len,
-        block_q=bq, interpret=not _on_tpu(), k_scale=k_scale,
-        v_scale=v_scale)
+        heads=heads, block_q=block_q, block_k=block_k,
+        interpret=not _on_tpu(), k_scale=k_scale, v_scale=v_scale)
     return _state_out(m, l, acc, d)
 
 

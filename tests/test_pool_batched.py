@@ -301,6 +301,100 @@ def test_paged_partial_last_page(kv_len):
                                    atol=1e-6, rtol=1e-5)
 
 
+# --------------------------------------- the kv-head tile of the paged kernel
+# Each case: group size g (kv heads kvh), the query heads and rows per head
+# of a tile and the key slice of a page (None = ``chunk_attn.paged_tiles``'
+# choice, which at these sizes is the whole group, the whole chunk and the
+# whole page), page tokens, kv dtype, occupancy, and kv_len (None = whole
+# pages).
+RETILED_CASES = {
+    "g1-whole-chunk": (1, 2, None, None, None, 8, "bfloat16", "full", None),
+    "g4-rows-x4-keys-x2": (4, 1, None, 8, 4, 8, "bfloat16", "ragged", None),
+    "g12-rows-x2": (12, 1, None, 16, None, 8, "float32", "full", None),
+    "g12-heads-x3-rows-x2": (12, 2, 4, 16, None, 16, "bfloat16", "ragged",
+                             None),
+    "g4-int8-keys-x2": (4, 2, None, None, 4, 8, "int8", "ragged", None),
+    "g4-fp8-heads-x2-ppc2": (4, 1, 2, 16, 8, 16, "fp8", "full", None),
+    "g4-odd-steps": (4, 2, None, None, None, 32, "bfloat16", "odd", None),
+    "g4-all-invalid": (4, 1, 2, 8, 4, 8, "bfloat16", "none", None),
+    "g4-creditor-subset-int8": (4, 1, 2, 8, 4, 8, "int8", "subset", None),
+    "g4-partial-page-ppc2": (4, 1, 2, 8, 4, 16, "float32", "full", 22),
+    "g12-partial-page-ppc4": (12, 1, None, None, 4, 8, "bfloat16", "full",
+                              20),
+}
+
+
+@pytest.mark.parametrize("case", list(RETILED_CASES))
+def test_paged_retiled_parity(case, monkeypatch):
+    """The paged kernel's head-block grid — all or some of the g query heads
+    of one kv head in one tile, in one or several row blocks, each landed
+    page consumed whole or in key slices — against the gathered slot-grid
+    kernel (``ops.pool_attention``) and the per-slot scan: group sizes 1,
+    4 and 12, int8 and fp8 payloads, ragged and empty occupancy, an odd
+    number of pages per program (the double buffer's parity runs on across
+    programs), the creditor ``slots=`` subset, and a partial last page of
+    ppc > 1 pages."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    from repro.core import attention as A
+    from repro.kernels import ops
+    from repro.kvstore import pages as PG
+    g, kvh, heads, bq, bk, pt, kv_dtype, occupancy, kv_len = \
+        RETILED_CASES[case]
+    b, c, d, nslots = 1, 32, 16, 4
+    _, tbl, pool_l = _build_pool(nslots, kv_dtype, b, c, kvh, d,
+                                 page_tokens=pt)
+    tiled = functools.partial(ops.pool_attention_paged, heads=heads,
+                              block_q=bq, block_k=bk)
+    if kv_len is not None:  # direct call vs token-truncated stacks
+        k_l, v_l, ks_l, vs_l = pool_l
+        rows = PG.handle_rows(tbl)
+        handles = jnp.asarray(rows, jnp.int32).reshape(-1)
+        valid = jnp.ones((nslots,), jnp.int32)
+        q = jax.random.normal(jax.random.key(11), (b, c, kvh * g, d),
+                              jnp.float32)
+        got = tiled(q, k_l, v_l, handles, valid, ppc=rows.shape[1],
+                    kv_len=kv_len)
+        kq, vq, _, _ = PG.gather_chunks(k_l, v_l, ks_l, vs_l,
+                                        jnp.asarray(rows))
+        ref = ops.pool_attention(q, kq[:, :, :kv_len], vq[:, :, :kv_len],
+                                 valid)
+        for x, y in zip(got, ref):
+            np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                       atol=1e-6, rtol=1e-5)
+        return
+    monkeypatch.setattr(ops, "pool_attention_paged", tiled)
+    qg = jax.random.normal(jax.random.key(6), (b, c, kvh, g, d), jnp.float32)
+    tol = 2e-3 if kv_dtype in ("int8", "fp8") else 1e-6
+    sc, limit, slots = [0, 1, 2, 3, -1], 4, None
+    if occupancy == "ragged":
+        sc, limit = [2, 0, 3, 1, -1], 2
+    elif occupancy == "odd":  # 3 one-page steps per program, 2 programs
+        limit = 3
+    elif occupancy == "none":
+        limit = 0
+    elif occupancy == "subset":
+        sc, slots = [3, 1, 0, 2, -1], np.asarray([0, 2, 3])
+    outs, states = _scan_states(pool_l, tbl, sc, limit, qg, slots=slots)
+    if occupancy == "none":  # the exact identity state, as the scan's
+        st0 = A.attn_init(b, c, kvh, g, d)
+        for x, y in zip(st0, states["paged"]):
+            np.testing.assert_array_equal(np.asarray(x), y)
+        return
+    # the per-slot scan, the gathered kernel and the paged kernel sum in
+    # different orders: bound each state and output by tol times its
+    # largest magnitude (the unnormalised sums reach ~10 here)
+    for name in ("pallas_batched", "paged", "jnp"):
+        pairs = [(outs[name], outs["pallas_scan"])]
+        if name != "jnp":
+            pairs += list(zip(states[name], states["pallas_scan"]))
+        for got, want in pairs:
+            np.testing.assert_allclose(
+                got, want, rtol=0,
+                atol=tol * max(1.0, float(np.max(np.abs(want)))))
+
+
 def test_paged_pool_scan_has_no_gather_intermediate():
     """Acceptance: the lowered paged pool scan contains NO dense
     [S, B, C, KVH, *] slot-stack intermediate and no [S*ppc, B, pt, KVH, *]

@@ -3,7 +3,8 @@
 Interpret mode (every other kernel test) checks results, not what Mosaic
 accepts: block shapes, tiling-aligned DMA slices, VMEM use. These tests
 compile each kernel at qwen3-8b widths (chunk 2048, 32 query / 8 kv heads,
-head_dim 128, bf16) for a described ``v5e:2x2`` chip — no chip attached,
+head_dim 128, bf16; the paged pool kernel also at mistral-large widths and
+a 256-token chunk) for a described ``v5e:2x2`` chip — no chip attached,
 nothing runs; one more compiles the four-stage pipeline at reduced widths
 and checks the names a profiler trace will show. The topology is described
 inside a module fixture (never at import: only one process may load the
@@ -77,20 +78,33 @@ def test_pool_attention_compiles_for_v5e(spec):
     _compile(ca.pool_attention_pallas, q, kv, kv, valid)
 
 
-@pytest.mark.parametrize("kv_dtype,page_tokens", [("bfloat16", C),
-                                                  ("int8", 256)])
-def test_pool_attention_paged_compiles_for_v5e(spec, kv_dtype, page_tokens):
+@pytest.mark.parametrize("kv_dtype,page_tokens,widths", [
+    ("bfloat16", C, (H, KVH, C)), ("int8", 256, (H, KVH, C)),
+    ("bfloat16", C, (96, 8, C)), ("bfloat16", 256, (H, KVH, 256))],
+    ids=["bfloat16-2048", "int8-256", "mistral-bfloat16-2048",
+         "bucket256-bfloat16-256"])
+def test_pool_attention_paged_compiles_for_v5e(spec, kv_dtype, page_tokens,
+                                               widths):
+    """The paged pool kernel at the tiles ``paged_tiles`` picks for the
+    served shapes — qwen3-8b (g 4), mistral-large (96/8 heads, g 12) and
+    the smallest mixed-open bucket (256-token chunks of one page) — compiles
+    within the default scoped VMEM."""
     import jax.numpy as jnp
     from repro.kernels import chunk_attn as ca
-    ppc = C // page_tokens
+    h, kvh, c = widths
+    ppc = c // page_tokens
     pages = (SLOTS + 1) * ppc
-    q = spec((1, H, C, D), jnp.bfloat16)
-    kv = spec((pages, 1, KVH, page_tokens, D), jnp.dtype(kv_dtype))
+    kv_dt = jnp.dtype(kv_dtype)
+    heads, block_q, block_k = ca.paged_tiles(h // kvh, c, page_tokens)
+    assert ca.paged_vmem_bytes(heads * block_q, block_k, page_tokens, D,
+                               kv_dt.itemsize) <= ca.PAGED_VMEM_BYTES
+    q = spec((1, h, c, D), jnp.bfloat16)
+    kv = spec((pages, 1, kvh, page_tokens, D), kv_dt)
     handles = spec((SLOTS * ppc,), jnp.int32)
     valid = spec((SLOTS,), jnp.int32)
     args = [q, kv, kv, handles, valid]
     if kv_dtype == "int8":
-        sc = spec((pages, KVH), jnp.float32)
+        sc = spec((pages, kvh), jnp.float32)
         args += [sc, sc]
 
     def fn(q, k, v, handles, valid, ks=None, vs=None):
