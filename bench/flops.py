@@ -1,12 +1,18 @@
 """Operations and bytes, worked out from shapes: the model step and each
 attention kernel call. Nothing here reads the system under test.
 
-A config ``c`` is a configuration file's dict (``hidden_size``, ...).
+A config ``c`` is a configuration file's dict (``hidden_size``, ...). What
+depends on the architecture (the model step's operations, how many layers
+call the attention kernels) is its layout's (``layouts/<name>.py``),
+found by the config's ``layout`` under ``root``; a kernel call's counts
+depend on the attention widths only and stay here.
 """
 from __future__ import annotations
 
 import json
 import os
+
+import lookup
 
 PEAKS_FILE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                           "peaks.json")
@@ -25,32 +31,15 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def linear_flops_per_token(c: dict) -> float:
-    """q, k, v, o projections and the SwiGLU MLP, all layers."""
-    d, hd = c["hidden_size"], c["head_dim"]
-    h, kvh, f = (c["num_attention_heads"], c["num_key_value_heads"],
-                 c["intermediate_size"])
-    per_layer = d * (h + 2 * kvh) * hd + h * hd * d + 3 * d * f
-    return 2.0 * per_layer * c["num_hidden_layers"]
-
-
 def causal_pairs(s: int) -> float:
     """(query, key) pairs of a causal prompt of ``s`` tokens."""
     return s * (s + 1) / 2.0
 
 
-def attention_flops(c: dict, s: int) -> float:
-    """QK^T and PV over the causal triangle, all layers."""
-    return (4.0 * c["num_attention_heads"] * c["head_dim"] * causal_pairs(s)
-            * c["num_hidden_layers"])
-
-
-def model_flops(c: dict, s: int) -> float:
-    """Useful operations of one prefill of ``s`` tokens: every linear layer
-    at every position, causal attention, and the output head for the one
-    next-token row."""
-    return (linear_flops_per_token(c) * s + attention_flops(c, s)
-            + 2.0 * c["hidden_size"] * c["vocab_size"])
+def model_flops(c: dict, s: int, root: str = lookup.BENCH) -> float:
+    """Useful operations of one prefill of ``s`` tokens, as the config's
+    layout counts them."""
+    return lookup.layout(c, root).model_flops(c, s)
 
 
 def self_kernel(c: dict, chunk: int, batch: int = 1):
@@ -79,17 +68,19 @@ def pool_kernel(c: dict, chunk: int, prefix: int, batch: int = 1):
 
 
 def kernel_min_seconds(c: dict, seq: int, num_chunks: int, peak: dict,
-                       batch: int = 1) -> dict:
+                       batch: int = 1, root: str = lookup.BENCH) -> dict:
     """Least time of every attention kernel call of one prefill of ``seq``
-    tokens in ``num_chunks`` chunks, all layers, by kernel: ``{"self": s,
-    "pool": s}``. A call's least time is the larger of its operations over
-    the peak rate and its bytes over the memory bandwidth. Chunk 0 has no
-    pool call with work in it."""
+    tokens in ``num_chunks`` chunks, over the layers that attend (the
+    config's layout counts them), by kernel: ``{"self": s, "pool": s}``. A
+    call's least time is the larger of its operations over the peak rate
+    and its bytes over the memory bandwidth. Chunk 0 has no pool call with
+    work in it."""
     chunk = seq // num_chunks
     calls = {"self": [self_kernel(c, chunk, batch)] * num_chunks,
              "pool": [pool_kernel(c, chunk, j * chunk, batch)
                       for j in range(1, num_chunks)]}
-    return {name: c["num_hidden_layers"] * sum(
+    layers = lookup.layout(c, root).attention_layers(c)
+    return {name: layers * sum(
         max(fl / peak["bf16_flops_per_s"], by / peak["hbm_bytes_per_s"])
         for fl, by in cs) for name, cs in calls.items()}
 

@@ -4,13 +4,16 @@ window, read the trace, check the served logits against the plain
 reference, and return the result line.
 
 The cell names a configuration file (``bench/configs/<config>.json``) and a
-traffic file (``bench/traffic/<mix>.json``); per-layer metrics are readers
-in ``bench/metrics/<metric>.py``. Nothing here names a cell.
+traffic file (``bench/traffic/<mix>.json``); the configuration names its
+layout (``bench/layouts/<layout>.py``: parameter tree, weights, counts) and
+its plain reference (``bench/reference/<reference>.py``); per-layer metrics
+are readers in ``bench/metrics/<metric>.py``. Nothing here names a cell or
+an architecture. ``root`` points those lookups at another directory (the
+tests' fixtures).
 """
 from __future__ import annotations
 
 import gc
-import importlib.util
 import json
 import os
 import shutil
@@ -22,10 +25,11 @@ import types
 import numpy as np
 
 import flops
+import lookup
 import traffic as traffic_mod
 import weights
 
-BENCH = os.path.dirname(os.path.abspath(__file__))
+BENCH = lookup.BENCH
 ROOT = os.path.dirname(BENCH)
 OUT = os.path.join(ROOT, "bench_out")
 
@@ -78,22 +82,13 @@ def tpu_devices(cell: Cell):
     return devices[:cell.chips]
 
 
-def model_config(c: dict):
-    """The system's ModelConfig for configuration file ``c``: the file's
-    sizes override the registered architecture's."""
-    from repro.configs.base import get_config, replace
-    return replace(
-        get_config(c["arch"]), num_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"], num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"], d_ff=c["intermediate_size"],
-        vocab_size=c["vocab_size"], head_dim=c["head_dim"],
-        qk_norm=bool(c.get("qk_norm")), rope_theta=c["rope_theta"],
-        norm_eps=c["rms_norm_eps"],
-        tie_embeddings=bool(c.get("tie_word_embeddings")),
-        dtype=c["torch_dtype"])
+def model_config(c: dict, root: str = BENCH):
+    """The system's ModelConfig for configuration file ``c``, as its
+    layout builds it."""
+    return lookup.layout(c, root).model_config(c)
 
 
-def build_engine(c: dict, t: dict, devices, seed: int):
+def build_engine(c: dict, t: dict, devices, seed: int, root: str = BENCH):
     """The served engine, built the way ``launch.serve`` builds its jax
     executor (batch scheduler, uniform chunks), on ``devices`` as
     ``stages`` x ``tp``, holding weights made from ``seed``."""
@@ -107,7 +102,7 @@ def build_engine(c: dict, t: dict, devices, seed: int):
     stages, tp = s["stages"], s["tp"]
     mesh = Mesh(np.asarray(devices[:stages * tp]).reshape(stages, tp),
                 ("data", "model"))
-    cfg = model_config(c)
+    cfg = model_config(c, root)
     run = RunConfig(num_chunks=t["num_chunks"], num_stages=stages,
                     attn_backend=s["attn_backend"],
                     pool_backend=s["pool_backend"], kv_dtype=s["kv_dtype"])
@@ -117,13 +112,13 @@ def build_engine(c: dict, t: dict, devices, seed: int):
                       buckets=tuple(sorted(t["buckets"])),
                       partition="uniform", kv_dtype=s["kv_dtype"])
     eng = PrefillEngine(ec, JaxExecutor(cfg, None, Topology(mesh=mesh), run))
-    load_weights(eng, c, seed)
+    load_weights(eng, c, seed, root)
     return eng
 
 
-def load_weights(eng, c: dict, seed: int) -> None:
+def load_weights(eng, c: dict, seed: int, root: str = BENCH) -> None:
     """Give the engine's executor the weights made from ``seed``: one
-    jitted call that makes the flat tree and restacks it into the
+    jitted call that makes the layout's flat tree and restacks it into the
     system's per-stage layout, sharded as the system shards it."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec
@@ -133,15 +128,16 @@ def load_weights(eng, c: dict, seed: int) -> None:
     cfg, topo = ex.cfg, ex.topo
     plan = pp.build_plan(cfg, topo.num_stages, max(eng.ec.buckets), ex.run_cfg)
     key = weights.base_key(seed)
+    layout = lookup.layout(c, root)
     weights.check_layout(
-        jax.eval_shape(lambda k: weights.flat_params(k, c), key),
+        jax.eval_shape(lambda k: layout.flat_params(k, c), key),
         jax.eval_shape(build_model(cfg).init, jax.random.key(0)))
     shardings = jax.tree.map(
         lambda p: NamedSharding(topo.mesh, p),
         pp.stage_param_specs(cfg, plan, topo),
         is_leaf=lambda x: isinstance(x, PartitionSpec))
     ex.staged = jax.jit(
-        lambda k: pp.stage_params(cfg, weights.flat_params(k, c), plan),
+        lambda k: pp.stage_params(cfg, layout.flat_params(k, c), plan),
         out_shardings=shardings)(key)
 
 
@@ -261,12 +257,7 @@ def end_to_end(w: dict, setup_s: float) -> dict:
 
 def metric_reader(name: str):
     """``read`` of the per-layer metric's file, ``bench/metrics/<name>.py``."""
-    path = os.path.join(BENCH, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return lookup.module("metrics", name).read
 
 
 def read_per_layer(cell: Cell, view) -> dict:
@@ -281,15 +272,6 @@ def read_per_layer(cell: Cell, view) -> dict:
 
 
 # ------------------------------------------------------------------- check
-
-def reference_module(c: dict):
-    path = os.path.join(BENCH, "reference", c["reference"] + ".py")
-    spec = importlib.util.spec_from_file_location("ref_" + c["reference"],
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
 
 def pick_sample(done: list, n: int, seed: int) -> list:
     """``n`` finished requests drawn from the seed, the longest among
@@ -318,27 +300,30 @@ def compare(served, ref) -> dict:
 
 
 def reference_logits(c: dict, seed: int, prompts: list, modes=("f32",),
-                     device=None) -> list:
+                     device=None, root: str = BENCH) -> list:
     """The plain reference's next-token logits of each prompt, per mode,
-    with the weights made anew from the seed, one layer at a time."""
+    with the weights made anew from the seed by the layout, one layer at a
+    time."""
     import jax
-    ref = reference_module(c)
+    ref = lookup.module("reference", c["reference"], root)
+    layout = lookup.layout(c, root)
     key = weights.base_key(seed)
     with jax.default_device(device):
-        layer_fn = jax.jit(lambda k, i: weights.layer(k, c, i))
-        g = jax.jit(lambda k: weights.globals_(k, c))(key)
+        layer_fn = jax.jit(lambda k, i: layout.layer(k, c, i))
+        g = jax.jit(lambda k: layout.globals_(k, c))(key)
         return ref.last_logits(c, g, lambda i: layer_fn(key, i), prompts,
                                modes)
 
 
-def check(c: dict, seed: int, done: list, vocab: int, device) -> dict:
+def check(c: dict, seed: int, done: list, vocab: int, device,
+          root: str = BENCH) -> dict:
     """Every compared number of the run, each with its limit."""
     chk = c["check"]
     sample = pick_sample(done, chk["sample"], seed)
     prompts = [traffic_mod.tokens(seed, r["rid"], r["seq"], vocab)
                for r in sample]
     t = time.perf_counter()
-    refs = reference_logits(c, seed, prompts, device=device)
+    refs = reference_logits(c, seed, prompts, device=device, root=root)
     worst = {}
     for r, ref in zip(sample, refs):
         for k, v in compare(r["result"], ref["f32"]).items():
@@ -368,11 +353,11 @@ def free_weights(eng) -> None:
 
 
 def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
-        t_start: float, keep_trace: str = None) -> dict:
+        t_start: float, keep_trace: str = None, root: str = BENCH) -> dict:
     import jax
     c, t = cell.config, cell.traffic
     counter = CompileCounter()
-    eng = build_engine(c, t, devices, seed)
+    eng = build_engine(c, t, devices, seed, root)
     vocab = c["vocab_size"]
     warm_up(eng, t, vocab)
     n_warm = len(eng.waves())
@@ -425,7 +410,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
                    for m in cell.end_to_end if m["name"] in e2e}
     free_weights(eng)
     del eng
-    numbers = check(c, seed, w["done"], vocab, dev)
+    numbers = check(c, seed, w["done"], vocab, dev, root)
     # a request that never finished is as wrong as a wrong answer
     correct = bool(w["done"]) and w["failed"] == 0 and all(
         v["value"] <= v["limit"] for v in numbers.values())
