@@ -23,10 +23,21 @@ class MoEConfig:
     # expert-parallel padding: experts [num_real:] are zero-weight and their
     # router logits are masked — bit-exact with the unpadded model (0 = none)
     num_real_experts: int = 0
+    # width of the shared expert's SwiGLU (0 -> num_shared_experts x d_expert)
+    d_shared: int = 0
+    # the experts this chip holds, [held_first, held_first + held_count) of
+    # num_experts (0 = all): the dropless layer routes over all of them and
+    # computes the held ones' part of the result (expert parallelism)
+    held_first: int = 0
+    held_count: int = 0
 
     @property
     def real_experts(self) -> int:
         return self.num_real_experts or self.num_experts
+
+    @property
+    def held(self) -> int:
+        return self.held_count or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -70,7 +81,7 @@ class FrontendConfig:
 @dataclass(frozen=True)
 class ModelConfig:
     arch: str
-    family: str  # dense | moe | ssm | hybrid | encdec | vlm
+    family: str  # dense | moe | ssm | hybrid | hybrid_moe | encdec | vlm
     num_layers: int
     d_model: int
     num_heads: int
@@ -87,6 +98,11 @@ class ModelConfig:
     logits_scaling: float = 1.0
     residual_multiplier: float = 1.0
     attention_multiplier: float = 0.0  # 0 -> 1/sqrt(head_dim)
+    # attention without rotary positions (Granite-4.0-H's "nope")
+    nope: bool = False
+    # hybrid_moe: the kind of each layer's mixer, "mamba" or "attention"
+    # (a model cut to fewer layers keeps the first num_layers)
+    layer_types: Tuple[str, ...] = ()
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     hybrid: Optional[HybridConfig] = None
@@ -104,6 +120,22 @@ class ModelConfig:
     @property
     def attn_free(self) -> bool:
         return self.family == "ssm"
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """Mixer kind of every layer (hybrid_moe)."""
+        return tuple(self.layer_types[:self.num_layers])
+
+    @property
+    def attn_layer_count(self) -> int:
+        """Layers that attend (and hold KV)."""
+        if self.family == "ssm":
+            return 0
+        if self.family == "hybrid":
+            return self.hybrid.num_groups
+        if self.family == "hybrid_moe":
+            return self.kinds.count("attention")
+        return self.num_layers
 
     @property
     def subquadratic(self) -> bool:
@@ -154,6 +186,19 @@ class ModelConfig:
             n_ssm = h.num_groups * h.ssm_per_group + h.tail_ssm_layers
             shared = attn_params() + dense_ffn(self.d_ff) + block_norms()
             return emb + n_ssm * ssm_per + shared + d
+        if self.family == "hybrid_moe":
+            s, m = self.ssm, self.moe
+            d_in = s.expand * d
+            nheads = d_in // s.head_dim
+            conv_ch = d_in + 2 * s.n_groups * s.d_state
+            mamba = (d * (d_in + conv_ch + nheads) + s.conv_kernel * conv_ch
+                     + conv_ch + 3 * nheads + d_in + d_in * d + d)
+            attn = attn_params() + d
+            moe = (d * m.num_experts + m.held * 3 * d * m.d_expert
+                   + 3 * d * m.d_shared + d)
+            kinds = self.kinds
+            return (emb + kinds.count("mamba") * mamba
+                    + kinds.count("attention") * attn + len(kinds) * moe + d)
         if self.family == "moe":
             m = self.moe
             d_e = m.d_expert or self.d_ff
@@ -185,11 +230,7 @@ class ModelConfig:
         hd = self.resolved_head_dim
         if self.family == "ssm":
             return 0
-        if self.family == "hybrid":
-            n_attn = self.hybrid.num_groups  # shared block applied once per group
-            return 2 * n_attn * self.num_kv_heads * hd * bytes_per_el
-        n_attn = self.num_layers
-        return 2 * n_attn * self.num_kv_heads * hd * bytes_per_el
+        return 2 * self.attn_layer_count * self.num_kv_heads * hd * bytes_per_el
 
 
 @dataclass(frozen=True)
@@ -237,8 +278,9 @@ class RunConfig:
     # copies — no gather_chunks stack in HBM, DESIGN.md §3.7)
     pool_backend: str = "auto"
     # SSD inner loop for the ssm/hybrid stage programs, same knob pattern:
-    # "jnp" = models.ssm.ssd_chunked reference; "pallas" = kernels.ops.ssd
-    ssm_backend: str = "jnp"
+    # "jnp" = models.ssm.ssd_chunked reference; "pallas" = kernels.ops.ssd;
+    # "auto" = the platform's default (models.ssm.ssd_impl)
+    ssm_backend: str = "auto"
     # KV page store (repro.kvstore): storage dtype of the per-stage paged
     # pool — "auto" (model dtype, bit-identical to the unpaged pool),
     # "int8" / "fp8" (per-kv-head-scale codec; spill/fetch wires carry the
@@ -311,7 +353,7 @@ def _ensure_loaded() -> None:
     for mod in (
         "whisper_small", "qwen3_8b", "stablelm_3b", "granite_3_2b", "qwen3_14b",
         "granite_moe_3b_a800m", "qwen2_moe_a2_7b", "llava_next_34b",
-        "zamba2_7b", "mamba2_130m",
+        "zamba2_7b", "mamba2_130m", "granite_4_0_h_small",
         # paper-evaluation models (simulator workloads, Fig. 6)
         "llama3_70b", "mistral_123b", "qwen3_235b", "llama3_405b",
     ):

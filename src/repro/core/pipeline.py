@@ -45,8 +45,8 @@ from repro.core.staging import (Params, alloc_kv_pool,  # noqa: F401
                                 pad_q_heads, stage_param_specs, stage_params)
 from repro.kvstore.pages import PagedPool
 from repro.core.stagestep import (StageCtx, attend_chunk,  # noqa: F401
-                                  hybrid_stage_step, ssm_stage_step,
-                                  tfm_stage_step)
+                                  hybrid_stage_step, pattern_stage_step,
+                                  ssm_stage_step, tfm_stage_step)
 from repro.models import layers as L
 from repro.models import ssm as S
 from repro.models.topology import Topology
@@ -115,6 +115,11 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
     bit-identical to a build without this feature (the keys exist in the
     ledger/telemetry pytrees unconditionally, so no collective count
     changes). Return order is ``logits[, ledger][, telemetry][, kv]``.
+
+    A ``hybrid_moe`` model always returns, last, its held-expert counter:
+    the (token, pick) pairs of the prompts' real chunks that went to the
+    experts this chip holds, per layer ([N * lps] int32, global layer
+    order; ``layers.held_moe``).
     """
     if plan.mode == "gpipe":
         assert not return_ledger, "gpipe has no MBKR transport ledger"
@@ -153,6 +158,7 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
     ring_perm = [(i, (i + 1) % n) for i in range(n)]
 
     is_hybrid = cfg.family == "hybrid"
+    is_pattern = cfg.family == "hybrid_moe"
     is_ssm = cfg.family == "ssm"
     is_encdec = cfg.family == "encdec"
 
@@ -190,10 +196,14 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
         else:
             pool = alloc_kv_pool(cfg, plan, b, topo, mtp=mtp)
         x0 = jnp.zeros((b, c, cfg.d_model), dt)
-        if is_ssm or is_hybrid:
+        if is_ssm or is_hybrid or is_pattern:
             d_in, nheads, conv_ch = S.dims(cfg)
             s = cfg.ssm
-            if is_hybrid:
+            if is_pattern:
+                nm = plan.stage_kinds.count("mamba")
+                conv0 = jnp.zeros((nm, b, s.conv_kernel - 1, conv_ch), jnp.float32)
+                ssd0 = jnp.zeros((nm, b, nheads, s.head_dim, s.d_state), jnp.float32)
+            elif is_hybrid:
                 pg = cfg.hybrid.ssm_per_group
                 conv0 = jnp.zeros((lps, pg, b, s.conv_kernel - 1, conv_ch), jnp.float32)
                 ssd0 = jnp.zeros((lps, pg, b, nheads, s.head_dim, s.d_state), jnp.float32)
@@ -225,11 +235,11 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
         # one chunk's STORED pool bytes (local shard geometry under manual
         # TP — the telemetry collect psum restores logical stage bytes)
         chunk_bytes = 0.0 if is_ssm else obs_t.chunk_stored_bytes(
-            plan, lps, b, c, kvh, hd)
+            plan, plan.pool_layers, b, c, kvh, hd)
         rep = mtp.tp if mtp is not None else 1
 
         def tick(carry, t):
-            x_prev, pool, state, x_last, led, tel = carry
+            x_prev, pool, state, x_last, led, tel, picks = carry
             phase = t - stage
             ctx = StageCtx(cfg=cfg, plan=plan, topo=topo, stage=stage,
                            phase=phase, first_half=stage < n // 2,
@@ -267,6 +277,10 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
                 x_out, state, pool, led, tel = hybrid_stage_step(
                     ctx, stage_layers, extra["shared"], x, state, pool, led,
                     tel)
+            elif is_pattern:
+                x_out, state, pool, held, led, tel = pattern_stage_step(
+                    ctx, stage_layers, x, state, pool, led, tel)
+                picks = picks + jnp.where(ctx.active, held, 0)
             else:
                 x_out, pool, led, tel = tfm_stage_step(
                     ctx, stage_layers, x, pool, led, tel, cross=cross)
@@ -292,12 +306,14 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
             if health is not None:
                 nbad = jnp.sum(~jnp.isfinite(x_out.astype(jnp.float32)))
                 bad = jnp.where(ctx.active, nbad, 0).astype(jnp.int32)
-            return (x_next, pool, state, x_last, led, tel), (tel_ys, bad)
+            return ((x_next, pool, state, x_last, led, tel, picks),
+                    (tel_ys, bad))
 
         tel0 = obs_t.telemetry_init() if return_telemetry else None
-        carry0 = (x0, pool, state0, x_last0, tx.ledger_init(), tel0)
-        (xf, pool_f, _, x_last, led, _), (tel_ys, bad_ys) = jax.lax.scan(
-            tick, carry0, jnp.arange(plan.num_ticks))
+        picks0 = jnp.zeros((lps,), jnp.int32) if is_pattern else None
+        carry0 = (x0, pool, state0, x_last0, tx.ledger_init(), tel0, picks0)
+        (xf, pool_f, _, x_last, led, _, picks), (tel_ys, bad_ys) = \
+            jax.lax.scan(tick, carry0, jnp.arange(plan.num_ticks))
         # replicate the final hidden state across stages
         x_last, led = transport.stage_psum(x_last, st_ax, led)
         led = tx.ledger_collect(led, led_axes)
@@ -310,6 +326,8 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
             # scan-final pool, re-stacked on a leading stage axis for the
             # host-side snapshot (mirrors the prefix_pool input layout)
             outs.append(jax.tree.map(lambda a: a[None], pool_f))
+        if is_pattern:
+            outs.append(picks[None, :])  # [1, lps] local stage row
         if health is not None:
             # residual is replicated across manual TP, so the count already
             # agrees on every TP shard — no psum, no extra collective
@@ -357,6 +375,8 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
             kv_leaf_spec, kv_leaf_spec,
             kv_leaf_spec if plan.codec.quantized else None,
             kv_leaf_spec if plan.codec.quantized else None))
+    if is_pattern:
+        out_specs_l.append(P(st_ax, None))
     if health is not None:
         out_specs_l.append(P(st_ax, None))
     out_specs = tuple(out_specs_l)
@@ -373,6 +393,8 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
     x_last, ledger = outs[0], outs[1]
     telem = outs[2] if return_telemetry else None
     kv_out = outs[2 + int(return_telemetry)] if return_kv else None
+    held = (outs[2 + int(return_telemetry) + int(return_kv)].reshape(-1)
+            if is_pattern else None)
     if health is not None:
         # operand callbacks are legal HERE (outside the manual region):
         # one host delivery of the full [N, T] non-finite profile
@@ -397,4 +419,6 @@ def prefill_pipeline(cfg: ModelConfig, staged: Params, tokens: jax.Array,
         ret.append(telem)
     if return_kv:
         ret.append(kv_out)
+    if is_pattern:
+        ret.append(held)
     return ret[0] if len(ret) == 1 else tuple(ret)

@@ -51,7 +51,8 @@ class PipelinePlan:
                                  # RunConfig.pool_backend ("auto" follows
                                  # attn_backend; "paged" = gather-free
                                  # ragged pool kernel) — never "auto" here
-    ssm_backend: str = "jnp"     # jnp | pallas (kernels.ops.ssd)
+    ssm_backend: str = "jnp"     # jnp | pallas (kernels.ops.ssd); "auto"
+                                 # is resolved by platform in build_plan
     spill_dtype: str = "bfloat16"  # int8 -> wire-only spill compression
     ship_dtype: str = "bfloat16"   # qship q/acc wire format (= model dtype)
     # KV page store (repro.kvstore): the pool holds fixed-size pages in the
@@ -69,6 +70,17 @@ class PipelinePlan:
     host_slots_used: Any = None   # [H] the (few) slots host tables touch —
                                   # the creditor-side scan visits ONLY these
     slot_pages: Any = None        # [slots+1, ppc] slot -> physical page ids
+    # hybrid_moe: the mixer kind of each of a stage's layers, the same on
+    # every stage (static; core.stagestep.pattern_stage_step walks it)
+    stage_kinds: tuple = ()
+
+    @property
+    def pool_layers(self) -> int:
+        """Layers of a stage that hold KV (the pool's layer axis): its
+        attention layers by pattern, else every layer (or hybrid group)."""
+        if self.stage_kinds:
+            return self.stage_kinds.count("attention")
+        return self.layers_per_stage
 
     @property
     def scratch(self) -> int:
@@ -109,6 +121,10 @@ def build_plan(cfg: ModelConfig, num_stages: int, seq_len: int,
 
     mode = mode or ("mocap" if run.mbkr else "terapipe")
     m = run.num_chunks
+    import jax
+    from repro.models.ssm import ssd_impl
+    ssm_backend = ssd_impl(run.ssm_backend, jax.default_backend())
+    kinds = _stage_kinds(cfg, num_stages)
     pool_backend = (run.attn_backend if run.pool_backend in ("auto", "", None)
                     else run.pool_backend)
     tp_lowering = compat.resolve_tp_lowering(run.tp_lowering)
@@ -119,10 +135,11 @@ def build_plan(cfg: ModelConfig, num_stages: int, seq_len: int,
                             _layers_per_stage(cfg, num_stages), 0, m,
                             attn_backend=run.attn_backend,
                             pool_backend=pool_backend,
-                            ssm_backend=run.ssm_backend,
+                            ssm_backend=ssm_backend,
                             transport=run.transport,
                             tp_lowering=tp_lowering,
-                            fetch_batch=run.fetch_batch)
+                            fetch_batch=run.fetch_batch,
+                            stage_kinds=kinds)
     assert seq_len % m == 0, f"seq_len {seq_len} must divide into {m} chunks"
     c = seq_len // m
     use_mbkr = mode == "mocap" and not cfg.attn_free and num_stages >= 2 and m >= 2
@@ -142,10 +159,11 @@ def build_plan(cfg: ModelConfig, num_stages: int, seq_len: int,
         remote_attn=run.remote_attn,
         attn_backend=run.attn_backend,
         pool_backend=pool_backend,
-        ssm_backend=run.ssm_backend,
+        ssm_backend=ssm_backend,
         transport=run.transport,
         tp_lowering=tp_lowering,
         fetch_batch=run.fetch_batch,
+        stage_kinds=kinds,
         spill_dtype=run.kv_spill_dtype,
         ship_dtype=cfg.dtype,   # wire in model precision (bf16 in prod)
         kv_dtype=codec.name, page_tokens=geom.page_tokens,
@@ -158,6 +176,23 @@ def build_plan(cfg: ModelConfig, num_stages: int, seq_len: int,
             [mp.host_slot_a[mp.p2:], mp.host_slot_b[mp.p2:]])).astype(np.int32)
         if mp.p2 < m else np.zeros((0,), np.int32),
     )
+
+
+def _stage_kinds(cfg: ModelConfig, n: int) -> tuple:
+    """hybrid_moe: the kinds of one stage's layers, which every stage must
+    share (the stage count divides the layers, each stage holding whole
+    periods of the pattern); () for the other families."""
+    if cfg.family != "hybrid_moe":
+        return ()
+    kinds, nl = cfg.kinds, cfg.num_layers
+    lps = nl // n
+    stages = {kinds[s * lps:(s + 1) * lps] for s in range(n)}
+    if nl % n or len(stages) != 1:
+        raise ValueError(
+            f"{cfg.arch}: {nl} layers of pattern {kinds} do not split into "
+            f"{n} stages of one local pattern; give a stage count that "
+            f"divides the layers into whole periods")
+    return kinds[:lps]
 
 
 def _layers_per_stage(cfg: ModelConfig, n: int) -> int:

@@ -371,3 +371,71 @@ def hybrid_stage_step(ctx: StageCtx, groups: Params, shared: Params,
     with jax.named_scope("layer.kv_write"):
         pool, led, tel = remote.write_pools(ctx, pool, ks, vs, led, tel)
     return x, (conv2, ssd2), pool, led, tel
+
+
+# ------------------------------------------------------- layer-pattern step
+
+def pattern_stage_step(ctx: StageCtx, layers: Params, x: jax.Array, state,
+                       pool, led: Ledger = None, tel: StageTelemetry = None):
+    """hybrid_moe stage: the stage's layers in the order of its pattern
+    (``plan.stage_kinds``, static and the same on every stage), unrolled:
+    the expert weights are read in place from the layer stack
+    (``models.hybrid_moe.moe_block``), where a scan over the layers would
+    slice them, and a grouped matmul copies a slice it is given.
+
+    - A Mamba-2 mixer carries its conv and SSD state from tick to tick
+      (zeroed at phase 0, the start of the request), as ``ssm_stage_step``.
+    - An attention mixer goes through ``attend_chunk`` on its own layer of
+      the pool (rotary positions unless ``cfg.nope``).
+    - Every mixer is followed by the held-expert MoE and the shared expert
+      (``models.hybrid_moe.moe_block``).
+
+    ``state`` = (conv [n_mamba, B, K-1, ch], ssd [n_mamba, B, H, P, N]) of
+    this stage's Mamba layers. Returns (x, state, pool, held (token, pick)
+    pairs per layer [lps] int32, ledger, telemetry)."""
+    from repro.models import hybrid_moe as HM
+    cfg, plan = ctx.cfg, ctx.plan
+    b, c, _ = x.shape
+    hd = cfg.resolved_head_dim
+    fresh = ctx.phase <= 0
+    rope = None
+    if not cfg.nope:
+        with jax.named_scope("layer.attn_proj"):
+            positions = jnp.clip(ctx.phase, 0, plan.num_chunks - 1) \
+                * plan.chunk_len + jnp.arange(c)[None, :]
+            rope = L.rope_angles(positions, hd, cfg.rope_theta)
+    conv, ssd = state
+    at = lambda tree, i: jax.tree.map(lambda a: a[i], tree)
+    seen = {HM.MAMBA: 0, HM.ATTENTION: 0}
+    picks, ks, vs, convs, ssds = [], [], [], [], []
+    for li, kind in enumerate(plan.stage_kinds):
+        i = seen[kind]
+        seen[kind] += 1
+        if kind == HM.MAMBA:
+            carried = {"conv": jnp.where(fresh, 0.0, conv[i]),
+                       "ssd": jnp.where(fresh, 0.0, ssd[i])}
+            x, st = S.block_apply(cfg, at(layers["mamba"], i), x,
+                                  state=carried, ssd_impl=plan.ssm_backend)
+            convs.append(st["conv"])
+            ssds.append(st["ssd"])
+        else:
+            lp = at(layers["attn"], i)
+            with jax.named_scope("layer.attn_proj"):
+                q, k, v = HM.attn_proj(cfg, lp, x, rope)
+            att, led, tel = attend_chunk(ctx, i, q, k, v, pool, led, tel)
+            with jax.named_scope("layer.attn_proj"):
+                upd = jnp.einsum("bcq,qd->bcd",
+                                 att.reshape(b, c, att.shape[2] * hd),
+                                 lp["wo"])
+                x = x + cfg.residual_multiplier * upd
+            ks.append(k)
+            vs.append(v)
+        x, held = HM.moe_block(cfg, layers["moe"], li, x)
+        picks.append(held)
+    if ks:
+        with jax.named_scope("layer.kv_write"):
+            pool, led, tel = remote.write_pools(ctx, pool, jnp.stack(ks),
+                                                jnp.stack(vs), led, tel)
+    stack = lambda parts, like: jnp.stack(parts) if parts else like
+    return (x, (stack(convs, conv), stack(ssds, ssd)), pool,
+            jnp.stack(picks), led, tel)

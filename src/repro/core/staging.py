@@ -55,8 +55,10 @@ class ManualTP:
 
 def manual_tp_plan(cfg: ModelConfig, plan: PipelinePlan,
                    topo: Optional[Topology]) -> Optional[ManualTP]:
-    """None unless the plan asks for manual lowering AND tp > 1."""
-    if topo is None or plan.tp_lowering != "manual" or topo.tp_size <= 1:
+    """None unless the plan asks for manual lowering AND tp > 1 (and never
+    for hybrid_moe, whose layers replicate over the TP axes)."""
+    if (topo is None or plan.tp_lowering != "manual" or topo.tp_size <= 1
+            or cfg.family == "hybrid_moe"):
         return None
     md = topo.tp_axis
     axes = md if isinstance(md, tuple) else (md,)
@@ -145,7 +147,7 @@ def alloc_kv_pool(cfg: ModelConfig, plan: PipelinePlan, b: int,
     if mtp is not None:
         kvh //= mtp.kv_div
     pool = kvpages.alloc_pool(plan.page_geometry, plan.codec,
-                              plan.layers_per_stage, b, kvh, hd)
+                              plan.pool_layers, b, kvh, hd)
     if (topo is not None and isinstance(topo.tp_axis, tuple)
             and auto_tp(topo, mtp)):
         spec = P(None, None, None, topo.tp_axis[0], None, None)
@@ -190,6 +192,13 @@ def stage_params(cfg: ModelConfig, params: Params, plan: PipelinePlan) -> Params
             "embed": params["embed"], "final_norm": params["final_norm"],
             "stage_layers": staged_groups, "shared": params["shared"],
         }
+    if cfg.family == "hybrid_moe":
+        # each kind's stack splits evenly: every stage holds the same
+        # local pattern (plan.stage_kinds)
+        split = lambda a: a.reshape((n, a.shape[0] // n) + a.shape[1:])
+        return {"embed": params["embed"], "final_norm": params["final_norm"],
+                "stage_layers": {g: jax.tree.map(split, params[g])
+                                 for g in ("mamba", "attn", "moe")}}
     if cfg.family == "encdec":
         out = {
             "embed": params["embed"], "final_norm": params["final_norm"],
@@ -250,6 +259,15 @@ def _stage_param_specs(cfg: ModelConfig, plan: PipelinePlan, topo: Topology) -> 
             is_leaf=lambda x: isinstance(x, P))
         out = {"embed": P(None, md), "final_norm": P(None),
                "stage_layers": g_specs, "shared": shared}
+        return _rename_model(out, md)
+    if cfg.family == "hybrid_moe":
+        from repro.models import hybrid_moe as HM
+        hs = HM.specs(cfg, fsdp=False)
+        layers = {g: jax.tree.map(lift, hs[g],
+                                  is_leaf=lambda x: isinstance(x, P))
+                  for g in ("mamba", "attn", "moe")}
+        out = {"embed": P(None, md), "final_norm": P(None),
+               "stage_layers": layers}
         return _rename_model(out, md)
     if cfg.family == "encdec":
         from repro.models import whisper as W

@@ -255,7 +255,7 @@ def analytic_wire_bytes(plan, cfg, b: int, *,
     chunk runtime ships full chunks, so the ledger pins against the dense
     case, and the ragged model prices what partial chunks will save."""
     n, m, c = plan.num_stages, plan.num_chunks, plan.chunk_len
-    lps = plan.layers_per_stage
+    lps = plan.pool_layers
     kvh, hd, h = cfg.num_kv_heads, cfg.resolved_head_dim, cfg.num_heads
     dt = dtype_bytes or float(jnp.dtype(cfg.dtype).itemsize)
     codec = plan.codec

@@ -8,8 +8,14 @@ executes SEQUENTIALLY — the scratch state [P, N] persists across chunk steps
 and is reset at chunk 0 (this is how the recurrence crosses chunk boundaries
 without leaving VMEM).
 
-Layout notes: P (head channel) and N (state) are the two minor dims; Q (chunk
-length) is a multiple of 8 sublanes, P/N multiples of 128 lanes preferred.
+Layout: the wrapper hands the kernel head-major operands, so every block is
+a whole [Q, P] / [Q, N] / [P, N] tile of one head (Mosaic tiles the last two
+dims by 8 x 128 or takes them whole). dt arrives as a [1, Q] row; its
+column and the running sums of dt·A in both orientations are float32
+matmuls with an identity or a triangular mask (no in-kernel transpose or
+cumsum).
+The per-head A_log and D live in SMEM. The big products take the input's
+dtype on the MXU (bf16 on the served path) with fp32 accumulation.
 """
 from __future__ import annotations
 
@@ -21,57 +27,69 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_HI = jax.lax.Precision.HIGHEST
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, dskip_ref,
+
+def _exact(a, b, dims):
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _mm(a, b, dims, dtype):
+    return jax.lax.dot_general(a.astype(dtype), b.astype(dtype), (dims, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _ssd_kernel(a_ref, dskip_ref, x_ref, dt_ref, b_ref, c_ref,
                 init_ref, y_ref, st_out_ref, state_ref, *, chunk: int):
+    hi = pl.program_id(1)
     ci = pl.program_id(2)
     nc = pl.num_programs(2)
 
     @pl.when(ci == 0)
     def _reset():
-        state_ref[...] = init_ref[0, 0, :, :].astype(jnp.float32)
+        state_ref[...] = init_ref[0, 0].astype(jnp.float32)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)        # [Q, P]
-    dt = dt_ref[0, :, 0].astype(jnp.float32)         # [Q]
-    a = a_ref[0]                                     # scalar A_log for this head
-    bm = b_ref[0, :, 0, :].astype(jnp.float32)       # [Q, N]
-    cm = c_ref[0, :, 0, :].astype(jnp.float32)       # [Q, N]
-    d_skip = dskip_ref[0]
+    dtype = x_ref.dtype
+    x = x_ref[0, 0].astype(jnp.float32)              # [Q, P]
+    a = -jnp.exp(a_ref[hi])                          # scalar A of this head
+    dt_row = dt_ref[0, 0].astype(jnp.float32)        # [1, Q]
+    da_row = dt_row * a
+    bm = b_ref[0, 0]                                 # [Q, N]
+    cm = c_ref[0, 0]                                 # [Q, N]
 
-    da = dt * (-jnp.exp(a))                          # [Q]
-    da_cs = jnp.cumsum(da)                           # [Q]
-    xdt = x * dt[:, None]                            # [Q, P]
-
-    # intra-chunk: L[i,j] = exp(da_cs[i] - da_cs[j]) for j <= i
-    seg = da_cs[:, None] - da_cs[None, :]
     iota_i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     iota_j = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    lmat = jnp.where(iota_j <= iota_i, jnp.exp(seg), 0.0)
-    cb = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # [Q, Q]
-    y_diag = jax.lax.dot_general(cb * lmat, xdt, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)  # [Q, P]
+    causal = iota_j <= iota_i
+    tril = causal.astype(jnp.float32)
+    eye = (iota_i == iota_j).astype(jnp.float32)
+    # dt as a column, and the running sums of dt·A, cs[i] = sum_{k <= i}
+    # da[k], as a row and a column
+    dt_col = _exact(eye, dt_row, ((1,), (1,)))                         # [Q, 1]
+    cs_row = _exact(da_row, tril, ((1,), (1,)))                        # [1, Q]
+    cs_col = _exact(tril, da_row, ((1,), (1,)))                        # [Q, 1]
+    total = jnp.sum(da_row, axis=1, keepdims=True)                     # [1, 1]
+    xdt = x * dt_col                                                   # [Q, P]
+
+    # intra-chunk: L[i,j] = exp(cs[i] - cs[j]) for j <= i
+    lmat = jnp.where(causal, jnp.exp(cs_col - cs_row), 0.0)
+    cb = _mm(cm, bm, ((1,), (1,)), dtype)                              # [Q, Q]
+    y_diag = _mm(cb * lmat, xdt, ((1,), (0,)), dtype)                  # [Q, P]
 
     # off-diagonal: read the carried state
-    state = state_ref[...]                           # [P, N]
-    decay_in = jnp.exp(da_cs)                        # [Q]
-    y_off = jax.lax.dot_general(cm, state, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # [Q, P]
-    y_off = y_off * decay_in[:, None]
+    state = state_ref[...]                                             # [P, N]
+    y_off = _mm(cm, state, ((1,), (1,)), dtype) * jnp.exp(cs_col)      # [Q, P]
 
-    y = y_diag + y_off + x * d_skip
-    y_ref[0, :, 0, :] = y.astype(y_ref.dtype)
+    y = y_diag + y_off + x * dskip_ref[hi]
+    y_ref[0, 0] = y.astype(y_ref.dtype)
 
-    # state update: state' = state * exp(sum da) + sum_q decay_out[q] B[q] (x dt)[q]
-    decay_out = jnp.exp(da_cs[-1] - da_cs)           # [Q]
-    upd = jax.lax.dot_general((xdt * decay_out[:, None]), bm,
-                              (((0,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)  # [P, N]
-    state_ref[...] = state * jnp.exp(da_cs[-1]) + upd
+    # state' = state * exp(sum da) + sum_q exp(cs[-1] - cs[q]) (x dt)[q] B[q]
+    upd = _mm(xdt * jnp.exp(total - cs_col), bm, ((0,), (0,)), dtype)  # [P, N]
+    state_ref[...] = state * jnp.exp(total) + upd
 
     @pl.when(ci == nc - 1)
     def _emit_state():
-        st_out_ref[0, 0, :, :] = state_ref[...]
+        st_out_ref[0, 0] = state_ref[...]
 
 
 def ssd_pallas(x: jax.Array, dt: jax.Array, a_log: jax.Array,
@@ -91,31 +109,34 @@ def ssd_pallas(x: jax.Array, dt: jax.Array, a_log: jax.Array,
     nc = t // chunk
     if init_state is None:
         init_state = jnp.zeros((bs, h, p, n), jnp.float32)
+    head_major = lambda a: jnp.moveaxis(a, 2, 1)          # [B,T,H,..] -> [B,H,T,..]
+    dth = head_major(dt.astype(jnp.float32))[:, :, None]  # [B,H,1,T]
 
     grid = (bs, h, nc)
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     y, st = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda bi, hi, ci: (bi, ci, hi)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
-            pl.BlockSpec((1, chunk, 1, n), lambda bi, hi, ci: (bi, ci, hi // hg, 0)),
-            pl.BlockSpec((1, chunk, 1, n), lambda bi, hi, ci: (bi, ci, hi // hg, 0)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
+            smem, smem,
+            pl.BlockSpec((1, 1, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda bi, hi, ci: (bi, hi, 0, ci)),
+            pl.BlockSpec((1, 1, chunk, n), lambda bi, hi, ci: (bi, hi // hg, ci, 0)),
+            pl.BlockSpec((1, 1, chunk, n), lambda bi, hi, ci: (bi, hi // hg, ci, 0)),
             pl.BlockSpec((1, 1, p, n), lambda bi, hi, ci: (bi, hi, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda bi, hi, ci: (bi, ci, hi, 0)),
+            pl.BlockSpec((1, 1, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
             pl.BlockSpec((1, 1, p, n), lambda bi, hi, ci: (bi, hi, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bs, t, h, p), x.dtype),
+            jax.ShapeDtypeStruct((bs, h, t, p), x.dtype),
             jax.ShapeDtypeStruct((bs, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(x, dt, a_log.astype(jnp.float32), b, c, d_skip.astype(jnp.float32),
+    )(a_log.astype(jnp.float32), d_skip.astype(jnp.float32), head_major(x),
+      dth, head_major(b), head_major(c),
       init_state)
-    return y, st
+    return head_major(y), st

@@ -39,7 +39,7 @@ class ServeOptions:
     # kernel / transport backends
     attn_backend: str = "jnp"
     pool_backend: str = "auto"
-    ssm_backend: str = "jnp"
+    ssm_backend: str = "auto"
     tp_lowering: str = "auto"
     transport: str = "jax"
     fetch_batch: str = "auto"
@@ -135,8 +135,10 @@ def add_serve_args(ap: argparse.ArgumentParser) -> None:
                          "+ fetch/qship) — mixable with --attn-backend; "
                          "paged = one RAGGED launch straight off the page "
                          "store (DESIGN.md §3.7)")
-    ap.add_argument("--ssm-backend", default=S, choices=("jnp", "pallas"),
-                    help="SSD inner loop for ssm/hybrid archs")
+    ap.add_argument("--ssm-backend", default=S,
+                    choices=("auto", "jnp", "pallas"),
+                    help="SSD inner loop for ssm/hybrid archs; auto = the "
+                         "platform's default (models.ssm.ssd_impl)")
     ap.add_argument("--tp-lowering", default=S, choices=("auto", "manual"),
                     help="TP lowering (core.transport, DESIGN.md §3.6)")
     ap.add_argument("--transport", default=S,

@@ -116,14 +116,21 @@ def jax_devices():
     return devices
 
 
-def pipeline_shape(n_dev: int, opts: ServeOptions, platform: str):
+def pipeline_shape(n_dev: int, opts: ServeOptions, platform: str,
+                   cfg=None):
     """(stages, tp) over ``n_dev`` devices: tp=2 once there are >= 4,
     otherwise one stage per device (a single chip is a one-stage
     pipeline; MBKR then has no pair and is off). Compiled (Mosaic) Pallas
     kernels cannot be auto-partitioned, so on a TPU with a Pallas backend
-    under ``tp_lowering="auto"`` every device is a stage of its own."""
-    pallas = {opts.attn_backend, opts.pool_backend,
-              opts.ssm_backend} & {"pallas", "paged"}
+    under ``tp_lowering="auto"`` every device is a stage of its own. The
+    SSD backend counts where the model (``cfg``, else ``opts.arch``'s)
+    has SSM layers, resolved by platform as the plan resolves it."""
+    from repro.models.ssm import ssd_impl
+    cfg = cfg or get_config(opts.arch)
+    backends = {opts.attn_backend, opts.pool_backend}
+    if cfg.ssm is not None:
+        backends.add(ssd_impl(opts.ssm_backend, platform))
+    pallas = backends & {"pallas", "paged"}
     mosaic_auto = (platform == "tpu" and bool(pallas)
                    and opts.tp_lowering == "auto")
     tp = 2 if n_dev >= 4 and not mosaic_auto else 1
@@ -172,12 +179,16 @@ def _build_engine(opts: ServeOptions, *, cfg=None, topo=None, jax_ctx=None):
         import jax
         from repro.core import pipeline as pp
         from repro.launch.mesh import make_test_topology
+        from repro.models.ssm import ssd_impl
         if cfg is None:
             cfg = (replace(get_smoke_config(opts.arch), dtype="float32")
                    if opts.preset == "smoke" else get_config(opts.arch))
+        platform = jax.devices()[0].platform
+        opts = opts.override(ssm_backend=ssd_impl(opts.ssm_backend,
+                                                  platform))
         if jax_ctx is None:
-            stages, tp = pipeline_shape(jax.device_count(), opts,
-                                        jax.devices()[0].platform)
+            stages, tp = pipeline_shape(jax.device_count(), opts, platform,
+                                        cfg)
             jax_ctx = {"stages": stages, "tp": tp}
         stages, tp = jax_ctx["stages"], jax_ctx["tp"]
         if topo is None:

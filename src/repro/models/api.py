@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
-from repro.models import hybrid, ssm, transformer, whisper
+from repro.models import hybrid, hybrid_moe, ssm, transformer, whisper
 from repro.models import layers as L
 
 
@@ -27,7 +27,8 @@ class Model:
     def _mod(self):
         return {
             "dense": transformer, "moe": transformer, "vlm": transformer,
-            "ssm": ssm, "hybrid": hybrid, "encdec": whisper,
+            "ssm": ssm, "hybrid": hybrid, "hybrid_moe": hybrid_moe,
+            "encdec": whisper,
         }[self.cfg.family]
 
     # ------------------------------------------------------------- params

@@ -283,6 +283,71 @@ def moe_layer(params, x, *, num_experts: int, top_k: int, capacity_factor: float
     return jax.vmap(combine_one)(y, tok, valid).astype(x.dtype)
 
 
+def held_moe(params, x, *, top_k: int, held_first: int = 0,
+             layer: Optional[int] = None):
+    """Dropless token-choice top-k MoE over the experts this chip holds.
+
+    The router scores every expert (``router`` [d, E]) and each token keeps
+    its top ``top_k``, gated by a softmax over the k picked logits. The
+    (token, pick) pairs whose expert lies in ``[held_first, held_first +
+    Eh)`` (``Eh`` = the experts in ``e_wgu`` [Eh, d, 2f] / ``e_wd`` [Eh, f,
+    d]) are sorted by expert and run as one grouped matmul for gate and up
+    and one for down (``jax.lax.ragged_dot``): no capacity, nothing
+    dropped, no padded slots. The other pairs belong to experts another
+    chip holds and add nothing here, so the result is this chip's part of
+    the layer (expert parallelism without its exchange).
+
+    With ``layer`` (static) the expert weights are stacked over layers,
+    [L, Eh, ...], and this layer's experts are read in place as groups
+    ``[layer * Eh, (layer + 1) * Eh)`` of the stack: a grouped matmul takes
+    whole operands, so a slice of the stack would be a copy.
+
+    x [..., d]. Returns (out [..., d], held pairs as an int32 scalar)."""
+    shape, d = x.shape, x.shape[-1]
+    xt = x.reshape(-1, d)
+    wgu, wd = params["e_wgu"], params["e_wd"]
+    t, eh = xt.shape[0], wgu.shape[-3]
+    first = 0
+    if layer is not None:
+        first = layer * eh
+        wgu = wgu.reshape((-1,) + wgu.shape[-2:])
+        wd = wd.reshape((-1,) + wd.shape[-2:])
+    groups = wgu.shape[0]
+    with jax.named_scope("layer.moe_route"):
+        logits = jnp.einsum("td,de->te", xt, params["router"],
+                            preferred_element_type=jnp.float32)
+        top, picked = jax.lax.top_k(logits, top_k)               # [T, k]
+        gates = jax.nn.softmax(top, axis=-1)
+        local = picked - held_first
+        held = (local >= 0) & (local < eh)
+        # sort the pairs by held expert; pairs of absent experts go last,
+        # past every group, where no matmul reads them (their rows of the
+        # product are zeros)
+        group = jnp.where(held, first + local, groups).reshape(-1)  # [T*k]
+        order = jnp.argsort(group, stable=True)
+        # rows per group, by a one-hot count
+        sizes = jnp.sum(group[:, None] == jnp.arange(groups), axis=0,
+                        dtype=jnp.int32)
+        rows = jnp.take(xt, order // top_k, axis=0, mode="clip")  # [T*k, d]
+    with jax.named_scope("layer.moe_experts"):
+        gu = jax.lax.ragged_dot(rows, wgu, sizes)
+        g, u = jnp.split(gu, 2, axis=-1)
+        y = jax.lax.ragged_dot(jax.nn.silu(g) * u, wd, sizes)
+    with jax.named_scope("layer.moe_combine"):
+        # each token's k rows, found by the inverse permutation (a sort),
+        # gated and summed one pick at a time: no [T*k, d] copy in (token,
+        # pick) order. The absent experts' rows are zeros and their gates
+        # are dropped.
+        at = jnp.argsort(order).reshape(t, top_k)
+        w = jnp.where(held, gates, 0.0)
+        out = jnp.zeros((t, d), jnp.float32)
+        for j in range(top_k):
+            out = out + w[:, j, None] * jnp.take(
+                y, at[:, j], axis=0, mode="clip")
+        out = out.astype(x.dtype)
+    return out.reshape(shape), jnp.sum(held, dtype=jnp.int32)
+
+
 # --------------------------------------------------------- embed / unembed
 
 def pad_vocab(v: int, multiple: int = 128) -> int:

@@ -115,6 +115,17 @@ def _ssd_pallas(x, dt, a_log, b, c, d_skip, *, chunk: int,
 # SSD inner-loop registry (the ssm/hybrid analogue of the attention-backend
 # registry): selected per-plan via ``RunConfig.ssm_backend``.
 SSD_IMPLS = {"jnp": ssd_chunked, "pallas": _ssd_pallas}
+# what ``ssm_backend="auto"`` runs, by platform: the faster on the chip,
+# the jnp reference everywhere else (PERF.md has the TPU timings)
+SSD_DEFAULT = {"tpu": "pallas"}
+
+
+def ssd_impl(requested: str, platform: str) -> str:
+    """The SSD backend a plan runs: ``requested``, or for "auto" the
+    platform's default (``SSD_DEFAULT``)."""
+    if requested == "auto":
+        return SSD_DEFAULT.get(platform, "jnp")
+    return requested
 
 
 def ssd_decode_step(x, dt, a_log, b, c, d_skip, state):
@@ -202,33 +213,43 @@ def block_specs(cfg: ModelConfig, *, fsdp: bool = True) -> Params:
 def block_apply(cfg: ModelConfig, lp: Params, x: jax.Array, *,
                 state: Optional[Dict[str, jax.Array]] = None,
                 topo: Optional[Topology] = None, ssd_impl: str = "jnp"):
-    """Mamba2 block over a (chunk of a) sequence. Returns (y, new_state).
-    ``ssd_impl`` picks the SSD inner loop from ``SSD_IMPLS``."""
+    """Mamba2 block over a (chunk of a) sequence. Returns (y, new_state);
+    the block's output joins the residual scaled by
+    ``cfg.residual_multiplier``. ``ssd_impl`` picks the SSD inner loop from
+    ``SSD_IMPLS``. The projections, conv and gated norm run under the
+    ``layer.ssm_proj`` scope, the scan under ``layer.ssd``."""
     b, t, d = x.shape
     s = cfg.ssm
     d_in, nheads, conv_ch = dims(cfg)
-    hn = L.rms_norm(x, lp["ln"], cfg.norm_eps)
-    zxbcdt = jnp.einsum("btd,de->bte", hn, lp["in_proj"])
-    z, xbc, dtv = jnp.split(zxbcdt, [d_in, d_in + conv_ch], axis=-1)
-    conv_init = None if state is None else state["conv"]
-    xbc, conv_tail = causal_conv(xbc, lp["conv_w"], lp["conv_b"], init_state=conv_init)
-    xbc = jax.nn.silu(xbc)
-    xs, bmat, cmat = jnp.split(xbc, [d_in, d_in + s.n_groups * s.d_state], axis=-1)
-    xh = xs.reshape(b, t, nheads, s.head_dim)
-    bmat = bmat.reshape(b, t, s.n_groups, s.d_state)
-    cmat = cmat.reshape(b, t, s.n_groups, s.d_state)
-    dtv = jax.nn.softplus(dtv.astype(jnp.float32) + lp["dt_bias"])  # [B,T,H]
-    ssd_init = None if state is None else state["ssd"]
     if ssd_impl not in SSD_IMPLS:
         raise KeyError(f"unknown ssm backend {ssd_impl!r}; "
                        f"registered: {sorted(SSD_IMPLS)}")
-    y, new_ssd = SSD_IMPLS[ssd_impl](xh, dtv, lp["a_log"], bmat, cmat,
-                                     lp["d_skip"], chunk=s.chunk_size,
-                                     init_state=ssd_init)
-    y = y.reshape(b, t, d_in)
-    y = L.rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
-                   lp["gate_norm"], cfg.norm_eps)
-    out = jnp.einsum("bte,ed->btd", y, lp["out_proj"])
+    with jax.named_scope("layer.ssm_proj"):
+        hn = L.rms_norm(x, lp["ln"], cfg.norm_eps)
+        zxbcdt = jnp.einsum("btd,de->bte", hn, lp["in_proj"])
+        z, xbc, dtv = jnp.split(zxbcdt, [d_in, d_in + conv_ch], axis=-1)
+        conv_init = None if state is None else state["conv"]
+        xbc, conv_tail = causal_conv(xbc, lp["conv_w"], lp["conv_b"],
+                                     init_state=conv_init)
+        xbc = jax.nn.silu(xbc)
+        xs, bmat, cmat = jnp.split(xbc, [d_in, d_in + s.n_groups * s.d_state],
+                                   axis=-1)
+        xh = xs.reshape(b, t, nheads, s.head_dim)
+        bmat = bmat.reshape(b, t, s.n_groups, s.d_state)
+        cmat = cmat.reshape(b, t, s.n_groups, s.d_state)
+        dtv = jax.nn.softplus(dtv.astype(jnp.float32) + lp["dt_bias"])
+    with jax.named_scope("layer.ssd"):
+        ssd_init = None if state is None else state["ssd"]
+        y, new_ssd = SSD_IMPLS[ssd_impl](xh, dtv, lp["a_log"], bmat, cmat,
+                                         lp["d_skip"], chunk=s.chunk_size,
+                                         init_state=ssd_init)
+    with jax.named_scope("layer.ssm_proj"):
+        y = y.reshape(b, t, d_in)
+        y = L.rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
+                       lp["gate_norm"], cfg.norm_eps)
+        out = jnp.einsum("bte,ed->btd", y, lp["out_proj"])
+        if cfg.residual_multiplier != 1.0:
+            out = cfg.residual_multiplier * out
     new_state = {"conv": conv_tail.astype(jnp.float32), "ssd": new_ssd}
     return x + out, new_state
 

@@ -168,11 +168,13 @@ def attn_block(cfg: ModelConfig, lp: Params, x: jax.Array, *,
     if cfg.qk_norm:
         q = L.rms_norm(q, lp["q_norm"], cfg.norm_eps)
         k = L.rms_norm(k, lp["k_norm"], cfg.norm_eps)
-    if positions is None:
-        positions = jnp.arange(s)[None, :] + (0 if causal_offset is None else causal_offset)
-    cos, sin = L.rope_angles(positions, hd, cfg.rope_theta)
-    q = L.apply_rope(q, cos, sin)
-    k = L.apply_rope(k, cos, sin)
+    if not cfg.nope:
+        if positions is None:
+            positions = jnp.arange(s)[None, :] + (
+                0 if causal_offset is None else causal_offset)
+        cos, sin = L.rope_angles(positions, hd, cfg.rope_theta)
+        q = L.apply_rope(q, cos, sin)
+        k = L.apply_rope(k, cos, sin)
     if topo is not None and cfg.num_heads % topo.tp_size == 0:
         q = jax.lax.with_sharding_constraint(
             q, topo.sharding(topo.batch_axes, None, topo.tp_axis, None))

@@ -197,7 +197,7 @@ def per_event_wire_bytes(plan, cfg, b: int) -> Dict[str, float]:
     from repro.core import transport as tx
     w = tx.analytic_wire_bytes(plan, cfg, b)
     n, m, p2 = plan.num_stages, plan.num_chunks, min(plan.p2, plan.num_chunks)
-    lps = plan.layers_per_stage
+    lps = plan.pool_layers
     out = {"spill": 0.0, "fetch": 0.0, "qship": 0.0}
     n_spill = n * (m - p2)
     if n_spill:

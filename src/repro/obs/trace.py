@@ -47,6 +47,8 @@ from repro.obs._io import atomic_write_text
 # (a transport or kernel call inside a layer scope is named by the inner)
 SCOPES = ("stage.embed", "stage.head", "layer.attn_proj", "layer.attn_self",
           "layer.attn_pool", "layer.kv_write", "layer.mlp",
+          "layer.ssm_proj", "layer.ssd", "layer.moe_route",
+          "layer.moe_experts", "layer.moe_combine", "layer.moe_shared",
           "transport.ring_shift", "transport.pair_shift",
           "transport.stage_psum", "transport.tp_psum",
           "transport.tp_reduce_scatter", "transport.tp_all_gather")

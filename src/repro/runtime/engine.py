@@ -257,7 +257,9 @@ class JaxExecutor:
     (results to the host), plus the ``host.gc`` pauses; the wave record
     carries the phase durations, whether the wave compiled, traced or
     loaded anything, its requests' ``t_admit`` and its ``Program``, whose
-    ``op_scopes()`` names the device ops."""
+    ``op_scopes()`` names the device ops; a ``hybrid_moe`` wave also
+    carries ``moe_held_picks``, the (token, pick) pairs that went to the
+    held experts, per layer."""
 
     def __init__(self, cfg: ModelConfig, staged_params, topo, run: RunConfig):
         from repro.core import pipeline as pp
@@ -375,6 +377,7 @@ class JaxExecutor:
                 out = res[0]
                 tel = res[1] if collect else None
                 kv = res[1 + int(collect)] if armed else None
+                held = res[-1] if self.cfg.family == "hybrid_moe" else None
             with span("engine.device_wait", wave=wi) as device_wait:
                 out.block_until_ready()
             dt = time.perf_counter() - t0
@@ -394,6 +397,8 @@ class JaxExecutor:
                     r.result = row
                 if tel is not None:
                     tel = {name: np.asarray(v) for name, v in tel.items()}
+                if held is not None:
+                    held = np.asarray(held).tolist()
         events1 = events.counts()
         compile_events = {e: events1[e] - events0[e] for e in events1}
         pauses = events.gc_pauses(self._gc_read)
@@ -412,6 +417,9 @@ class JaxExecutor:
             "t_admit": [r.t_admit for r in requests],
             "program": self._programs[key],
         }
+        if held is not None:
+            # (token, pick) pairs routed to the held experts, per layer
+            wave["moe_held_picks"] = held
         if tel is not None:
             from repro.obs import telemetry as obs_t
             wave["telemetry"] = tel

@@ -245,13 +245,34 @@ def test_pipeline_shape_one_stage_per_device():
     # Mosaic kernels lower only in a fully manual region: on a TPU with a
     # Pallas backend under auto TP lowering every device is a stage
     for kw in ({"attn_backend": "pallas"}, {"pool_backend": "paged"},
-               {"ssm_backend": "pallas"}):
+               {"ssm_backend": "pallas", "arch": "mamba2-130m"}):
         opts = ServeOptions(**kw)
         assert pipeline_shape(4, opts, "tpu") == (4, 1)
         assert pipeline_shape(8, opts, "tpu") == (8, 1)
         assert pipeline_shape(4, opts, "cpu") == (2, 2)  # interpret mode
         manual = opts.override(tp_lowering="manual")
         assert pipeline_shape(4, manual, "tpu") == (2, 2)
+
+
+@pytest.mark.parametrize("arch", ["mamba2-130m", "zamba2-7b",
+                                  "granite-4.0-h-small"])
+def test_pipeline_shape_default_ssd_backend(arch):
+    """The default SSD backend ("auto") is what the plan runs on that
+    platform: the Pallas kernel on a TPU, so an SSM arch's default options
+    give one stage per chip there, and a model without SSM layers is not
+    held to it."""
+    from repro.configs.base import RunConfig, get_config
+    from repro.launch.options import ServeOptions
+    from repro.launch.serve import pipeline_shape
+    from repro.models.ssm import ssd_impl
+    opts = ServeOptions(arch=arch)
+    assert opts.ssm_backend == RunConfig().ssm_backend == "auto"
+    assert ssd_impl(opts.ssm_backend, "tpu") == "pallas"
+    assert ssd_impl(opts.ssm_backend, "cpu") == "jnp"
+    assert pipeline_shape(4, opts, "tpu") == (4, 1)
+    assert pipeline_shape(4, opts, "cpu") == (2, 2)
+    assert pipeline_shape(4, opts.override(ssm_backend="jnp"), "tpu") == (2, 2)
+    assert pipeline_shape(4, opts, "tpu", get_config("qwen3-8b")) == (2, 2)
 
 
 def test_device_profile_by_kind():

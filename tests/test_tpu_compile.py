@@ -11,6 +11,7 @@ inside a module fixture (never at import: only one process may load the
 TPU library at a time).
 """
 import os
+import re
 
 import pytest
 
@@ -112,6 +113,57 @@ def test_pool_attention_paged_compiles_for_v5e(spec, kv_dtype, page_tokens,
                                               ppc=ppc, k_scale=ks,
                                               v_scale=vs)
     _compile(fn, *args)
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+def test_ssd_compiles_for_v5e(spec, impl, monkeypatch):
+    """Both SSD paths of ``models.ssm.SSD_IMPLS`` (the TPU default,
+    ``ssd_impl("auto", "tpu")``, is one of them) at granite-4.0-h-small's
+    shapes: a 2048-token chunk, 128 heads of 64, d_state 128, one group,
+    256-position blocks, the state carried in."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels import ops
+    from repro.models import ssm
+    # the kernel wrapper asks the process's backend (the CPU here)
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    assert ssm.ssd_impl("auto", "tpu") in ssm.SSD_IMPLS
+    h, p, n = 128, 64, 128
+    args = [spec((1, C, h, p), jnp.bfloat16), spec((1, C, h), jnp.float32),
+            spec((h,), jnp.float32), spec((1, C, 1, n), jnp.bfloat16),
+            spec((1, C, 1, n), jnp.bfloat16), spec((h,), jnp.float32),
+            spec((1, h, p, n), jnp.float32)]
+
+    def fn(x, dt, a, b, c, d, s0):
+        return ssm.SSD_IMPLS[impl](x, dt, a, b, c, d, chunk=256,
+                                   init_state=s0)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert ("tpu_custom_call" in text) == (impl == "pallas")
+
+
+def test_held_moe_compiles_for_v5e(spec):
+    """The dropless held-expert layer at granite-4.0-h-small's widths (a
+    2048-token chunk, d 4096, the router over 72 experts, top 10, 36 held
+    experts of width 768), reading layer 3's experts from a stack of ten:
+    both grouped matmuls lower to the TPU's own ragged dot, not to a dense
+    product over every expert, and no layer's expert weights are copied
+    (the temporaries stay under one layer's experts)."""
+    import jax
+    import jax.numpy as jnp
+    from repro.models import layers as L
+    params = {"router": spec((4096, 72), jnp.bfloat16),
+              "e_wgu": spec((10, 36, 4096, 2 * 768), jnp.bfloat16),
+              "e_wd": spec((10, 36, 768, 4096), jnp.bfloat16)}
+    x = spec((1, C, 4096), jnp.bfloat16)
+    compiled = jax.jit(lambda p, x: L.held_moe(p, x, top_k=10, layer=3)
+                       ).lower(params, x).compile()
+    text = compiled.as_text()
+    grouped = [n for n in re.findall(r"%([\w.\-]+) = ", text)
+               if n.startswith("ragged-dot") and "metadata" not in n]
+    assert len(grouped) == 2, grouped
+    # the one other product is the router's
+    assert text.count(" convolution(") == 1 and " dot(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 36 * 3 * 4096 * 768 * 2
 
 
 def test_pipeline_names_for_v5e(topo):
