@@ -2,24 +2,35 @@
 
 This is MOCAP's compute hot spot: one chunk of C query tokens attends over
 (prefix + chunk) KV — the prefix rows are fully visible, the final C rows are
-causal with offset ``prefix_len``. GQA is handled by mapping query head h to
-kv head h // group in the K/V BlockSpec index maps (no KV replication in VMEM).
+causal with offset ``prefix_len``. GQA is handled by tiling the query heads
+that share a kv head together (no KV replication in VMEM).
 
 Layout: every kernel here is HEAD-MAJOR — q ``[B, H, C, D]``, k/v
 ``[B, KVH, T, D]`` — so each block's last two dims are (tokens, head_dim),
 which the TPU compiler tiles as (8k, 128) rows. A token-major ``[B, C, H, D]``
 block with one head in the second-minor position is refused by Mosaic; the
 ``kernels.ops`` wrappers transpose at the boundary. The online-softmax row
-statistics live as ``(block_q, 1)`` columns in VMEM and leave the kernel as
-lane-dense ``[B, H, 1, C]`` rows (one in-kernel transpose at the last step).
+statistics live as ``(rows, 1)`` columns in VMEM and leave the kernel as
+lane-dense ``[B, H, 1, C]`` rows (one in-kernel transpose at the end).
 
-Tiling: grid = (B, H, nq, nk) with the KV block loop innermost (sequential on
-TPU); online-softmax accumulators live in fp32 VMEM scratch. Block shapes are
-(block_q, head_dim) / (block_k, head_dim) with head_dim padded to the 128-lane
-width by the wrapper (`ops.chunk_attention`). Blocks strictly above the causal
-diagonal are skipped via ``pl.when`` (no MXU work issued). Matmuls run in the
-input dtype with fp32 accumulation; probabilities are cast to the value dtype
-before the PV matmul, like the jnp reference (``core.attention``).
+Tiling of the self block (``chunk_attention_pallas``): grid = (B, KVH *
+blocks, nq). A program owns a query tile: ``heads`` of the g query heads
+of one kv head, ``block_q`` rows each, viewed as ``heads * block_q`` score
+rows, so each key slice meets every row of the tile in one online-softmax
+update. K/V of the kv head reach VMEM whole, once per head block (the
+BlockSpec's index ignores the query tile; a kv head too large for VMEM
+comes in spans along a fourth grid axis instead). The program loops over
+the key slices its tile can see and no others: slices below every row's
+diagonal update with no mask, the widest first; only slices that straddle
+the diagonal or ``kv_len`` build a mask; slices past the tile's last
+visible key are never computed. ``self_tiles`` picks the tile from the
+shapes; the diagonal square has the query tile's side, so a chunk of C
+queries computes ``1 + block_q / C`` times its causal pairs
+(``self_block_pairs``). Accumulators live in fp32 VMEM scratch, head_dim is
+padded to the 128-lane width by the wrapper (`ops.chunk_attention`).
+Matmuls run in the input dtype with fp32 accumulation; probabilities are
+cast to the value dtype before the PV matmul, like the jnp reference
+(``core.attention``).
 
 ``pool_attention_pallas`` is the batched sibling for MOCAP's POOL scan: the
 same online softmax with a slot axis in the grid — (B, H, nq, slots, nk) —
@@ -76,6 +87,12 @@ PAGED_TILE_ROWS = 2048
 PAGED_MIN_ROWS = 256
 PAGED_SCORE_BYTES = 4 << 20
 PAGED_VMEM_BYTES = 14 << 20
+
+# Self-block kernel tile (``self_tiles``): the most query rows per head of
+# a tile, which is also the side of the diagonal square a tile masks; and
+# the most score rows (heads x rows per head) of one update.
+SELF_DIAG_ROWS = 256
+SELF_TILE_ROWS = 1024
 
 
 def _block_update(q, k, v, mask, scale, m_ref, l_ref, acc_ref):
@@ -141,8 +158,24 @@ def _state_shapes(b, h, c, d):
 
 def _attn_kernel(q_ref, k_ref, v_ref, *refs,
                  scale: float, causal_offset: int, kv_len: int,
-                 block_q: int, block_k: int, return_state: bool = False,
-                 quantized: bool = False):
+                 block_q: int, block_k: int, span: int,
+                 return_state: bool = False, quantized: bool = False):
+    """Causal self block: grid = (B, KVH * blocks, nq, spans). A program
+    (bi, hb, qi) owns one query tile, ``heads`` query heads of kv head
+    ``hb // blocks``, ``block_q`` rows each, viewed as ``R = heads *
+    block_q`` score rows (row r is query ``qi*block_q + r % block_q``).
+    k/v arrive in spans of ``span`` keys (the whole kv head at every served
+    shape, so ``spans`` is 1); each program loops over the key slices of
+    the span that its tile can see, and no further:
+
+    - slices at or below every row's diagonal update with no mask, the
+      widest (``block_k``) first, the remainder in halving widths down to
+      ``narrow = math.gcd(block_q, block_k)``;
+    - the ``narrow`` slices that straddle a row's diagonal or ``kv_len``
+      mask, the rest of the span is never computed.
+
+    The state scratch carries across spans; the last span writes o (and
+    the state rows with ``return_state``)."""
     if quantized:  # extra inputs: per-token fp32 dequant scale columns
         ksc_ref, vsc_ref, *refs = refs
     o_ref, *refs = refs
@@ -150,40 +183,168 @@ def _attn_kernel(q_ref, k_ref, v_ref, *refs,
         mo_ref, lo_ref, ao_ref, m_ref, l_ref, acc_ref = refs
     else:
         m_ref, l_ref, acc_ref = refs
-    qb = pl.program_id(2)
-    kb = pl.program_id(3)
-    nk = pl.num_programs(3)
+    qi, si = pl.program_id(2), pl.program_id(3)
+    heads = q_ref.shape[1]
+    rows = heads * block_q
+    narrow = math.gcd(block_q, block_k)
+    first = qi * block_q + causal_offset   # the tile's first query, as a key
+    lo = si * span                         # the span's first key
+    full, seen = _visible(first, lo, kv_len, block_q, span, narrow)
 
-    @pl.when(kb == 0)
+    @pl.when(si == 0)
     def _init():
         _init_state(m_ref, l_ref, acc_ref)
 
-    # absolute positions of this block's queries / keys
-    q_pos = qb * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    q = q_ref[0].reshape(rows, q_ref.shape[-1])
 
-    # skip blocks entirely above the causal diagonal
-    last_q = qb * block_q + causal_offset + block_q - 1  # last query's abs pos
-    first_k = kb * block_k
-
-    @pl.when(first_k <= last_q)
-    def _compute():
-        q = q_ref[0, 0]
-        # dequant-on-read: quantized pages store (payload, per-page
-        # per-head scales expanded to a per-token column by the caller)
-        k = _load_kv(k_ref[0, 0], ksc_ref[0, 0] if quantized else None,
+    def update(start, width, masked):
+        """One online-softmax update against keys [start, start + width)
+        of the span."""
+        sl = pl.ds(pl.multiple_of(start, narrow), width)
+        k = _load_kv(k_ref[0, 0, sl], ksc_ref[0, 0, sl] if quantized else None,
                      q.dtype)
-        v = _load_kv(v_ref[0, 0], vsc_ref[0, 0] if quantized else None,
+        v = _load_kv(v_ref[0, 0, sl], vsc_ref[0, 0, sl] if quantized else None,
                      q.dtype)
-        mask = (k_pos <= q_pos + causal_offset) & (k_pos < kv_len)
+        mask = None
+        if masked:
+            iota = functools.partial(jax.lax.broadcasted_iota, jnp.int32,
+                                     (rows, width))
+            k_pos = lo + start + iota(1)
+            q_pos = first + jax.lax.rem(iota(0), block_q)
+            mask = (k_pos <= q_pos) & (k_pos < kv_len)
         _block_update(q, k, v, mask, scale, m_ref, l_ref, acc_ref)
 
-    @pl.when(kb == nk - 1)
+    # unmasked: ``full`` narrow slices, as wide slices and then the rest
+    per = block_k // narrow
+    wide = full // per
+
+    def wide_step(i, carry):
+        update(i * block_k, block_k, False)
+        return carry
+
+    jax.lax.fori_loop(0, wide, wide_step, 0)
+    start, rest = wide * block_k, full - wide * per
+    for j in reversed(range((per - 1).bit_length())):
+        bit = jax.lax.shift_right_logical(rest, j) & 1
+
+        @pl.when(bit == 1)
+        def _part():
+            update(start, narrow << j, False)
+        start = start + bit * (narrow << j)
+
+    # masked: the narrow slices from there to the last key some row sees
+    def masked_step(i, carry):
+        update(start + i * narrow, narrow, True)
+        return carry
+
+    jax.lax.fori_loop(0, pl.cdiv(seen - start, narrow), masked_step, 0)
+
+    @pl.when(si == pl.num_programs(3) - 1)
     def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        if return_state:
-            _store_state(m_ref, l_ref, acc_ref, mo_ref, lo_ref, ao_ref)
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        for j in range(heads):
+            sl = slice(j * block_q, (j + 1) * block_q)
+            o_ref[0, j] = out[sl].astype(o_ref.dtype)
+            if return_state:
+                mo_ref[0, j] = _row(m_ref[sl])
+                lo_ref[0, j] = _row(l_ref[sl])
+                ao_ref[0, j] = acc_ref[sl]
+
+
+def _visible(first, lo, kv_len, block_q, span, narrow):
+    """Keys of the span [lo, lo + span) that a query tile whose first query
+    sits at key position ``first`` sees: ``full``, the number of narrow
+    slices from ``lo`` that every row of the tile sees, and ``seen``, the
+    keys from ``lo`` that some row sees. Python ints or traced scalars."""
+    def clip(x):
+        return jnp.minimum(jnp.maximum(x, 0), span)
+    full = clip(jnp.minimum(first + 1, kv_len) - lo) // narrow
+    seen = clip(jnp.minimum(first + block_q, kv_len) - lo)
+    return full, seen
+
+
+def _largest(n: int, most: int):
+    """``n`` if it is at most ``most``, else the largest multiple of 128
+    that divides ``n`` and is at most ``most``; None if none is."""
+    if n <= most:
+        return n
+    fits = [m for m in range(LANES, most + 1, LANES) if n % m == 0]
+    return fits[-1] if fits else None
+
+
+def self_tiles(group: int, c: int, t: int, d: int, causal_offset: int = 0):
+    """Tile of the causal self-block kernel, from shapes alone: ``(heads,
+    block_q, block_k)``.
+
+    ``block_q`` is the query rows per head of a tile and the side of the
+    diagonal square: a divisor of ``c`` that is a multiple of 128 (or
+    ``c`` itself) and at most ``SELF_DIAG_ROWS``, so a tile computes at
+    most half a square past its rows' diagonals and a chunk of C queries
+    computes ``1 + block_q / C`` times its causal pairs. ``heads`` is the
+    most of the ``group`` query heads of one kv head whose ``R = heads *
+    block_q`` rows stay within ``SELF_TILE_ROWS``. ``block_k``, the widest
+    unmasked key slice, is ``block_q`` doubled while it divides the keys
+    (``t`` rounded up to ``block_q``) and its fp32 score tile ``[R,
+    block_k]`` fits ``PAGED_SCORE_BYTES``. ``d`` and ``causal_offset`` do
+    not move the tile at any shape served; ``self_span`` picks from ``d``
+    how many keys reach VMEM at once."""
+    del d, causal_offset
+    block_q = _largest(c, SELF_DIAG_ROWS) or c
+    heads = max(h for h in range(1, group + 1) if group % h == 0
+                and (h == 1 or h * block_q <= SELF_TILE_ROWS))
+    rows, keys = heads * block_q, -(-t // block_q) * block_q
+    block_k = block_q
+    while (keys % (2 * block_k) == 0
+           and 4 * rows * 2 * block_k <= PAGED_SCORE_BYTES):
+        block_k *= 2
+    return heads, block_q, block_k
+
+
+def self_vmem_bytes(rows: int, block_k: int, span: int, d: int,
+                    kv_bytes: int, q_bytes: int = 2,
+                    quantized: bool = False) -> int:
+    """VMEM of the self-block kernel at a tile of ``rows`` query rows and
+    ``span`` resident keys, with the state outputs: the pipelined q, o
+    and accumulator blocks and state rows, the two k|v span buffers (and
+    their lane-padded scale columns), the state scratch (lane-padded (R, 1)
+    columns) and ~5 bytes per score element of temporaries, as
+    ``paged_vmem_bytes`` counts them."""
+    return (2 * rows * d * q_bytes * 2          # q and o blocks
+            + 2 * rows * d * 4                  # acc out block
+            + 2 * 2 * 8 * rows * 4              # m, l out rows (8-padded)
+            + 2 * 2 * span * d * kv_bytes       # k|v spans, double-buffered
+            + (2 * 2 * span * LANES * 4 if quantized else 0)
+            + rows * (2 * LANES + d) * 4        # m, l columns + acc scratch
+            + 5 * rows * block_k)               # score temporaries
+
+
+def self_span(t: int, rows: int, block_k: int, d: int, kv_bytes: int,
+              q_bytes: int = 2, quantized: bool = False) -> int:
+    """Keys of one kv head that reach VMEM at once: all ``t`` where the
+    tile's ``self_vmem_bytes`` stays within ``PAGED_VMEM_BYTES`` (so the
+    default scoped limit holds), else the largest multiple of ``block_k``
+    dividing ``t`` that does."""
+    fits = [s for s in range(block_k, t + 1, block_k) if t % s == 0
+            and self_vmem_bytes(rows, block_k, s, d, kv_bytes, q_bytes,
+                                quantized) <= PAGED_VMEM_BYTES]
+    return fits[-1] if fits else block_k
+
+
+def self_block_pairs(c: int, t: int, causal_offset: int, tiles):
+    """(computed, useful) (query, key) pairs per head of one self-block
+    call at ``tiles = (heads, block_q, block_k)``: the key slices the
+    kernel updates against, and the pairs a causal mask keeps (query i
+    sees keys ``<= causal_offset + i`` below ``t``)."""
+    _, block_q, block_k = tiles
+    narrow = math.gcd(block_q, block_k)
+    keys = -(-t // block_k) * block_k
+    computed = 0
+    for qi in range(c // block_q):
+        _, seen = _visible(qi * block_q + causal_offset, 0, t, block_q,
+                           keys, narrow)
+        computed += block_q * narrow * -(-int(seen) // narrow)
+    useful = sum(min(i + causal_offset + 1, t) for i in range(c))
+    return computed, useful
 
 
 def _pool_kernel(valid_ref, q_ref, k_ref, v_ref, *refs,
@@ -305,20 +466,12 @@ def paged_tiles(group: int, c: int, pt: int):
     tile ``[R, block_k]`` fits ``PAGED_SCORE_BYTES`` and at most
     ``PAGED_TILE_ROWS``, the most heads among equal ``R``. A page then
     crosses HBM→VMEM ``c * group / R`` times per call."""
-    def largest(n, most):
-        """``n`` if it is at most ``most``, else the largest multiple of
-        128 that divides ``n`` and is at most ``most``; None if none is."""
-        if n <= most:
-            return n
-        fits = [m for m in range(LANES, most + 1, LANES) if n % m == 0]
-        return fits[-1] if fits else None
-
     least = min(group * c, PAGED_MIN_ROWS)
-    block_k = largest(pt, PAGED_SCORE_BYTES // (4 * least)) or (
+    block_k = _largest(pt, PAGED_SCORE_BYTES // (4 * least)) or (
         LANES if pt % LANES == 0 else pt)
     most = min(PAGED_TILE_ROWS, PAGED_SCORE_BYTES // (4 * block_k))
     tiles = [(h * bq, h, bq) for h in range(group, 0, -1) if group % h == 0
-             for bq in [largest(c, most // h)] if bq]
+             for bq in [_largest(c, most // h)] if bq]
     _, heads, block_q = max(tiles) if tiles else (
         0, 1, LANES if c % LANES == 0 else c)
     return heads, block_q, block_k
@@ -575,8 +728,8 @@ def pool_attention_paged_pallas(
 def chunk_attention_pallas(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     causal_offset: int = 0, scale: Optional[float] = None,
-    kv_len: Optional[int] = None,
-    block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
+    kv_len: Optional[int] = None, heads: Optional[int] = None,
+    block_q: Optional[int] = None, block_k: Optional[int] = None,
     interpret: bool = False, return_state: bool = False,
     k_scale: Optional[jax.Array] = None, v_scale: Optional[jax.Array] = None,
 ):
@@ -586,6 +739,11 @@ def chunk_attention_pallas(
     ``causal_offset``: absolute position of q[0] minus the position of k[0]
     (= prefix length for chunked prefill). ``kv_len``: number of VALID kv
     positions (defaults to T; use when T includes padding).
+
+    ``heads``, ``block_q`` and ``block_k`` (query heads and rows per head
+    of a tile, widest key slice) default to ``self_tiles``' choice from the
+    shapes; k/v come whole per kv head where ``self_span`` finds VMEM for
+    it, else in the largest spans that fit.
 
     ``return_state``: also return the online-softmax residuals — ``(m, l)
     [B, H, 1, C]`` (fp32 running max / denominator rows) and the
@@ -597,52 +755,64 @@ def chunk_attention_pallas(
 
     ``k_scale``/``v_scale`` [B, KVH, T, 1] fp32: when given, k/v are
     QUANTIZED page payloads (int8 / fp8 from ``kvstore.quant``) and the
-    kernel dequantizes each block after the load — the KV bytes that cross
-    HBM and land in VMEM stay compressed. One scale per kv token (the page
-    store's per-page per-head scales, expanded by the caller), so scales
-    may vary across the pages inside one kv block.
+    kernel dequantizes each key slice after the load — the KV bytes that
+    cross HBM and land in VMEM stay compressed. One scale per kv token (the
+    page store's per-page per-head scales, expanded by the caller), so
+    scales may vary across the pages inside one key slice.
     """
     b, h, c, d = q.shape
     kvh, t = k.shape[1], k.shape[2]
     g = h // kvh
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     kv_len = kv_len if kv_len is not None else t
-    block_q = min(block_q, c)
-    block_k = min(block_k, t)
-    assert c % block_q == 0 and t % block_k == 0, (c, t, block_q, block_k)
-    nq, nk = c // block_q, t // block_k
+    auto = self_tiles(g, c, kv_len, d, causal_offset)
+    heads, block_q, block_k = (x or a for x, a in zip(
+        (heads, block_q, block_k), auto))
+    assert g % heads == 0 and c % block_q == 0 and t % block_k == 0, (
+        g, heads, c, block_q, t, block_k)
     quantized = k_scale is not None
     assert quantized == (v_scale is not None)
+    span = self_span(t, heads * block_q, block_k, d, k.dtype.itemsize,
+                     q.dtype.itemsize, quantized)
+    nq, blocks = c // block_q, g // heads
 
     kernel = functools.partial(
         _attn_kernel, scale=scale, causal_offset=causal_offset, kv_len=kv_len,
-        block_q=block_q, block_k=block_k, return_state=return_state,
-        quantized=quantized)
-    q_spec = pl.BlockSpec((1, 1, block_q, d),
-                          lambda bi, hi, qi, ki: (bi, hi, qi, 0))
-    kv_spec = pl.BlockSpec((1, 1, block_k, d),
-                           lambda bi, hi, qi, ki: (bi, hi // g, ki, 0))
+        block_q=block_q, block_k=block_k, span=span,
+        return_state=return_state, quantized=quantized)
+
+    def kv_index(bi, hb, qi, si):
+        """Span si of the tile's kv head; past the tile's last visible span
+        the index stays, so the pipeline copies nothing."""
+        last = (jnp.minimum(qi * block_q + causal_offset + block_q, kv_len)
+                - 1) // span
+        return bi, hb // blocks, jnp.minimum(si, last), 0
+
+    # head block hb of ``heads`` heads is heads hb*heads .. hb*heads+heads-1,
+    # all of kv head hb // blocks
+    q_spec = pl.BlockSpec((1, heads, block_q, d),
+                          lambda bi, hb, qi, si: (bi, hb, qi, 0))
+    kv_spec = pl.BlockSpec((1, 1, span, d), kv_index)
     out_shapes = [jax.ShapeDtypeStruct((b, h, c, d), q.dtype)]
     out_specs = [q_spec]
     if return_state:
-        ml_spec = pl.BlockSpec((1, 1, 1, block_q),
-                               lambda bi, hi, qi, ki: (bi, hi, 0, qi))
+        ml_spec = pl.BlockSpec((1, heads, 1, block_q),
+                               lambda bi, hb, qi, si: (bi, hb, 0, qi))
         out_shapes += _state_shapes(b, h, c, d)
         out_specs += [ml_spec, ml_spec, q_spec]
     in_specs = [q_spec, kv_spec, kv_spec]
     args = [q, k, v]
     if quantized:
-        sc_spec = pl.BlockSpec((1, 1, block_k, 1),
-                               lambda bi, hi, qi, ki: (bi, hi // g, ki, 0))
+        sc_spec = pl.BlockSpec((1, 1, span, 1), kv_index)
         in_specs += [sc_spec, sc_spec]
         args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     res = pl.pallas_call(
         kernel,
-        grid=(b, h, nq, nk),
+        grid=(b, h // heads, nq, t // span),
         in_specs=in_specs,
         out_specs=out_specs if return_state else q_spec,
         out_shape=out_shapes if return_state else out_shapes[0],
-        scratch_shapes=_state_scratch(block_q, d),
+        scratch_shapes=_state_scratch(heads * block_q, d),
         interpret=interpret, name=SELF_KERNEL,
     )(*args)
     return tuple(res) if return_state else res

@@ -101,12 +101,13 @@ def _state_out(m, l, acc, d: int):
 
 
 @_kernel_jit(_ca.SELF_KERNEL,
-             static_argnames=("causal_offset", "scale", "block_q", "block_k",
-                              "return_state"))
+             static_argnames=("causal_offset", "scale", "heads", "block_q",
+                              "block_k", "return_state"))
 def chunk_attention(q, k, v, *, causal_offset: int = 0,
                     scale: Optional[float] = None,
-                    block_q: int = _ca.DEFAULT_BLOCK_Q,
-                    block_k: int = _ca.DEFAULT_BLOCK_K,
+                    heads: Optional[int] = None,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     return_state: bool = False,
                     k_scale=None, v_scale=None):
     """Chunked-prefill flash attention (MOCAP hot spot). See chunk_attn.py.
@@ -117,6 +118,10 @@ def chunk_attention(q, k, v, *, causal_offset: int = 0,
     combine across KV sources at full precision — used by the pipeline's
     "pallas" attention backend (core.attention).
 
+    ``heads``/``block_q``/``block_k`` (query heads and rows per head of a
+    tile, widest key slice) override ``chunk_attn.self_tiles``' choice from
+    the shapes; an override ``block_q`` halves until it divides C.
+
     ``k_scale``/``v_scale`` [B, T, KVH]: k/v are quantized KV-page payloads
     (``repro.kvstore``, one scale row per kv token) and the kernel
     dequantizes after the block load.
@@ -124,10 +129,13 @@ def chunk_attention(q, k, v, *, causal_offset: int = 0,
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     t, c = k.shape[1], q.shape[1]
-    bq = min(block_q, c)
+    auto = _ca.self_tiles(q.shape[2] // k.shape[2], c, t, LANE * -(-d // LANE),
+                          causal_offset)
+    heads = heads or auto[0]
+    bq = min(block_q, c) if block_q else auto[1]
     while c % bq:
         bq //= 2
-    bk = min(block_k, t)
+    bk = min(block_k, t) if block_k else auto[2]
     qp = _heads_major(_pad_to(q, 3, LANE))
     kp = _heads_major(_pad_to(_pad_to(k, 3, LANE), 1, bk))
     vp = _heads_major(_pad_to(_pad_to(v, 3, LANE), 1, bk))
@@ -136,7 +144,7 @@ def chunk_attention(q, k, v, *, causal_offset: int = 0,
         v_scale = jnp.swapaxes(_pad_to(v_scale, 1, bk), 1, 2)[..., None]
     res = _ca.chunk_attention_pallas(
         qp, kp, vp, causal_offset=causal_offset, scale=scale, kv_len=t,
-        block_q=bq, block_k=bk, interpret=not _on_tpu(),
+        heads=heads, block_q=bq, block_k=bk, interpret=not _on_tpu(),
         return_state=return_state, k_scale=k_scale, v_scale=v_scale)
     if return_state:
         out, m, l, acc = res
@@ -224,8 +232,8 @@ def pool_attention_paged(q, k_pages, v_pages, handles, valid, *, ppc: int,
 
 
 def full_attention(q, k, v, *, scale: Optional[float] = None,
-                   block_q: int = _ca.DEFAULT_BLOCK_Q,
-                   block_k: int = _ca.DEFAULT_BLOCK_K):
+                   block_q: Optional[int] = None,
+                   block_k: Optional[int] = None):
     """Non-causal (full-visibility) wrapper around ``chunk_attention``:
     every query attends over every key — the encdec CROSS-attention shape
     (decoder chunk vs the whole encoder output) and bidirectional encoders.

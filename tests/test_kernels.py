@@ -167,3 +167,109 @@ def test_full_attention_matches_bidirectional_oracle(b, c, h, kvh, d, t):
     want = L.naive_attention(q, k, v, causal_offset=None)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
+
+
+# ---------------------------------- the kv-head tile of the self-block kernel
+# Each case: group size g, kv heads, chunk C, prefix P (keys T = P + C;
+# "full": every key visible, causal_offset = T), kv payload, return_state,
+# and the tile (heads, block_q, block_k; None = ``chunk_attn.self_tiles``'
+# choice from the shapes). "-spans": no VMEM for a whole kv head, so k/v
+# come in spans of block_k keys along the grid.
+SELF_RETILED_CASES = {
+    "g1-causal": (1, 2, 64, 0, "float32", True, (None, None, None)),
+    "g4-heads-x2-rows-x4-keys-x2": (4, 2, 64, 0, "float32", True, (2, 16, 32)),
+    "g12-heads-x3-prefix": (12, 1, 64, 40, "float32", True, (4, 32, 64)),
+    "g4-full-visibility": (4, 2, 32, "full", "float32", True, (2, 16, 64)),
+    "g4-kv-tail": (4, 1, 48, 37, "float32", True, (4, 16, 32)),
+    "g4-full-kv-tail": (4, 1, 16, "full", "float32", True, (1, 16, 64)),
+    "g4-int8-full": (4, 2, 32, "full", "int8", True, (2, 16, 32)),
+    "g12-fp8-prefix": (12, 1, 32, 24, "fp8", True, (6, 16, 32)),
+    "g4-bf16-no-state": (4, 2, 64, 16, "bfloat16", False, (None, None, None)),
+    "g4-keys-below-rows": (4, 1, 64, 32, "float32", False, (1, 64, 16)),
+    "g12-c256": (12, 1, 256, 0, "float32", True, (None, None, None)),
+    "g4-prefix-spans": (4, 1, 32, 40, "float32", True, (2, 16, 16)),
+}
+
+
+def _quantized(x, kv_dtype):
+    """(payload, per-token scales [B, T, KVH], dequantized float32)."""
+    top = {"int8": 127.0, "fp8": 448.0}[kv_dtype]
+    sc = jnp.max(jnp.abs(x), axis=-1) / top
+    y = x / sc[..., None]
+    payload = (jnp.round(y).astype(jnp.int8) if kv_dtype == "int8"
+               else y.astype(jnp.float8_e4m3fn))
+    return payload, sc, payload.astype(jnp.float32) * sc[..., None]
+
+
+@pytest.mark.parametrize("case", list(SELF_RETILED_CASES))
+def test_chunk_attention_retiled_parity(case, monkeypatch):
+    """The self-block kernel's kv-head tile — one or several head blocks of
+    the g query heads of a kv head, one or several query tiles, unmasked
+    key slices of one or several widths and the masked diagonal and
+    ``kv_len`` slices — against the materialised oracle, and its fp32
+    state against the jnp backend's one-update state: g 1, 4 and 12, a
+    causal chunk, a prefix, full visibility, keys that are no multiple of
+    the key slice, int8 and fp8 payloads, tile overrides, k/v in spans."""
+    from repro.core import attention as A
+    from repro.kernels import chunk_attn as ca
+    if case.endswith("-spans"):
+        monkeypatch.setattr(ca, "PAGED_VMEM_BYTES", 0)
+    g, kvh, c, p, kv_dtype, state, (heads, bq, bk) = SELF_RETILED_CASES[case]
+    b, d, h = 1, 16, g * kvh
+    full = p == "full"
+    t = c + (0 if full else p)
+    offset = t if full else p
+    dt = jnp.bfloat16 if kv_dtype == "bfloat16" else jnp.float32
+    ks = jax.random.split(jax.random.key(13), 3)
+    q = _rand(ks[0], (b, c, h, d), dt)
+    k = _rand(ks[1], (b, t, kvh, d), dt)
+    v = _rand(ks[2], (b, t, kvh, d), dt)
+    quant, k_ref, v_ref = {}, k, v
+    if kv_dtype in ("int8", "fp8"):
+        k, k_sc, k_ref = _quantized(k, kv_dtype)
+        v, v_sc, v_ref = _quantized(v, kv_dtype)
+        quant = {"k_scale": k_sc, "v_scale": v_sc}
+    res = ops.chunk_attention(q, k, v, causal_offset=offset, heads=heads,
+                              block_q=bq, block_k=bk, return_state=state,
+                              **quant)
+    out = res[0] if state else res
+    want = ref.chunk_attention_ref(q, k_ref, v_ref, causal_offset=offset)
+    tol = 2e-2 if dt == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32), atol=tol, rtol=tol)
+    if not state:
+        return
+    qg = A.group_queries(q, kvh)
+    mask = (jnp.arange(t)[None, :] <= jnp.arange(c)[:, None] + offset)
+    st = A.attn_update(qg, k_ref, v_ref, mask[None, None, None],
+                       1.0 / np.sqrt(d), A.attn_init(b, c, kvh, g, d))
+    got = A.PallasBackend._to_state(*res[1:], kvh)
+    for x, y in zip(got, st):
+        np.testing.assert_allclose(
+            np.asarray(x), np.asarray(y), rtol=0,
+            atol=1e-6 * max(1.0, float(np.max(np.abs(np.asarray(y))))))
+
+
+# every served self-block shape: C = T for the 2048-token chunks and the
+# mixed-open buckets' chunks, g 4 (qwen3-8b, granite) and 12 (mistral)
+SERVED_SELF_SHAPES = [(g, c) for g in (4, 12) for c in (256, 512, 1024, 2048)]
+
+
+@pytest.mark.parametrize("g,c", SERVED_SELF_SHAPES,
+                         ids=[f"g{g}-c{c}" for g, c in SERVED_SELF_SHAPES])
+def test_self_tiles_served_shapes(g, c):
+    """``self_tiles`` divides each served shape, keeps the fp32 score tile
+    within its budget and the diagonal square with the query tile: no key
+    slice lies wholly above the diagonal (a tile computes at most half a
+    square past its rows' diagonals), and a 2048-token chunk computes at
+    most 1.15 times its causal pairs."""
+    from repro.kernels import chunk_attn as ca
+    heads, block_q, block_k = tiles = ca.self_tiles(g, c, c, 128, 0)
+    assert g % heads == 0 and c % block_q == 0 and c % block_k == 0
+    assert block_q % 128 == 0 and block_k % block_q == 0
+    assert 4 * heads * block_q * block_k <= ca.PAGED_SCORE_BYTES
+    computed, useful = ca.self_block_pairs(c, c, 0, tiles)
+    assert useful == c * (c + 1) // 2
+    assert useful <= computed <= useful + (c // block_q) * block_q ** 2 // 2
+    if c == 2048:
+        assert computed / useful <= 1.15

@@ -3,9 +3,9 @@
 Interpret mode (every other kernel test) checks results, not what Mosaic
 accepts: block shapes, tiling-aligned DMA slices, VMEM use. These tests
 compile each kernel at qwen3-8b widths (chunk 2048, 32 query / 8 kv heads,
-head_dim 128, bf16; the paged pool kernel also at mistral-large widths and
-a 256-token chunk) for a described ``v5e:2x2`` chip — no chip attached,
-nothing runs; one more compiles the four-stage pipeline at reduced widths
+head_dim 128, bf16; the self-block and paged pool kernels also at
+mistral-large widths and a 256-token chunk) for a described ``v5e:2x2``
+chip — no chip attached, nothing runs; one more compiles the four-stage pipeline at reduced widths
 and checks the names a profiler trace will show. The topology is described
 inside a module fixture (never at import: only one process may load the
 TPU library at a time).
@@ -51,22 +51,46 @@ def _compile(fn, *args):
     return compiled
 
 
-@pytest.mark.parametrize("variant", ["plain", "return_state", "int8"])
-def test_chunk_attention_compiles_for_v5e(spec, variant):
+@pytest.mark.parametrize("variant,widths,offset", [
+    ("plain", (H, KVH, C), 0), ("return_state", (H, KVH, C), 0),
+    ("int8", (H, KVH, C), 0), ("return_state", (96, 8, C), 0),
+    ("return_state", (H, KVH, 256), 0), ("int8", (H, KVH, C), C)],
+    ids=["plain", "return_state", "int8", "mistral-return_state",
+         "bucket256-return_state", "full-visibility-int8"])
+def test_chunk_attention_compiles_for_v5e(spec, variant, widths, offset):
+    """The self-block kernel at the tiles ``self_tiles`` picks for the
+    served shapes — qwen3-8b (g 4), mistral-large (96/8 heads, g 12), the
+    smallest mixed-open bucket (256-token chunks) and MBKR's fetched chunk
+    (every key visible, int8 payloads) — compiles with the whole kv head
+    resident, within the default scoped VMEM: the kernel asks for no
+    limit of its own."""
+    import jax
     import jax.numpy as jnp
     from repro.kernels import chunk_attn as ca
+    h, kvh, c = widths
     kv_dt = jnp.int8 if variant == "int8" else jnp.bfloat16
-    q = spec((1, H, C, D), jnp.bfloat16)
-    kv = spec((1, KVH, C, D), kv_dt)
+    heads, block_q, block_k = ca.self_tiles(h // kvh, c, c, D, offset)
+    rows, kv_bytes = heads * block_q, jnp.dtype(kv_dt).itemsize
+    quantized = variant == "int8"
+    assert ca.self_span(c, rows, block_k, D, kv_bytes, 2, quantized) == c
+    assert ca.self_vmem_bytes(rows, block_k, c, D, kv_bytes, 2,
+                              quantized) <= ca.PAGED_VMEM_BYTES
+    q = spec((1, h, c, D), jnp.bfloat16)
+    kv = spec((1, kvh, c, D), kv_dt)
     args = [q, kv, kv]
-    kw = {"return_state": variant == "return_state"}
-    if variant == "int8":
-        sc = spec((1, KVH, C, 1), jnp.float32)
+    kw = {"return_state": variant == "return_state",
+          "causal_offset": offset}
+    if quantized:
+        sc = spec((1, kvh, c, 1), jnp.float32)
         args += [sc, sc]
 
     def fn(q, k, v, ks=None, vs=None):
         return ca.chunk_attention_pallas(q, k, v, k_scale=ks, v_scale=vs,
                                          **kw)
+    lowered = jax.jit(fn).lower(*args).as_text()
+    # a kernel that sets vmem_limit_bytes carries a scoped memory config
+    assert lowered.count("tpu_custom_call") == 1
+    assert "scoped_memory_configs" not in lowered
     _compile(fn, *args)
 
 
